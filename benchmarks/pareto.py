@@ -74,7 +74,9 @@ def main() -> None:
 
     from shared_tensor_tpu.config import ScalePolicy
     from shared_tensor_tpu.ops import codec_pallas as codec
+    from shared_tensor_tpu.utils.compile_cache import enable_compile_cache
 
+    enable_compile_cache()
     policy = ScalePolicy[args.policy]
     for log2n in (int(s) for s in args.sizes.split(",")):
         print(json.dumps(measure_size(codec, 1 << log2n, policy)), flush=True)

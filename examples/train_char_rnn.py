@@ -21,6 +21,7 @@ import jax
 import jax.numpy as jnp
 
 from shared_tensor_tpu.models import char_rnn as m
+from shared_tensor_tpu.utils.compile_cache import enable_compile_cache
 
 
 def train_pod(text: bytes, cfg, args) -> None:
@@ -98,6 +99,7 @@ def main() -> None:
     if len(text) < args.seq + 2:
         sys.exit("corpus too small for --seq")
 
+    enable_compile_cache()
     cfg = m.CharRNNConfig(hidden=args.hidden, layers=args.layers)
     if args.peer:
         train_peer(text, cfg, args)

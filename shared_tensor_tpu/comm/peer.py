@@ -379,8 +379,8 @@ class SharedTensorPeer:
             # side.
             self._burst = min(self._burst, wire.burst_frames_cap(spec))
         # Device-tier burst (Config.device_frame_burst): any size — the
-        # point is amortizing the device-link round trip, which hurts at
-        # every table size (VERDICT r03 item 3).
+        # point is amortizing the device-link round trip, which is paid at
+        # every table size.
         dev_burstable = (
             not tcfg.wire_compat
             and not host_tier_active()
@@ -568,6 +568,11 @@ class SharedTensorPeer:
             self._ready.set()
         self._stop = threading.Event()
         self._wake = threading.Event()
+        # close() is reached from the caller, from leave() and from the
+        # drain helper thread; the lock serialises them and the flag makes
+        # every call after the first a no-op
+        self._close_lock = threading.Lock()
+        self._closed = False
         # parent-side handshake state: link_id -> snapshot being received
         self._pending: dict[int, bytearray] = {}
         # child-side re-graft accounting. Invariant: the snapshot we send a
@@ -1528,22 +1533,32 @@ class SharedTensorPeer:
 
     def close(self) -> None:
         """Leave the tree. Peers survive and re-graft (the reference prints an
-        apology and exit(-1)s the entire process instead — quirk Q8)."""
-        self._stop.set()
-        self._wake.set()
-        for t in (self._send_thread, self._recv_thread):
-            t.join(timeout=5.0)
-        if self._engine is not None:
-            # engine threads block inside the node's queues/condvars: they
-            # must stop BEFORE the node is torn down
-            self._engine.stop()
-        if self._obs is not None:
-            # final native-ring drain + sink/registry teardown, BEFORE the
-            # node closes so the close-path events still merge in
-            self._obs.close()
-        self.node.close()
-        if self._engine is not None:
-            self._engine.destroy()
+        apology and exit(-1)s the entire process instead — quirk Q8).
+
+        Idempotent and serialised: a second caller (``ctl drain`` runs
+        leave() -> close() on a helper thread while the owner's ``finally``
+        closes too) waits for the first teardown to finish and returns. Two
+        threads inside the teardown at once tore the node down under a
+        still-running engine and aborted the interpreter (ROADMAP D0)."""
+        with self._close_lock:
+            if self._closed:
+                return
+            self._closed = True
+            self._stop.set()
+            self._wake.set()
+            for t in (self._send_thread, self._recv_thread):
+                t.join(timeout=5.0)
+            if self._engine is not None:
+                # engine threads block inside the node's queues/condvars:
+                # they must stop BEFORE the node is torn down
+                self._engine.stop()
+            if self._obs is not None:
+                # final native-ring drain + sink/registry teardown, BEFORE
+                # the node closes so the close-path events still merge in
+                self._obs.close()
+            self.node.close()
+            if self._engine is not None:
+                self._engine.destroy()
 
     # -- introspection -------------------------------------------------------
 
@@ -1921,9 +1936,9 @@ class SharedTensorPeer:
                     continue
                 # Device tier: K-frame bursts when enabled — ONE dispatch +
                 # ONE device->host fetch per message (self._burst_device;
-                # a tunneled/PCIe device link pays its round trip per
-                # FETCH, so K frames per fetch multiply delivered residual
-                # per round trip exactly as BURST does on host).
+                # the device link pays its round trip per FETCH, so K
+                # frames per fetch multiply delivered residual per round
+                # trip exactly as BURST does on host).
                 dev_burst = (
                     not compat
                     and not self.st.host_tier

@@ -48,16 +48,7 @@ from typing import NamedTuple, Optional, Tuple
 import jax
 import jax.numpy as jnp
 import numpy as np
-try:  # jax >= 0.6: top-level shard_map with the check_vma kwarg
-    from jax import shard_map
-except ImportError:  # jax 0.4.x: experimental module, check_rep kwarg
-    from jax.experimental.shard_map import shard_map as _shard_map_legacy
-
-    def shard_map(f, *, mesh, in_specs, out_specs, check_vma=True):
-        return _shard_map_legacy(
-            f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-            check_rep=check_vma,
-        )
+from jax import shard_map
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from ..config import MeshConfig, ScalePolicy
@@ -96,14 +87,23 @@ def init_state(
     seed is just replicated — the streaming join path lives in the DCN tier
     (comm/peer.py)."""
     sh = state_sharding(mesh, config)
-    n_peer = mesh.shape[sh.spec[0]]
-    rows_per_shard(spec.total, mesh.shape[sh.spec[1]])  # validate divisibility
-    if template is not None:
-        flat = flatten(template, spec)
+    peer_ax, shard_ax = sh.spec
+    shape = (mesh.shape[peer_ax], spec.total)
+    rows_per_shard(spec.total, mesh.shape[shard_ax])  # validate divisibility
+    # Both arrays are built under their sharding, so each device only ever
+    # holds its own (1, total / n_shard) block: broadcasting on the default
+    # device first would stage the whole (n_peer, total) state there.
+    zeros = jax.jit(lambda: jnp.zeros(shape, jnp.float32), out_shardings=sh)
+    residual = zeros()
+    if template is None:
+        values = zeros()
     else:
-        flat = jnp.zeros((spec.total,), jnp.float32)
-    values = jax.device_put(jnp.broadcast_to(flat, (n_peer, spec.total)), sh)
-    residual = jax.device_put(jnp.zeros((n_peer, spec.total), jnp.float32), sh)
+        seed = jax.device_put(
+            flatten(template, spec), NamedSharding(mesh, P(shard_ax))
+        )
+        values = jax.jit(
+            lambda f: jnp.broadcast_to(f, shape), out_shardings=sh
+        )(seed)
     return PeerSyncState(values, residual)
 
 

@@ -34,9 +34,8 @@ from typing import Tuple
 
 import jax
 import jax.numpy as jnp
+from jax import shard_map
 from jax.sharding import Mesh, PartitionSpec as P
-
-from .ici import shard_map  # version-shimmed (jax 0.4.x..0.7)
 
 from ..config import MeshConfig, ScalePolicy
 from ..ops.codec import SAT

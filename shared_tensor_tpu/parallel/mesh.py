@@ -76,28 +76,7 @@ def init_multihost(
     Returns this process's index. No-ops safely if already initialized."""
     import jax.distributed
 
-    # jax 0.4.x's CPU backend refuses multiprocess computations unless a
-    # cross-process collectives implementation is picked explicitly; newer
-    # jax selects one automatically (and may drop the config knob). On
-    # 0.4.37 the option accepts update() but is NOT readable as a config
-    # attribute, so probe the flag holder directly (default "none").
-    try:
-        from jax._src import xla_bridge as _xb
-
-        if _xb.CPU_COLLECTIVES_IMPLEMENTATION.value in (None, "none"):
-            jax.config.update("jax_cpu_collectives_implementation", "gloo")
-    except Exception:
-        pass  # knob absent on this jax: the backend picks automatically
-
-    # jax >= 0.5 exposes is_initialized(); 0.4.x only has the private
-    # global client state — probe whichever this version has
-    if hasattr(jax.distributed, "is_initialized"):
-        initialized = jax.distributed.is_initialized()
-    else:
-        from jax._src import distributed as _dist
-
-        initialized = getattr(_dist.global_state, "client", None) is not None
-    if initialized:
+    if jax.distributed.is_initialized():
         return jax.process_index()  # idempotent use in notebooks/tests
     # Any RuntimeError here (bad coordinator address, mismatched
     # num_processes/process_id) propagates: swallowing it would let a broken

@@ -237,6 +237,15 @@ class PodTrainer:
         self.steps += 1
         return losses, scales
 
+    def lower(self, batch: Any, lr: float = 1e-2):
+        """The fused step :meth:`step` runs on a sync beat, lowered for this
+        state and ``batch`` and not run: ``.compile().as_text()`` is how
+        chip_smoke.py shows the codec kernels are in the program
+        (``tpu_custom_call``)."""
+        return self._step.lower(
+            self.state, self.opt_state, batch, jnp.float32(lr)
+        )
+
     def read(self, peer: int = 0) -> Any:
         """Peer ``peer``'s current replica as the template pytree (reference
         copyToTensor, src/sharedtensor.c:435-446)."""

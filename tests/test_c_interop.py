@@ -1,5 +1,5 @@
 """Byte-level interop against a real compiled-C reference-protocol peer
-(VERDICT.md round-1 item 5; SURVEY.md §7.4 hard part 5).
+(SURVEY.md §7.4 hard part 5).
 
 `native/stc_harness.c` is a fresh C implementation of the reference wire
 protocol + codec spec (reference src/sharedtensor.c:106-189 BEHAVIOR, per

@@ -1,8 +1,8 @@
-"""Executable evidence for the GSPMD multi-host tier (VERDICT.md round-1
-item 8): two REAL processes form a jax.distributed cluster over loopback,
-build the global (peer, shard) mesh with parallel/mesh.py, and run a real
-cross-process collective. This is the jax.distributed analog of the
-reference's N-processes-on-localhost dev story (SURVEY.md §4.1)."""
+"""Executable evidence for the GSPMD multi-host tier: two REAL processes
+form a jax.distributed cluster over loopback, build the global (peer, shard)
+mesh with parallel/mesh.py, and run a real cross-process collective. This is
+the jax.distributed analog of the reference's N-processes-on-localhost dev
+story (SURVEY.md §4.1)."""
 
 import os
 import socket
@@ -38,7 +38,7 @@ WORKER = textwrap.dedent(
 
     # the pod mesh spans both processes; psum over the peer axis must sum
     # contributions from devices this process cannot address directly
-    from shared_tensor_tpu.parallel.ici import shard_map  # version-shimmed
+    from jax import shard_map
     import jax.numpy as jnp
     from jax.sharding import NamedSharding, PartitionSpec as P
 

@@ -1,0 +1,99 @@
+"""The chip path compiles for a v5e, checked without one.
+
+The installed libtpu can describe a v5e 2x2 topology and run XLA:TPU and
+Mosaic for it under JAX_PLATFORMS=cpu, so ``jit(...).lower(...).compile()``
+on arguments sharded over that topology is the real compilation: VMEM
+overflows, unsupported Mosaic ops and bad shardings fail here, on the CPU,
+before anybody spends chip time. Compiling is not running — chip_smoke.py
+does that.
+
+The kernels are forced out of interpret mode (``codec_pallas._interpret``)
+and the codec tier onto Pallas (``ST_CODEC``), which is what a tpu backend
+selects by itself; every test asserts the Mosaic custom calls are in the
+compiled text. Each test jits a function object of its own, so no trace made
+here is ever served to another test.
+"""
+
+from functools import partial
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+from jax.sharding import NamedSharding, PartitionSpec as P
+
+from shared_tensor_tpu.models import char_rnn as m
+from shared_tensor_tpu.ops import codec_pallas, table
+from shared_tensor_tpu.parallel import PeerSyncState, make_mesh, state_sharding
+from shared_tensor_tpu.train import build_train_step
+
+
+@pytest.fixture(scope="module")
+def v5e_devices():
+    from jax.experimental import topologies
+
+    try:
+        topo = topologies.get_topology_desc(
+            platform="tpu", topology_name="v5e:2x2"
+        )
+    except Exception as e:  # no libtpu, or one that cannot describe a v5e
+        pytest.skip(f"cannot build a v5e:2x2 topology here: {e}")
+    return topo.devices
+
+
+@pytest.fixture(autouse=True)
+def compiled_pallas(monkeypatch):
+    monkeypatch.setattr(codec_pallas, "_interpret", lambda: False)
+    monkeypatch.setenv("ST_CODEC", "pallas")
+
+
+@pytest.mark.parametrize(
+    "n_peer,n_shard,overlap", [(4, 1, False), (2, 2, True)]
+)
+def test_flagship_train_step_compiles_for_v5e(
+    v5e_devices, n_peer, n_shard, overlap
+):
+    cfg = m.CharRNNConfig()
+    mesh = make_mesh(n_peer, n_shard, devices=v5e_devices)
+    spec = table.make_spec(m.init_params(jax.random.key(0), cfg))
+    step = build_train_step(
+        mesh, spec, lambda p, b: m.loss_fn(p, b, cfg), overlap=overlap
+    )
+
+    def arg(shape, dtype, pspec):
+        return jax.ShapeDtypeStruct(
+            shape, dtype, sharding=NamedSharding(mesh, pspec)
+        )
+
+    sh = state_sharding(mesh).spec
+    block = arg((n_peer, spec.total), jnp.float32, sh)
+    tokens = arg((n_peer, 16, 256), jnp.int32, P(sh[0]))  # train_bench's shape
+    compiled = step.lower(
+        PeerSyncState(block, block), None, (tokens, tokens),
+        arg((), jnp.float32, P()),
+    ).compile()
+    # the quantize and the apply kernel
+    assert compiled.as_text().count("tpu_custom_call") >= 2
+
+
+@pytest.mark.parametrize("k", [1, 8, 16])
+def test_apply_table_batch_compiles_for_v5e(v5e_devices, k):
+    """K = 16 is the device tier's default burst (comm/peer.py) and what
+    SharedTensor.receive_frames pads up to; at the seed it asked for more
+    VMEM than a kernel gets."""
+    spec = table.make_spec(np.zeros(1 << 20, np.float32))
+    mesh = make_mesh(1, 1, devices=v5e_devices)
+    arg = lambda shape, dtype: jax.ShapeDtypeStruct(
+        shape, dtype, sharding=NamedSharding(mesh, P())
+    )
+    frames = table.TableFrame(
+        arg((k, spec.num_leaves), jnp.float32),
+        arg((k, spec.total // 32), jnp.uint32),
+    )
+    apply = jax.jit(
+        partial(table._apply_table_batch.__wrapped__, spec=spec, impl="pallas")
+    )
+    for n_arrays in (1, 3):  # the replica alone; with two links' residuals
+        arrays = (arg((spec.total,), jnp.float32),) * n_arrays
+        text = apply.lower(arrays, frames).compile().as_text()
+        assert "tpu_custom_call" in text, (k, n_arrays)

@@ -61,10 +61,10 @@ def test_peers_stay_consistent_under_compression():
     floor = float(jnp.max(scales))
     spread = tr.replica_spread()
     # 8 scales per other-peer link: the +/-scale oscillation of quirk Q3
-    # superposes across links and is trajectory-dependent — XLA-version fp
-    # drift moves which elements sit mid-oscillation at the final step
-    # (measured 17x floor on jax 0.4.37 vs ~12x when the 4x bound was
-    # calibrated); the scale-PROPORTIONAL shape of the bound is the claim
+    # superposes across links and is trajectory-dependent — fp drift between
+    # XLA versions moves which elements sit mid-oscillation at the final
+    # step (12x to 17x the floor has been seen); the scale-PROPORTIONAL
+    # shape of the bound is the claim
     assert spread <= max(8 * (4 - 1) * floor, 1e-6), (spread, floor)
     assert spread < 0.02, spread
 
