@@ -17,8 +17,7 @@ acceptance bar:
   (retransmits >= 1, exact convergence).
 
 Also exports the run's merged timeline as a Perfetto-loadable Chrome
-trace (the committed TRACE artifact rides profile_trace.py instead; this
-one is optional via ST_CLUSTER_TRACE_OUT).
+trace, optional via ST_CLUSTER_TRACE_OUT.
 
 r10 ``--subscribers N`` arm: N read-only serve-tier leaves graft DIRECTLY
 under the chaotic node (whose drop schedule then covers their unledgered

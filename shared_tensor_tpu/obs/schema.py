@@ -186,6 +186,18 @@ SCHEMA: dict[str, tuple[str, str]] = {
     "st_slo_burn_rate": ("gauge", "root analyzer: staleness SLO burn rate over the severity's long window (per-window label)"),
     "st_slo_alert": ("gauge", "root analyzer: staleness SLO alert severity (0=ok, 1=ticket, 2=page)"),
     "st_slo_bad_beats_total": ("counter", "root analyzer: digest beats whose worst corrected staleness broke the objective"),
+    # r27 pod tier (train/async_sgd.py PodTrainer; utils/profiling.py
+    # pod_registry() holds them). Steps are per PROGRAM (label program=
+    # "sync" for the beat that exchanges, "local" for the off-beat and for
+    # sync=False); a compilation is any backend compile JAX reports, a load
+    # from the persistent cache included, and the gauge is PodTrainer.steps
+    # at the latest one: a step number that keeps rising says a shape keeps
+    # changing.
+    "st_pod_steps_total": ("counter", "PodTrainer steps completed (per-program label: sync | local)"),
+    "st_pod_compiles_total": ("counter", "backend compilations JAX reported in this process (persistent-cache loads included)"),
+    "st_pod_compile_seconds_total": ("counter", "seconds in those compilations"),
+    "st_pod_cache_load_seconds_total": ("counter", "seconds retrieving executables from the persistent compilation cache"),
+    "st_pod_last_compile_step": ("gauge", "PodTrainer.steps when the latest compilation happened"),
     # per-link series (rendered via link_key)
     "st_link_bytes_out_total": ("counter", "wire bytes sent on the link (incl. framing/keepalives)"),
     "st_link_bytes_in_total": ("counter", "wire bytes received on the link"),

@@ -189,6 +189,7 @@ def quantize(
         ],
         input_output_aliases={1: 1},  # new residual reuses the old buffer
         interpret=_interpret(),
+        name="st_quantize",
     )(scale.reshape(1, 1), residual.reshape(rows, LANES))
     return Frame(scale, words2d.reshape(-1)), new_resid.reshape(-1)
 
@@ -238,6 +239,7 @@ def apply_frame_many(
         out_shape=[jax.ShapeDtypeStruct((rows, LANES), jnp.float32)] * k,
         input_output_aliases={2 + i: i for i in range(k)},
         interpret=_interpret(),
+        name="st_apply_frame_many",
     )(
         frame.scale.reshape(1, 1),
         frame.words.reshape(rows, WORDS_PER_ROW),
@@ -306,6 +308,7 @@ def quantize_rows(
         ],
         input_output_aliases={2: 1},
         interpret=_interpret(),
+        name="st_quantize_rows",
     )(
         s_row.reshape(rows, 1),
         rowcount.reshape(rows, 1).astype(jnp.int32),
@@ -390,6 +393,7 @@ def apply_rows_batch(
         out_shape=[jax.ShapeDtypeStruct((rows, LANES), jnp.float32)] * n_arr,
         input_output_aliases={3 + i: i for i in range(n_arr)},
         interpret=_interpret(),
+        name="st_apply_rows_batch",
     )(
         s_rows,
         rowcount.reshape(rows, 1).astype(jnp.int32),

@@ -116,25 +116,27 @@ def flatten(tree: Any, spec: TableSpec) -> jnp.ndarray:
         raise ValueError(
             f"tree structure {treedef} does not match spec {spec.treedef}"
         )
-    parts = []
-    for i, (leaf, n, p) in enumerate(zip(leaves, spec.ns, spec.padded)):
-        flat = jnp.ravel(jnp.asarray(leaf)).astype(jnp.float32)
-        if flat.shape[0] != n:
-            raise ValueError(
-                f"leaf {i} has {flat.shape[0]} elements, spec expects {n}"
-            )
-        parts.append(pad_flat(flat, p))
-    return jnp.concatenate(parts) if len(parts) > 1 else parts[0]
+    with jax.named_scope("st.flatten"):
+        parts = []
+        for i, (leaf, n, p) in enumerate(zip(leaves, spec.ns, spec.padded)):
+            flat = jnp.ravel(jnp.asarray(leaf)).astype(jnp.float32)
+            if flat.shape[0] != n:
+                raise ValueError(
+                    f"leaf {i} has {flat.shape[0]} elements, spec expects {n}"
+                )
+            parts.append(pad_flat(flat, p))
+        return jnp.concatenate(parts) if len(parts) > 1 else parts[0]
 
 
 def unflatten(flat: jnp.ndarray, spec: TableSpec) -> Any:
     """Inverse of :func:`flatten`."""
-    leaves = []
-    off = 0
-    for shape, n, p in zip(spec.shapes, spec.ns, spec.padded):
-        leaves.append(flat[off : off + n].reshape(shape))
-        off += p
-    return jax.tree.unflatten(spec.treedef, leaves)
+    with jax.named_scope("st.unflatten"):
+        leaves = []
+        off = 0
+        for shape, n, p in zip(spec.shapes, spec.ns, spec.padded):
+            leaves.append(flat[off : off + n].reshape(shape))
+            off += p
+        return jax.tree.unflatten(spec.treedef, leaves)
 
 
 def _live_mask_flat(spec: TableSpec) -> np.ndarray:

@@ -90,27 +90,30 @@ def init_state(
     peer_ax, shard_ax = sh.spec
     shape = (mesh.shape[peer_ax], spec.total)
     rows_per_shard(spec.total, mesh.shape[shard_ax])  # validate divisibility
-    # Both arrays are built under their sharding, so each device only ever
-    # holds its own (1, total / n_shard) block: broadcasting on the default
-    # device first would stage the whole (n_peer, total) state there.
-    zeros = jax.jit(lambda: jnp.zeros(shape, jnp.float32), out_shardings=sh)
-    residual = zeros()
-    if template is None:
-        values = zeros()
-    else:
-        seed = jax.device_put(
-            flatten(template, spec), NamedSharding(mesh, P(shard_ax))
-        )
-        values = jax.jit(
-            lambda f: jnp.broadcast_to(f, shape), out_shardings=sh
-        )(seed)
+    with jax.profiler.TraceAnnotation("st:init_state"):
+        # Both arrays are built under their sharding, so each device only
+        # ever holds its own (1, total / n_shard) block: broadcasting on the
+        # default device first would stage the whole (n_peer, total) state
+        # there.
+        zeros = jax.jit(lambda: jnp.zeros(shape, jnp.float32), out_shardings=sh)
+        residual = zeros()
+        if template is None:
+            values = zeros()
+        else:
+            seed = jax.device_put(
+                flatten(template, spec), NamedSharding(mesh, P(shard_ax))
+            )
+            values = jax.jit(
+                lambda f: jnp.broadcast_to(f, shape), out_shardings=sh
+            )(seed)
     return PeerSyncState(values, residual)
 
 
 def read_peer(state: PeerSyncState, spec: TableSpec, peer: int):
     """Peer ``peer``'s current replica as the caller's pytree (reference
     copyToTensor)."""
-    return unflatten(state.values[peer], spec)
+    with jax.profiler.TraceAnnotation("st:read_peer"):
+        return unflatten(state.values[peer], spec)
 
 
 def add_updates_raw(state: PeerSyncState, updates: jax.Array) -> PeerSyncState:
@@ -121,11 +124,14 @@ def add_updates_raw(state: PeerSyncState, updates: jax.Array) -> PeerSyncState:
 
     Un-jitted so callers (train/async_sgd.py) can fuse it into a larger
     step; use :func:`add_updates` standalone."""
-    u = jnp.nan_to_num(updates.astype(jnp.float32), nan=0.0, posinf=3.0e38, neginf=-3.0e38)
-    return PeerSyncState(
-        jnp.clip(state.values + u, -3.0e38, 3.0e38),
-        jnp.clip(state.residual + u, -3.0e38, 3.0e38),
-    )
+    with jax.named_scope("st.add_updates"):
+        u = jnp.nan_to_num(
+            updates.astype(jnp.float32), nan=0.0, posinf=3.0e38, neginf=-3.0e38
+        )
+        return PeerSyncState(
+            jnp.clip(state.values + u, -3.0e38, 3.0e38),
+            jnp.clip(state.residual + u, -3.0e38, 3.0e38),
+        )
 
 
 add_updates = jax.jit(add_updates_raw, donate_argnums=(0,))
@@ -226,33 +232,34 @@ def _leaf_scales(
     the segment reductions split into a local partial + a cross-shard
     psum/pmax (this is where the sharded replica pays one small collective —
     k floats — per frame)."""
-    amax_row = jnp.max(jnp.where(live, jnp.abs(rows), 0.0), axis=1)
-    amax = jax.ops.segment_max(amax_row, row_leaf, num_segments=k)
-    amax = jnp.maximum(amax, 0.0)  # segment_max identity is -inf
-    if shard_axis is not None:
-        amax = jax.lax.pmax(amax, shard_axis)
-    denom = jnp.where(amax > 0, amax, 1.0)
-    norm = jnp.where(live, rows / denom[row_leaf][:, None], 0.0)
-    if policy == ScalePolicy.ABS_MEAN:
-        part = jax.ops.segment_sum(
-            jnp.sum(jnp.abs(norm), axis=1, dtype=jnp.float32),
-            row_leaf,
-            num_segments=k,
-        )
+    with jax.named_scope("st.leaf_scales"):
+        amax_row = jnp.max(jnp.where(live, jnp.abs(rows), 0.0), axis=1)
+        amax = jax.ops.segment_max(amax_row, row_leaf, num_segments=k)
+        amax = jnp.maximum(amax, 0.0)  # segment_max identity is -inf
         if shard_axis is not None:
-            part = jax.lax.psum(part, shard_axis)
-        scales = amax * (part / ns)
-    else:
-        part = jax.ops.segment_sum(
-            jnp.sum(norm * norm, axis=1, dtype=jnp.float32),
-            row_leaf,
-            num_segments=k,
-        )
-        if shard_axis is not None:
-            part = jax.lax.psum(part, shard_axis)
-        rms = amax * jnp.sqrt(part / ns)
-        scales = pow2_floor(rms) if policy == ScalePolicy.POW2_RMS else rms
-    return jnp.where((amax > 0) & jnp.isfinite(scales), scales, 0.0)
+            amax = jax.lax.pmax(amax, shard_axis)
+        denom = jnp.where(amax > 0, amax, 1.0)
+        norm = jnp.where(live, rows / denom[row_leaf][:, None], 0.0)
+        if policy == ScalePolicy.ABS_MEAN:
+            part = jax.ops.segment_sum(
+                jnp.sum(jnp.abs(norm), axis=1, dtype=jnp.float32),
+                row_leaf,
+                num_segments=k,
+            )
+            if shard_axis is not None:
+                part = jax.lax.psum(part, shard_axis)
+            scales = amax * (part / ns)
+        else:
+            part = jax.ops.segment_sum(
+                jnp.sum(norm * norm, axis=1, dtype=jnp.float32),
+                row_leaf,
+                num_segments=k,
+            )
+            if shard_axis is not None:
+                part = jax.lax.psum(part, shard_axis)
+            rms = amax * jnp.sqrt(part / ns)
+            scales = pow2_floor(rms) if policy == ScalePolicy.POW2_RMS else rms
+        return jnp.where((amax > 0) & jnp.isfinite(scales), scales, 0.0)
 
 
 def _codec_send(ctx: _StepCtx, policy: ScalePolicy, pallas_tier: bool, residual):
@@ -269,25 +276,32 @@ def _codec_send(ctx: _StepCtx, policy: ScalePolicy, pallas_tier: bool, residual)
 
     Returns (new_residual [flat], words_all [n_peer, W_local],
     scales_all [n_peer, k], scales_local [k])."""
-    r = residual.reshape(ctx.rows_local, LANES)
-    row_leaf, rowcount, live = ctx.local_slices()
-    scales = _leaf_scales(r, row_leaf, live, ctx.ns, ctx.k, policy, ctx.shard_ax)
-    if pallas_tier:
-        from ..ops import codec_pallas
+    with jax.named_scope("st.codec_send"):
+        r = residual.reshape(ctx.rows_local, LANES)
+        row_leaf, rowcount, live = ctx.local_slices()
+        scales = _leaf_scales(
+            r, row_leaf, live, ctx.ns, ctx.k, policy, ctx.shard_ax
+        )
+        with jax.named_scope("st.row_scales"):
+            s_row = scales[row_leaf]
+        with jax.named_scope("st.quantize"):
+            if pallas_tier:
+                from ..ops import codec_pallas
 
-        words, r2 = codec_pallas.quantize_rows(scales[row_leaf], rowcount, residual)
-    else:
-        s_row = scales[row_leaf][:, None]  # (rows, 1)
-        # sign-quantize + error feedback (reference :166-174)
-        neg = r <= 0.0
-        bits = jnp.logical_and(live, neg)
-        sent = jnp.where(neg, -s_row, s_row)
-        r2 = jnp.where(
-            live & (s_row > 0), r - sent, jnp.where(live, r, 0.0)
-        ).reshape(-1)
-        words = pack_bits(bits.reshape(-1))
-    words_all = jax.lax.all_gather(words, ctx.peer_ax)  # (n_peer, W_local)
-    scales_all = jax.lax.all_gather(scales, ctx.peer_ax)  # (n_peer, k)
+                words, r2 = codec_pallas.quantize_rows(s_row, rowcount, residual)
+            else:
+                s_row = s_row[:, None]  # (rows, 1)
+                # sign-quantize + error feedback (reference :166-174)
+                neg = r <= 0.0
+                bits = jnp.logical_and(live, neg)
+                sent = jnp.where(neg, -s_row, s_row)
+                r2 = jnp.where(
+                    live & (s_row > 0), r - sent, jnp.where(live, r, 0.0)
+                ).reshape(-1)
+                words = pack_bits(bits.reshape(-1))
+        with jax.named_scope("st.allgather"):
+            words_all = jax.lax.all_gather(words, ctx.peer_ax)  # (n_peer, W_local)
+            scales_all = jax.lax.all_gather(scales, ctx.peer_ax)  # (n_peer, k)
     return r2, words_all, scales_all, scales
 
 
@@ -298,33 +312,40 @@ def _codec_apply(ctx: _StepCtx, pallas_tier: bool, values, words_all, scales_all
     one pass (fused Pallas on TPU). Result clamped to +/-codec.SAT like
     every state-mutating path. Shared by build_sync_step and
     build_sync_phases."""
-    row_leaf, rowcount, live = ctx.local_slices()
-    me = jax.lax.axis_index(ctx.peer_ax)
-    s_all = scales_all[:, row_leaf]  # (n_peer, rows_local)
-    s_all = jnp.where((jnp.arange(ctx.n_peer) == me)[:, None], 0.0, s_all)
-    if pallas_tier:
-        from ..ops import codec_pallas
+    with jax.named_scope("st.codec_apply"):
+        row_leaf, rowcount, live = ctx.local_slices()
+        with jax.named_scope("st.row_scales"):
+            me = jax.lax.axis_index(ctx.peer_ax)
+            s_all = scales_all[:, row_leaf]  # (n_peer, rows_local)
+            s_all = jnp.where((jnp.arange(ctx.n_peer) == me)[:, None], 0.0, s_all)
+        with jax.named_scope("st.words_layout"):
+            if pallas_tier:
+                words = (
+                    words_all.reshape(ctx.n_peer, ctx.rows_local, LANES // 32)
+                    .transpose(1, 0, 2)
+                    .reshape(ctx.rows_local, ctx.n_peer * (LANES // 32))
+                )
+            else:
+                words = (
+                    unpack_bits(words_all)
+                    .reshape(ctx.n_peer, ctx.rows_local, LANES)
+                    .astype(jnp.float32)
+                )
+        with jax.named_scope("st.apply"):
+            if pallas_tier:
+                from ..ops import codec_pallas
 
-        words2d = (
-            words_all.reshape(ctx.n_peer, ctx.rows_local, LANES // 32)
-            .transpose(1, 0, 2)
-            .reshape(ctx.rows_local, ctx.n_peer * (LANES // 32))
-        )
-        (v2,) = codec_pallas.apply_rows_batch(
-            s_all.T, rowcount, words2d, (values,)
-        )
-        return v2
-    v = values.reshape(ctx.rows_local, LANES)
-    bits_all = (
-        unpack_bits(words_all)
-        .reshape(ctx.n_peer, ctx.rows_local, LANES)
-        .astype(jnp.float32)
-    )
-    # elementwise+sum (VPU): s is a power of 2 and bits are 0/1, but under
-    # RMS policy s is arbitrary — keep the arithmetic exact f32, no MXU
-    delta = jnp.sum(s_all[:, :, None] * (1.0 - 2.0 * bits_all), axis=0)
-    v2 = jnp.where(live, jnp.clip(v + delta, -SAT, SAT), 0.0)
-    return v2.reshape(-1)
+                (v2,) = codec_pallas.apply_rows_batch(
+                    s_all.T, rowcount, words, (values,)
+                )
+                return v2
+            v = values.reshape(ctx.rows_local, LANES)
+            # elementwise+sum (VPU): s is a power of 2 and bits are 0/1, but
+            # under RMS policy s is arbitrary — keep the arithmetic exact
+            # f32, no MXU
+            delta = jnp.sum(s_all[:, :, None] * (1.0 - 2.0 * words), axis=0)
+            v2 = jnp.where(live, jnp.clip(v + delta, -SAT, SAT), 0.0)
+            return v2.reshape(-1)
 
 
 def build_sync_step(

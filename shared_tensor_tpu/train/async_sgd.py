@@ -46,6 +46,7 @@ from ..parallel.ici import (
     init_state,
     read_peer,
 )
+from ..utils import profiling
 
 
 def build_train_step(
@@ -114,9 +115,16 @@ def build_train_step(
     grad_fn = jax.value_and_grad(loss_fn)
 
     def per_peer(values_row: jnp.ndarray, batch_item):
-        params = unflatten(values_row, spec)
-        loss, grads = grad_fn(params, batch_item)
-        return loss, flatten(grads, spec)
+        with jax.named_scope("st.grads"):
+            params = unflatten(values_row, spec)
+            loss, grads = grad_fn(params, batch_item)
+            return loss, flatten(grads, spec)
+
+    def update_of(g, opt_state, values, lr):
+        with jax.named_scope("st.update"):
+            if optimizer is None:
+                return -lr * g, opt_state
+            return jax.vmap(optimizer.update)(g, opt_state, values)
 
     def _step(state: PeerSyncState, opt_state, batch, lr):
         if phases is not None:
@@ -129,22 +137,12 @@ def build_train_step(
             send, apply_gathered = phases
             r2, words_all, scales_all = send(state.residual)
             losses, g = jax.vmap(per_peer)(state.values, batch)
-            if optimizer is None:
-                updates = -lr * g
-            else:
-                updates, opt_state = jax.vmap(optimizer.update)(
-                    g, opt_state, state.values
-                )
+            updates, opt_state = update_of(g, opt_state, state.values, lr)
             v2 = apply_gathered(state.values, words_all, scales_all)
             state = add_updates_raw(PeerSyncState(v2, r2), updates)
             return state, opt_state, losses, scales_all
         losses, g = jax.vmap(per_peer)(state.values, batch)
-        if optimizer is None:
-            updates = -lr * g
-        else:
-            updates, opt_state = jax.vmap(optimizer.update)(
-                g, opt_state, state.values
-            )
+        updates, opt_state = update_of(g, opt_state, state.values, lr)
         state = add_updates_raw(state, updates)
         if sync_raw is not None:
             state, scales = sync_raw(state)
@@ -221,20 +219,27 @@ class PodTrainer:
             sh = NamedSharding(self.mesh, P(ax, *([None] * (x.ndim - 1))))
             return jax.device_put(x, sh)
 
-        return jax.tree.map(put, batch)
+        with jax.profiler.TraceAnnotation("st:shard_batch"):
+            return jax.tree.map(put, batch)
 
     def step(self, batch: Any, lr: float = 1e-2):
         """One fused train step (+sync on every ``sync_every``-th call).
         Returns (per-peer losses f32[n_peer], per-peer-leaf scales); state
         advances in place. With an optax ``optimizer``, ``lr`` is ignored
         (the transform owns the step size)."""
-        fn = self._step
-        if self._step_local is not None and (self.steps + 1) % self.sync_every:
-            fn = self._step_local
-        self.state, self.opt_state, losses, scales = fn(
-            self.state, self.opt_state, batch, jnp.float32(lr)
-        )
+        pod = profiling.pod_tier()
+        pod.step_now = self.steps  # a compilation in here is this step's
+        with jax.profiler.StepTraceAnnotation(
+            "st:train.step", step_num=self.steps
+        ):
+            fn = self._step
+            if self._step_local is not None and (self.steps + 1) % self.sync_every:
+                fn = self._step_local
+            self.state, self.opt_state, losses, scales = fn(
+                self.state, self.opt_state, batch, jnp.float32(lr)
+            )
         self.steps += 1
+        pod.count_step(self.sync and fn is self._step)
         return losses, scales
 
     def lower(self, batch: Any, lr: float = 1e-2):
@@ -254,7 +259,8 @@ class PodTrainer:
     def add(self, updates: jax.Array) -> None:
         """Out-of-band additive update, [n_peer, spec.total] flat (reference
         addFromTensor outside the training loop)."""
-        self.state = add_updates(self.state, updates)
+        with jax.profiler.TraceAnnotation("st:add"):
+            self.state = add_updates(self.state, updates)
 
     def replica_spread(self) -> float:
         """Max abs deviation of any replica from the peer mean — the

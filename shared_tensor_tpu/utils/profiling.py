@@ -1,41 +1,660 @@
-"""Tracing / profiling / rate metrics (SURVEY.md §5.1, §5.5 — the reference
-has no instrumentation beyond lifecycle fprintf lines).
+"""Where a step's time goes, and the pod tier's counters.
 
-Three small tools:
+The chip path names its own work: ``jax.named_scope("st.<name>")`` in
+parallel/ici.py, ops/table.py and train/async_sgd.py, ``name="st_<kernel>"``
+on the Pallas calls of ops/codec_pallas.py, ``st:<name>`` host spans
+(``jax.profiler`` annotations) in ``PodTrainer``. This module captures a
+trace and reads those names back; it knows the ``st.`` / ``st:`` prefixes
+and no single name.
 
-- :func:`trace`: context manager around ``jax.profiler`` producing a
-  TensorBoard-loadable device trace of whatever ran inside (the fused sync
-  step, the codec kernels, a training loop).
-- :class:`RateMeter`: turns the framework's monotonically-increasing
-  counters (SharedTensor.frames_in/out, the canonical
-  ``st_link_bytes_*_total{link=}`` series from ``peer.metrics()``) into
-  rates over a sliding window — frames/s, wire B/s, equivalent fp32-delta
-  B/s, the §6 quantities.
-- :func:`effective_bits`: measured bits/element/frame from a residual-RMS
-  trajectory — the matched-approximation-error yardstick (BASELINE.md's
-  convergence table; 1.0 for the reference on homogeneous data).
+- :func:`trace` — the capture: a ``jax.profiler`` session around whatever
+  runs inside. ``chipbench/run.py --keep-trace DIR`` writes the same files.
+- :func:`scope_map` — ``{(module, instruction): scope}`` from the compiled
+  programs' text: the join that gives a traced operation its scope (a
+  v5e's trace event carries the instruction's text and no JAX name).
+- :func:`scope_times` — the one reduction: per device and step, the self
+  time of every traced operation summed by scope, the rest as ``unscoped``,
+  the Mosaic kernels by name, the ``st:*`` host spans, and each idle gap
+  put down to the host span that covers it.
+  ``python -m shared_tensor_tpu.utils.profiling <dir> [--hlo PATH]`` prints
+  it (:func:`main`).
+- :func:`pod_registry` — the pod tier's counters (``st_pod_*`` in
+  obs/schema.py) in one :class:`~shared_tensor_tpu.obs.registry.Registry`:
+  steps by program, and compilations stamped with the step they fell in.
+- :class:`RateMeter`, :func:`effective_bits` — rates from cumulative
+  counters and bits per element per frame from a residual trajectory (the
+  host tiers' health plane).
 """
 
 from __future__ import annotations
 
+import bisect
 import contextlib
+import glob
 import math
+import os
+import re
+import threading
 import time
 from collections import deque
 from typing import Iterable, Iterator
 
 import jax
 
+SCOPE_PREFIX = "st."
+SPAN_PREFIXES = ("st:", "chipbench:")
+UNSCOPED = "unscoped"
+#: An idle gap shorter than this is the device's own turn-around between
+#: two operations, not something the host did.
+IDLE_GAP_NS = 50_000
+
+COMPILE_EVENT = "/jax/core/compile/backend_compile_duration"
+CACHE_LOAD_EVENT = "/jax/compilation_cache/cache_retrieval_time_sec"
+
 
 @contextlib.contextmanager
 def trace(log_dir: str) -> Iterator[None]:
-    """Device-level profiler trace; view with TensorBoard's profile plugin.
-    Usable around any jitted region (sync step, codec chain, train loop)."""
+    """Profiler session around any jitted region (sync step, train loop);
+    :func:`scope_times` and this module's command line read ``log_dir``."""
     jax.profiler.start_trace(log_dir)
     try:
         yield
     finally:
         jax.profiler.stop_trace()
+
+
+# --- the pod tier's counters ------------------------------------------------
+
+
+class PodTier:
+    """The pod tier's registry and what feeds it: ``PodTrainer.step`` stamps
+    the step it is in and counts it when it returns; one ``jax.monitoring``
+    listener counts backend compilations (a load from the persistent cache
+    is one too, and its seconds are kept apart) and stamps each with that
+    step, so "which step recompiled" is a gauge an operator reads."""
+
+    def __init__(self):
+        from ..obs.registry import Registry
+        from ..obs.schema import SCHEMA, label_key
+
+        self.registry = Registry()
+        self.step_now = 0
+        self._steps = {True: 0, False: 0}
+        self._mu = threading.Lock()
+        keys = {
+            synced: label_key("st_pod_steps_total", "program", program)
+            for synced, program in ((True, "sync"), (False, "local"))
+        }
+        self.registry.register_collector(
+            lambda: {keys[synced]: n for synced, n in self._steps.items()}
+        )
+        instrument = lambda make, name: make(name, SCHEMA[name][1])
+        self._compiles = instrument(self.registry.counter, "st_pod_compiles_total")
+        self._compile_s = instrument(
+            self.registry.counter, "st_pod_compile_seconds_total"
+        )
+        self._cache_load_s = instrument(
+            self.registry.counter, "st_pod_cache_load_seconds_total"
+        )
+        self._last_compile_step = instrument(
+            self.registry.gauge, "st_pod_last_compile_step"
+        )
+        jax.monitoring.register_event_duration_secs_listener(self._on_duration)
+
+    def count_step(self, synced: bool) -> None:
+        """One completed ``PodTrainer.step``; ``synced`` says whether the
+        program it ran held the exchange (the sync beat) or not."""
+        with self._mu:
+            self._steps[synced] += 1
+
+    def _on_duration(self, event: str, seconds: float, **_kw) -> None:
+        if event == COMPILE_EVENT:
+            self._compiles.inc()
+            self._compile_s.inc(max(0.0, seconds))
+            self._last_compile_step.set(self.step_now)
+        elif event == CACHE_LOAD_EVENT:
+            self._cache_load_s.inc(max(0.0, seconds))
+
+
+_pod_tier: PodTier | None = None
+_pod_tier_mu = threading.Lock()
+
+
+def pod_tier() -> PodTier:
+    """The process's one :class:`PodTier`, made on first use (importing this
+    module registers no listener)."""
+    global _pod_tier
+    if _pod_tier is None:
+        with _pod_tier_mu:
+            if _pod_tier is None:
+                _pod_tier = PodTier()
+    return _pod_tier
+
+
+def pod_registry():
+    """The pod tier's :class:`~shared_tensor_tpu.obs.registry.Registry`;
+    ``pod_registry().prometheus_text()`` is the operator's exporter."""
+    return pod_tier().registry
+
+
+# --- from the compiled program's text: which scope an instruction is in -------
+
+_SCOPE = re.compile(r"\bst\.[A-Za-z_][A-Za-z0-9_]*")
+_MODULE = re.compile(r"^HloModule (\S+?),", re.M)
+_COMPUTATION = re.compile(r"^(?:ENTRY )?%?([^\s(]+) \(.*\) -> .*\{\s*$")
+_INSTRUCTION = re.compile(r"^\s+(ROOT )?%?(\S+) = ")
+_OP_NAME = re.compile(r'op_name="([^"]*)"')
+_CALLEES = re.compile(
+    r"\b(?:calls|to_apply|body|condition|true_computation|false_computation)=%?([^\s,)}]+)"
+)
+_BRANCHES = re.compile(r"\bbranch_computations=\{([^}]*)\}")
+_NAME = re.compile(r"%?([A-Za-z_][\w.\-]*)")
+
+
+def scope_of(op_name: str) -> str:
+    """The ``st.*`` scopes of a JAX ``op_name`` (``jit(_step)/vmap(st.grads)/
+    st.flatten/pad``), outermost first and joined by ``/``:
+    ``st.grads/st.flatten``. The last component is the innermost scope.
+    Empty when there is none. The name's own last component is the
+    primitive, or a whole argument's path (``st.values`` where a caller
+    named its state ``st``), and is no scope. Of metadata XLA merged
+    (``a;b``) the first name that has a scope counts."""
+    for name in op_name.split(";"):
+        scope = "/".join(_SCOPE.findall(name.rpartition("/")[0]))
+        if scope:
+            return scope
+    return ""
+
+
+def innermost(scope: str) -> str:
+    return scope.rsplit("/", 1)[-1]
+
+
+def _parse_hlo(text: str):
+    """``(module, {computation: [(instruction, op_name, callees, is_root,
+    operands)]})`` of one module's text, as ``compiled.as_text()`` or an XLA
+    dump prints it (with or without ``%`` before names). ``operands`` are
+    the instructions of the same computation the line names."""
+    m = _MODULE.search(text)
+    module = m.group(1) if m else ""
+    comps: dict[str, list] = {}
+    current = None
+    for line in text.splitlines():
+        if current is None:
+            c = _COMPUTATION.match(line)
+            if c:
+                current = comps.setdefault(c.group(1), [])
+            continue
+        if line.startswith("}"):
+            current = None
+            continue
+        i = _INSTRUCTION.match(line)
+        if not i:
+            continue
+        # a Mosaic call's backend_config is the whole kernel: names are
+        # looked for in front of it
+        head = line.split("backend_config=", 1)[0] if len(line) > 8192 else line
+        op = _OP_NAME.search(line)
+        callees = _CALLEES.findall(head)
+        for group in _BRANCHES.findall(head):
+            callees += [c.strip().lstrip("%") for c in group.split(",") if c.strip()]
+        current.append((
+            i.group(2), op.group(1) if op else "", callees, bool(i.group(1)),
+            _NAME.findall(head[i.end():]),
+        ))
+    for instrs in comps.values():
+        names = {name for name, *_ in instrs}
+        for k, (name, op, callees, root, words) in enumerate(instrs):
+            instrs[k] = (name, op, callees, root,
+                         [w for w in words if w in names and w != name])
+    return module, comps
+
+
+def scope_map(*compiled_or_hlo_text) -> dict[tuple[str, str], str]:
+    """``{(module, instruction): scope}`` for every instruction of the given
+    programs (``jit(f).lower(...).compile()`` objects or their
+    ``as_text()``), read from ``metadata={op_name=...}``; the scope is
+    :func:`scope_of`'s path. An instruction whose own metadata names no
+    ``st.`` scope takes, in this order: the scope of the computation it
+    calls (a fusion's or a ``while`` body's root, else that computation's
+    most frequent scope); the scope of the instruction its computation is
+    called from; and, for what the compiler put in with no name at all (a
+    copy to another memory, a layout change, a loop around a collective),
+    the scope of the first instruction that uses its result, else of the
+    first whose result it uses. Instructions that no rule reaches are left
+    out."""
+    out: dict[tuple[str, str], str] = {}
+    for src in compiled_or_hlo_text:
+        text = src if isinstance(src, str) else src.as_text()
+        module, comps = _parse_hlo(text)
+        own = {  # (scope, callees, has no metadata at all)
+            (comp, name): (scope_of(op), callees, not op)
+            for comp, instrs in comps.items()
+            for name, op, callees, _, _ in instrs
+        }
+        called_from: dict[str, tuple[str, str]] = {}
+        for (comp, name), (_, callees, _) in own.items():
+            for callee in callees:
+                called_from.setdefault(callee, (comp, name))
+        up_memo: dict[tuple[str, str], str] = {}
+        comp_memo: dict[str, str] = {}
+
+        def up(comp: str, name: str) -> str:
+            """Own scope, else what the called computations give."""
+            key = (comp, name)
+            if key not in up_memo:
+                scope, callees, _ = own[key]
+                up_memo[key] = scope  # HLO's call graph has no cycle; be safe
+                for callee in () if scope else callees:
+                    scope = of_computation(callee)
+                    if scope:
+                        break
+                up_memo[key] = scope
+            return up_memo[key]
+
+        def of_computation(comp: str) -> str:
+            if comp not in comp_memo:
+                comp_memo[comp] = ""
+                scopes = [(up(comp, n), root) for n, _, _, root, _ in comps.get(comp, ())]
+                rooted = [s for s, root in scopes if root and s]
+                named = [s for s, _ in scopes if s]
+                if rooted:
+                    comp_memo[comp] = rooted[0]
+                elif named:
+                    comp_memo[comp] = max(named, key=named.count)
+            return comp_memo[comp]
+
+        found = {key: up(*key) for key in own if up(*key)}
+        users: dict[tuple[str, str], list[str]] = {}
+        operands_of: dict[tuple[str, str], list[str]] = {}
+        for comp, instrs in comps.items():
+            for name, _, _, _, operands in instrs:
+                operands_of[(comp, name)] = operands
+                for o in operands:
+                    users.setdefault((comp, o), []).append(name)
+        for near in (users, operands_of):  # consumers settle before producers
+            changed = True
+            while changed:
+                changed = False
+                for comp, name in own:
+                    if (comp, name) in found:
+                        continue
+                    at, scope = comp, ""
+                    while not scope and at in called_from:
+                        at, caller = called_from[at]
+                        scope = found.get((at, caller), "")
+                    if not scope and own[(comp, name)][2]:
+                        scope = next(
+                            (found[(comp, o)] for o in near.get((comp, name), ())
+                             if (comp, o) in found),
+                            "",
+                        )
+                    if scope:
+                        found[(comp, name)] = scope
+                        changed = True
+        out.update({(module, name): scope for (_, name), scope in found.items()})
+    return out
+
+
+def hlo_texts(path: str) -> list[str]:
+    """The program texts under ``path``: one file, or a directory that
+    ``--xla_dump_to`` wrote (its ``*after_optimizations.txt`` files)."""
+    if os.path.isdir(path):
+        files = sorted(glob.glob(os.path.join(path, "*after_optimizations.txt")))
+    else:
+        files = [path]
+    texts = []
+    for f in files:
+        with open(f, errors="replace") as fh:
+            texts.append(fh.read())
+    return texts
+
+
+# --- from a trace: whose every microsecond is ----------------------------------
+
+_DEVICE_PLANE = re.compile(r"^/device:TPU:(\d+)$")
+_HOST_PLANE = "/host:CPU"
+_OPS_LINE = "XLA Ops"
+_MODULES_LINE = "XLA Modules"
+_HLO_EVENT = re.compile(r"^%(\S+) = (.*)$", re.S)
+_OPCODE = re.compile(r"(?:^| )([a-z][a-z0-9\-]*)\(")
+_LAYOUT = re.compile(r"\{[^{}]*\}")
+_KERNEL_TARGET = 'custom_call_target="tpu_custom_call"'
+
+
+def newest_xplane(trace_dir: str) -> str:
+    found = glob.glob(os.path.join(trace_dir, "**", "*.xplane.pb"), recursive=True)
+    if not found:
+        raise FileNotFoundError(f"no *.xplane.pb under {trace_dir}")
+    return max(found, key=os.path.getmtime)
+
+
+def _instruction_of(event_name: str) -> tuple[str, str, str]:
+    """(instruction, label, kernel name or "") of one operation event. A TPU
+    trace names an event by its whole HLO text (``%fusion.3 = f32[201]{...}
+    fusion(...)``): the label is ``fusion.3 = f32[201] fusion``. A CPU trace
+    names it by the instruction alone."""
+    m = _HLO_EVENT.match(event_name)
+    if not m:
+        return event_name, event_name, ""
+    name, rest = m.group(1), m.group(2)
+    op = _OPCODE.search(rest)
+    shape = _LAYOUT.sub("", rest[: op.start()] if op else "").strip()
+    label = f"{name} = {shape} {op.group(1) if op else ''}"[:96]
+    kernel = re.sub(r"\.\d+$", "", name) if _KERNEL_TARGET in rest else ""
+    return name, label, kernel
+
+
+def self_times(events: list) -> list[float]:
+    """Self time of each ``(start, end)`` of one line, in the order given:
+    an event that encloses others (a ``while`` and its body's operations)
+    keeps only what they do not cover."""
+    order = sorted(range(len(events)), key=lambda i: (events[i][0], -events[i][1]))
+    own = [e[1] - e[0] for e in events]
+    stack: list[int] = []
+    for i in order:
+        start, end = events[i]
+        while stack and events[stack[-1]][1] <= start:
+            stack.pop()
+        if stack:
+            own[stack[-1]] -= min(end, events[stack[-1]][1]) - start
+        stack.append(i)
+    return own
+
+
+def _union(intervals: list) -> list[tuple[float, float]]:
+    out: list[list[float]] = []
+    for a, b in sorted(intervals):
+        if out and a <= out[-1][1]:
+            out[-1][1] = max(out[-1][1], b)
+        elif b > a:
+            out.append([a, b])
+    return [(a, b) for a, b in out]
+
+
+def attribute_gap(a: float, b: float, spans: list) -> str:
+    """The host span ``(name, start, end)`` that covers most of the idle gap
+    ``[a, b)``; of two that cover the same, the shorter (the inner one)."""
+    best, best_key = "unattributed", (0.0, 0.0)
+    for name, s, e in spans:
+        cover = min(b, e) - max(a, s)
+        if cover > 0 and (cover, s - e) > best_key:
+            best, best_key = name, (cover, s - e)
+    return best
+
+
+def _read_trace(path: str):
+    """(operation lines, module runs, host spans) of a profiler file.
+
+    ``lines`` is ``{device: [[(start, end, module, instruction, label,
+    kernel)]]}``, one inner list per profiler line (self time is taken
+    within a line): the ``XLA Ops`` line of every ``/device:TPU:<n>`` plane,
+    whose events name no module and get the program running at the time
+    (``XLA Modules``), or, on the CPU, the host threads' events that carry
+    ``hlo_op``, by ``device_ordinal``. ``runs`` is ``{device: [(start, end,
+    module)]}``; ``spans`` the ``st:*`` / ``chipbench:*`` host spans as
+    ``(name, start, end, step_num or None)``."""
+    from jax.profiler import ProfileData
+
+    lines: dict[str, list] = {}
+    runs: dict[str, list] = {}
+    spans: list = []
+    planes = list(ProfileData.from_file(path).planes)
+    for plane in planes:
+        dev = _DEVICE_PLANE.match(plane.name)
+        for line in plane.lines if dev else ():
+            if line.name == _MODULES_LINE:
+                runs[dev.group(1)] = sorted(
+                    (e.start_ns, e.start_ns + e.duration_ns,
+                     re.sub(r"\(-?\d+\)$", "", e.name))
+                    for e in line.events
+                )
+    for plane in planes:
+        dev = _DEVICE_PLANE.match(plane.name)
+        if dev:
+            ran = runs.get(dev.group(1), [])
+            starts = [a for a, _, _ in ran]
+            for line in plane.lines:
+                if line.name != _OPS_LINE:
+                    continue
+                evs = []
+                for e in line.events:
+                    k = bisect.bisect_right(starts, e.start_ns) - 1
+                    module = ran[k][2] if k >= 0 and e.start_ns < ran[k][1] else ""
+                    evs.append((e.start_ns, e.start_ns + e.duration_ns, module,
+                                *_instruction_of(e.name)))
+                lines.setdefault(dev.group(1), []).append(evs)
+        elif plane.name == _HOST_PLANE:
+            for line in plane.lines:
+                by_dev: dict[str, list] = {}
+                for e in line.events:
+                    st = dict(e.stats)
+                    if e.name.startswith(SPAN_PREFIXES):
+                        spans.append((e.name, e.start_ns, e.start_ns + e.duration_ns,
+                                      st.get("step_num")))
+                    elif e.duration_ns > 0 and "hlo_op" in st:
+                        op = str(st["hlo_op"])
+                        by_dev.setdefault(str(st.get("device_ordinal", 0)), []).append(
+                            (e.start_ns, e.start_ns + e.duration_ns,
+                             str(st.get("hlo_module", "")), op, op, "")
+                        )
+                for d, evs in by_dev.items():
+                    lines.setdefault(d, []).append(evs)
+    return lines, runs, spans
+
+
+def _pick_programs(maps, lines: dict) -> dict:
+    """One map from several: for every module name, the entries of the map
+    that knows most of the instructions the trace shows under that name."""
+    traced: dict[str, set] = {}
+    for dev_lines in lines.values():
+        for evs in dev_lines:
+            for _, _, module, instr, _, _ in evs:
+                traced.setdefault(module, set()).add(instr)
+    best: dict[str, tuple[int, dict]] = {}
+    for m in maps:
+        by_module: dict[str, dict] = {}
+        for (module, instr), scope in m.items():
+            by_module.setdefault(module, {})[(module, instr)] = scope
+        for module, entries in by_module.items():
+            hits = sum((module, i) in entries for i in traced.get(module, ()))
+            if module not in best or hits > best[module][0]:
+                best[module] = (hits, entries)
+    return {k: v for _, entries in best.values() for k, v in entries.items()}
+
+
+def _tally(into: dict, name: str, seconds: float, count: int = 1) -> None:
+    t = into.setdefault(name, {"count": 0, "total_s": 0.0})
+    t["count"] += count
+    t["total_s"] += seconds
+
+
+def scope_times(trace_dir: str, scope_map=None, steps: int | None = None) -> dict:
+    """Device time by scope, from the newest profiler file under
+    ``trace_dir`` (what :func:`trace` or ``chipbench/run.py --keep-trace``
+    wrote). Every figure in seconds; ``scopes``, ``unscoped``, ``kernels``
+    and ``ops`` are per device and per step (``steps``: how many the trace
+    holds; by default the runs of the program that took most device time,
+    1 where the trace has no ``XLA Modules`` line).
+
+    A v5e's trace events carry no JAX name, so an operation's scope comes
+    from ``scope_map`` (:func:`scope_map`) by the event's module and
+    instruction; without an entry it is ``unscoped``. ``scope_map`` may be a
+    list of maps, one a program: of several programs of one name (a
+    trainer's sync and off-beat steps are both ``jit__step``, and XLA
+    numbers their instructions alike) the one that holds most of that
+    module's traced instructions is taken. Self times: an event that
+    encloses others on its line counts only what they do not cover, so
+    scopes and ``unscoped`` add up to ``self_s``."""
+    path = newest_xplane(trace_dir)
+    lines, runs, spans = _read_trace(path)
+    if not isinstance(scope_map, dict):
+        scope_map = _pick_programs(scope_map or (), lines)
+    if steps is None:
+        module_s: dict[str, float] = {}
+        module_runs: dict[str, int] = {}
+        for ran in runs.values():
+            for a, b, module in ran:
+                module_s[module] = module_s.get(module, 0.0) + b - a
+                module_runs[module] = module_runs.get(module, 0) + 1
+        steps = 1
+        if module_s:
+            steps = max(1, module_runs[max(module_s, key=module_s.get)] // len(runs))
+    host = [(n, s, e) for n, s, e, _ in spans]
+    per_device = {}
+    ops: dict[tuple[str, str], float] = {}
+    joined = 0
+    for dev in sorted(lines):
+        by_scope: dict[str, float] = {}
+        kernels: dict[str, float] = {}
+        intervals = []
+        for evs in lines[dev]:
+            for (start, end, module, instr, label, kernel), own in zip(
+                evs, self_times([(e[0], e[1]) for e in evs])
+            ):
+                intervals.append((start, end))
+                scope = scope_map.get((module, instr), UNSCOPED)
+                joined += scope is not UNSCOPED
+                by_scope[scope] = by_scope.get(scope, 0.0) + own / 1e9
+                if kernel:
+                    kernels[kernel] = kernels.get(kernel, 0.0) + own / 1e9
+                ops[(label, scope)] = ops.get((label, scope), 0.0) + own / 1e9
+        busy = _union(intervals)
+        lo, hi = busy[0][0], busy[-1][1]
+        # the device also idles from the start of the longest host span (a
+        # benchmark's window) to its first operation, and from its last
+        # operation to that span's end
+        around = max(
+            ((s, e) for _, s, e in host if s < hi and e > lo),
+            key=lambda se: se[1] - se[0], default=(lo, hi),
+        )
+        edges = (
+            [(around[0], around[0])] * (around[0] < lo)
+            + busy
+            + [(around[1], around[1])] * (around[1] > hi)
+        )
+        inner = [h for h in host if (h[1], h[2]) != around]
+        gaps: dict[str, dict] = {}
+        for (_, a), (b, _) in zip(edges, edges[1:]):
+            if b - a > IDLE_GAP_NS:
+                # that enclosing span says nothing: it counts only where no
+                # span inside it covers any of the gap
+                doing = attribute_gap(a, b, inner)
+                if doing == "unattributed":
+                    doing = attribute_gap(a, b, host)
+                _tally(gaps, doing, (b - a) / 1e9)
+        per_device[dev] = {
+            "self_s": sum(by_scope.values()),
+            "busy_s": sum(b - a for a, b in busy) / 1e9,
+            "scopes": by_scope,
+            "kernels": kernels,
+            "idle_gaps": gaps,
+        }
+    if not per_device:
+        raise ValueError(f"{path} holds no device operation")
+    n = len(per_device)
+
+    def mean_of(key: str) -> dict[str, float]:
+        total: dict[str, float] = {}
+        for d in per_device.values():
+            for k, v in d[key].items():
+                total[k] = total.get(k, 0.0) + v
+        return {k: v / n / steps for k, v in sorted(total.items(), key=lambda kv: -kv[1])}
+
+    scopes = mean_of("scopes")
+    unscoped = scopes.pop(UNSCOPED, 0.0)
+    host_spans: dict[str, dict] = {}
+    for name, s, e, _ in spans:
+        _tally(host_spans, name, (e - s) / 1e9)
+    idle: dict[str, dict] = {}
+    for d in per_device.values():
+        for name, g in d["idle_gaps"].items():
+            _tally(idle, name, g["total_s"] / n, g["count"])
+    return {
+        "file": path,
+        "devices": n,
+        "steps": steps,
+        "self_s": sum(d["self_s"] for d in per_device.values()) / n / steps,
+        "busy_s": sum(d["busy_s"] for d in per_device.values()) / n / steps,
+        "scopes": scopes,
+        "unscoped": unscoped,
+        "kernels": mean_of("kernels"),
+        "ops": [
+            [label, scope, v / n / steps]
+            for (label, scope), v in sorted(ops.items(), key=lambda kv: -kv[1])
+        ],
+        "operations": sum(len(evs) for dev_lines in lines.values() for evs in dev_lines),
+        "operations_scoped": joined,
+        "host_spans": host_spans,
+        "step_spans": sorted(
+            (step, s, e) for name, s, e, step in spans if step is not None
+        ),
+        "idle_gaps": idle,
+        "per_device": per_device,
+    }
+
+
+def format_table(t: dict, top_ops: int = 24) -> str:
+    """:func:`scope_times`' result as the table PERF.md prints."""
+    ms = lambda s: f"{1e3 * s:10.3f}"
+    share = lambda s: f"{100 * s / t['self_s']:6.2f} %" if t["self_s"] else ""
+    out = [
+        f"{t['file']}",
+        f"devices {t['devices']}, steps {t['steps']}: ms per device and step "
+        f"(self time {ms(t['self_s']).strip()}, busy {ms(t['busy_s']).strip()}); "
+        f"{t['operations_scoped']} of {t['operations']} operations scoped",
+        f"{'ms/step':>10}  {'share':>8}  scope",
+    ]
+    for scope, v in t["scopes"].items():
+        out.append(f"{ms(v)}  {share(v)}  {scope}")
+    out.append(f"{ms(t['unscoped'])}  {share(t['unscoped'])}  {UNSCOPED}")
+    if t["kernels"]:
+        out.append("kernels (Mosaic custom calls, by name):")
+        out += [f"{ms(v)}  {share(v)}  {k}" for k, v in t["kernels"].items()]
+    out.append(f"operations, the {top_ops} longest:")
+    out += [f"{ms(v)}  {share(v)}  {label}  [{scope}]" for label, scope, v in t["ops"][:top_ops]]
+    if t["host_spans"]:
+        out.append("host spans (count, total ms):")
+        out += [
+            f"{h['count']:>10}  {ms(h['total_s'])}  {name}"
+            for name, h in sorted(t["host_spans"].items())
+        ]
+    if t["idle_gaps"]:
+        out.append(f"idle gaps over {IDLE_GAP_NS // 1000} us per device (count, total ms), by host span:")
+        out += [
+            f"{g['count']:>10}  {ms(g['total_s'])}  {name}"
+            for name, g in sorted(t["idle_gaps"].items(), key=lambda kv: -kv[1]["total_s"])
+        ]
+    return "\n".join(out)
+
+
+def main(argv=None) -> int:
+    import argparse
+    import json
+
+    ap = argparse.ArgumentParser(
+        prog="python -m shared_tensor_tpu.utils.profiling",
+        description="Device time by st.* scope from a kept profiler trace.",
+    )
+    ap.add_argument("trace_dir", help="what profiling.trace() or run.py --keep-trace wrote")
+    ap.add_argument("--hlo", action="append", default=[], metavar="PATH",
+                    help="compiled program text, or an --xla_dump_to directory, for "
+                    "the join by instruction name (repeatable)")
+    ap.add_argument("--steps", type=int, default=None, help="steps the trace holds")
+    ap.add_argument("--ops", type=int, default=24, help="operations to list")
+    ap.add_argument("--json", action="store_true", help="print the whole result as JSON")
+    args = ap.parse_args(argv)
+    maps = [scope_map(t) for path in args.hlo for t in hlo_texts(path)]
+    table = scope_times(args.trace_dir, maps, steps=args.steps)
+    if args.json:
+        del table["per_device"]
+        print(json.dumps(table))
+    else:
+        print(format_table(table, args.ops))
+    return 0
+
+
+# --- the host tiers' rate meters ----------------------------------------------
 
 
 class RateMeter:
@@ -134,3 +753,9 @@ def effective_bits(rms_trajectory: Iterable[float]) -> float:
     if last <= 0:  # exact convergence: count bits down to fp32 epsilon
         last = first * 2.0**-24
     return math.log2(first / last) / (len(traj) - 1)
+
+
+if __name__ == "__main__":
+    import sys
+
+    sys.exit(main())

@@ -116,3 +116,189 @@ def test_trace_writes_profile(tmp_path):
     # the profiler must have produced a trace artifact
     produced = list(tmp_path.rglob("*"))
     assert produced, "no profile output written"
+
+
+# --- PR 27: the by-scope reduction's arithmetic, on made-up lines ---------------
+
+
+def test_scope_of_reads_scopes_through_transformations():
+    from shared_tensor_tpu.utils.profiling import innermost, scope_of
+
+    assert scope_of("jit(_step)/vmap(st.grads)/st.flatten/jit(_pad)/pad") == "st.grads/st.flatten"
+    assert scope_of("jit(_step)/vmap(st.grads)/transpose(jvp())/mul") == "st.grads"
+    # of names XLA merged, the first that has a scope
+    assert scope_of("jit(f)/mul;jit(f)/st.update/neg;jit(f)/st.grads/add") == "st.update"
+    # an argument a caller named ``st`` is no scope
+    assert scope_of("st.values") == "" and scope_of("jit(f)/st.values") == ""
+    assert scope_of("jit(sync_step)/jit(main)/mul") == ""
+    assert scope_of("state.values") == "" and scope_of("first.step/add") == ""
+    assert innermost("st.codec_send/st.leaf_scales") == "st.leaf_scales"
+    assert innermost("st.grads") == "st.grads"
+
+
+def test_self_time_counts_an_enclosing_event_once():
+    """A ``while`` event spans its body's operations on the same line: it
+    keeps what they do not cover, and the line's self times add up to the
+    union of its events."""
+    from shared_tensor_tpu.utils.profiling import self_times
+
+    events = [
+        (0.0, 10.0),     # fusion
+        (10.0, 110.0),   # while.2 ...
+        (12.0, 40.0),    # ... its body: slice
+        (40.0, 95.0),    # ... reduce, which itself encloses
+        (50.0, 60.0),    # ... a nested call
+        (110.0, 130.0),  # all-gather
+    ]
+    own = self_times(events)
+    assert own == [10.0, 100.0 - 28.0 - 55.0, 28.0, 45.0, 10.0, 20.0]
+    assert sum(own) == 130.0
+    # the order of the events does not matter
+    back = self_times(events[::-1])
+    assert back[::-1] == own
+    assert self_times([]) == []
+
+
+def test_idle_gap_goes_to_the_span_that_covers_most_of_it():
+    from shared_tensor_tpu.utils.profiling import attribute_gap
+
+    spans = [
+        ("chipbench:window", 0.0, 1000.0),
+        ("chipbench:dispatch", 100.0, 200.0),
+        ("st:train.step", 110.0, 190.0),
+        ("chipbench:wait_step", 200.0, 400.0),
+    ]
+    # wholly inside three spans: the innermost (shortest) one
+    assert attribute_gap(120.0, 180.0, spans) == "st:train.step"
+    # the dispatch covers all of it, the step only a part
+    assert attribute_gap(100.0, 150.0, spans) == "chipbench:dispatch"
+    # mostly the wait, though the window covers all: the window covers most
+    assert attribute_gap(190.0, 300.0, spans) == "chipbench:window"
+    assert attribute_gap(210.0, 300.0, spans) == "chipbench:wait_step"
+    assert attribute_gap(2000.0, 2100.0, spans) == "unattributed"
+    assert attribute_gap(0.0, 50.0, []) == "unattributed"
+
+
+def test_scope_map_inherits_through_called_computations():
+    from shared_tensor_tpu.utils.profiling import scope_map
+
+    text = """HloModule jit_f, is_scheduled=true
+
+%fused_a (p: f32[8]) -> f32[8] {
+  %p = f32[8] parameter(0)
+  %neg.1 = f32[8] negate(%p), metadata={op_name="jit(f)/st.codec_send/st.quantize/neg"}
+  ROOT %add.1 = f32[8] add(%neg.1, %p), metadata={op_name="jit(f)/st.codec_apply/st.apply/add"}
+}
+
+%body (q: f32[8]) -> f32[8] {
+  %q = f32[8] parameter(0)
+  %slice.7 = f32[8] slice(%q), slice={[0:8]}
+  ROOT %copy.3 = f32[8] copy(%slice.7)
+}
+
+%cond (r: f32[8]) -> pred[] {
+  %r = f32[8] parameter(0)
+  ROOT %lt = pred[] constant(false)
+}
+
+ENTRY %main (a: f32[8]) -> f32[8] {
+  %a = f32[8] parameter(0), metadata={op_name="a"}
+  %fusion.3 = f32[8] fusion(%a), kind=kLoop, calls=%fused_a
+  %copy-start.1 = (f32[8], f32[8], u32[]) copy-start(%fusion.3)
+  %copy-done.1 = f32[8] copy-done(%copy-start.1)
+  %while.2 = f32[8] while(%copy-done.1), condition=%cond, body=%body, metadata={op_name="jit(f)/st.codec_send/st.allgather/while"}
+  %mul.9 = f32[8] multiply(%while.2, %a), metadata={op_name="jit(f)/mul"}
+  ROOT %bitcast.5 = f32[8] bitcast(%mul.9)
+}
+"""
+    m = scope_map(text)
+    # a fusion with no name of its own takes its root's scope
+    assert m[("jit_f", "fusion.3")] == "st.codec_apply/st.apply"
+    assert m[("jit_f", "neg.1")] == "st.codec_send/st.quantize"
+    # a while body's unnamed operations take the while's scope
+    assert m[("jit_f", "slice.7")] == "st.codec_send/st.allgather"
+    assert m[("jit_f", "copy.3")] == "st.codec_send/st.allgather"
+    assert m[("jit_f", "lt")] == "st.codec_send/st.allgather"
+    # what the compiler put in with no name goes to what uses its result
+    assert m[("jit_f", "copy-start.1")] == "st.codec_send/st.allgather"
+    assert m[("jit_f", "copy-done.1")] == "st.codec_send/st.allgather"
+    # a name with no scope in it stays unscoped, and gives none to the
+    # nameless around it
+    for name in ("mul.9", "a", "bitcast.5"):
+        assert ("jit_f", name) not in m
+    # a dump prints names without the percent sign
+    assert scope_map(text.replace("%", "")) == m
+
+
+# --- PR 27: the pod tier's counters ------------------------------------------------
+
+
+def _pod_trainer(**kw):
+    from shared_tensor_tpu.parallel import make_mesh
+    from shared_tensor_tpu.train import PodTrainer
+
+    tpl = {"w": jnp.ones((16, 128), jnp.float32)}
+    loss = lambda p, b: jnp.mean((b * p["w"][None]) ** 2)
+    tr = PodTrainer(make_mesh(2, 1), tpl, loss, **kw)
+    return tr, lambda rows: tr.shard_batch(jnp.ones((2, rows, 16, 128)))
+
+
+def _pod_counts():
+    from shared_tensor_tpu.obs.schema import label_key
+    from shared_tensor_tpu.utils.profiling import pod_registry
+
+    snap = pod_registry().snapshot()
+    steps = {
+        p: snap[label_key("st_pod_steps_total", "program", p)] for p in ("sync", "local")
+    }
+    return snap, steps
+
+
+def test_pod_step_counter_follows_trainer_steps():
+    tr, batch = _pod_trainer(sync_every=3)
+    _, before = _pod_counts()
+    for _ in range(7):
+        tr.step(batch(4))
+    _, after = _pod_counts()
+    assert tr.steps == 7
+    assert after["sync"] - before["sync"] == 2  # steps 3 and 6
+    assert after["local"] - before["local"] == 5
+    # a trainer that never exchanges runs the local program only
+    tr2, batch2 = _pod_trainer(sync=False)
+    tr2.step(batch2(4))
+    _, last = _pod_counts()
+    assert last["local"] - after["local"] == 1 and last["sync"] == after["sync"]
+
+
+def test_pod_compile_counter_names_the_step_that_recompiled():
+    tr, batch = _pod_trainer()
+    small, large = batch(4), batch(6)
+    tr.step(small)
+    tr.step(small)
+    snap, _ = _pod_counts()
+    compiles = snap["st_pod_compiles_total"]
+    tr.step(small)  # nothing new: no compilation
+    snap, _ = _pod_counts()
+    assert snap["st_pod_compiles_total"] == compiles
+    assert tr.steps == 3
+    tr.step(large)  # a new batch shape: this step (number 3) recompiles
+    snap, _ = _pod_counts()
+    assert snap["st_pod_compiles_total"] > compiles
+    assert snap["st_pod_last_compile_step"] == 3
+    assert snap["st_pod_compile_seconds_total"] > 0.0
+
+
+def test_pod_counters_are_in_the_schema_and_the_exposition():
+    from shared_tensor_tpu.obs.schema import SCHEMA
+    from shared_tensor_tpu.utils.profiling import pod_registry
+
+    names = (
+        "st_pod_steps_total", "st_pod_compiles_total", "st_pod_compile_seconds_total",
+        "st_pod_cache_load_seconds_total", "st_pod_last_compile_step",
+    )
+    text = pod_registry().prometheus_text()
+    for name in names:
+        assert name in SCHEMA, name
+        assert name in text, name
+    assert 'st_pod_steps_total{program="sync"}' in text
+    assert pod_registry() is pod_registry()

@@ -39,6 +39,10 @@ else:
 #: staleness check below: every entry must still occur in the scan.
 ALLOWED_NON_METRICS: dict[str, str] = {
     "st_trace": "Chrome trace_event category tag (trace_export.py)",
+    "st_quantize_rows": "Pallas kernel name (ops/codec_pallas.py), shown in device traces",
+    "st_apply_rows_batch": "Pallas kernel name (ops/codec_pallas.py), shown in device traces",
+    "st_quantize": "Pallas kernel name (ops/codec_pallas.py), shown in device traces",
+    "st_apply_frame_many": "Pallas kernel name (ops/codec_pallas.py), shown in device traces",
 }
 
 #: Dynamic-construction sites that are NOT metric names, keyed by the
