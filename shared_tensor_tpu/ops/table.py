@@ -10,8 +10,9 @@ error after 48 frames). This module provides that capability natively:
 - A pytree is flattened into ONE padded flat buffer, each leaf padded to a
   whole (8,128)-tile multiple so leaf boundaries are row-aligned.
 - Quantization computes an independent power-of-2 RMS scale per leaf
-  (segment reductions), then runs the same sign/error-feedback rule with a
-  per-row scale — still a single pass over HBM, one frame on the wire.
+  (dense reductions over the leaves' static row ranges, :func:`leaf_reduce`),
+  then runs the same sign/error-feedback rule with a per-row scale — still a
+  single pass over HBM, one frame on the wire.
 - The wire frame carries k scales (one per leaf) + the packed bitmask.
 
 With a single-leaf table this is byte-for-byte the reference codec.
@@ -20,8 +21,9 @@ With a single-leaf table this is byte-for-byte the reference codec.
 from __future__ import annotations
 
 import dataclasses
+import itertools
 from functools import partial
-from typing import Any, NamedTuple, Sequence
+from typing import Any, Iterator, NamedTuple
 
 import jax
 import jax.numpy as jnp
@@ -30,6 +32,10 @@ import numpy as np
 from ..config import ScalePolicy
 from .codec import SAT, pad_flat, pow2_floor
 from .packing import LANES, TILE, pack_bits, padded_len, unpack_bits
+
+
+# (start, stop) rows of each leaf, in leaf order
+LeafRanges = tuple[tuple[int, int], ...]
 
 
 class TableFrame(NamedTuple):
@@ -75,8 +81,18 @@ class TableSpec:
         desc = repr((str(self.treedef), self.shapes, self.ns, self.padded))
         return hashlib.sha256(desc.encode()).digest()[:16]
 
+    @property
+    def leaf_rows(self) -> LeafRanges:
+        """Every leaf's ``(start, stop)`` 128-lane rows: contiguous, in leaf
+        order, tiling ``[0, total // 128)``. The static form of the row ->
+        leaf map; :func:`leaf_reduce` and :func:`leaf_expand` run over it."""
+        stops = np.cumsum([p // LANES for p in self.padded]).tolist()
+        return tuple(zip([0] + stops[:-1], stops))
+
     def row_leaf(self) -> np.ndarray:
-        """int32[rows]: leaf index owning each 128-lane row."""
+        """int32[rows]: leaf index owning each 128-lane row (``leaf_rows`` as
+        a vector, for host-side data generation; no device step indexes by
+        it)."""
         return np.repeat(
             np.arange(self.num_leaves, dtype=np.int32),
             [p // LANES for p in self.padded],
@@ -94,6 +110,63 @@ class TableSpec:
                 c[full] = rem
             counts.append(c)
         return np.concatenate(counts)
+
+
+def clip_ranges(ranges: LeafRanges, lo: int, hi: int) -> LeafRanges:
+    """``ranges`` cut to rows ``[lo, hi)`` and shifted to start at 0: what a
+    shard holding those rows sees of each leaf. A leaf outside the window
+    keeps its position as an empty range."""
+    return tuple(
+        (min(max(a, lo), hi) - lo, min(max(b, lo), hi) - lo) for a, b in ranges
+    )
+
+
+def _range_runs(ranges: LeafRanges) -> Iterator[tuple[int, int, int, int]]:
+    """Runs of consecutive equally sized leaves, as ``(first_leaf, n_leaves,
+    start_row, rows_each)``. One run is one reshape to ``(n_leaves,
+    rows_each)``, so the op count follows the runs (7 for an OLMoE layer's
+    201 leaves), not the leaves. Empty leaves form runs with rows_each 0."""
+    first = 0
+    for each, group in itertools.groupby(ranges, key=lambda r: r[1] - r[0]):
+        n = len(list(group))
+        yield first, n, ranges[first][0], each
+        first += n
+
+
+def leaf_reduce(x: jnp.ndarray, ranges: LeafRanges, op: str) -> jnp.ndarray:
+    """Per-row partials ``x[rows]`` -> ``[len(ranges)]``: each leaf's ``op``
+    ("max" or "sum") over its own rows, by static slice + reshape + dense
+    reduce per run of equal leaves. What ``jax.ops.segment_max/segment_sum``
+    compute over ``row_leaf``, without the index operand (a serial scatter on
+    the TPU: 28.7 ms for 3.28 M rows, PERF.md section 6). An empty leaf
+    yields the identity (-inf / 0)."""
+    ident, red = (-jnp.inf, jnp.max) if op == "max" else (0.0, jnp.sum)
+    parts = []
+    for _, n, start, each in _range_runs(ranges):
+        if each == 0:
+            parts.append(jnp.full((n,), ident, x.dtype))
+        else:
+            seg = jax.lax.slice(x, (start,), (start + n * each,))
+            parts.append(red(seg.reshape(n, each), axis=1))
+    return jnp.concatenate(parts) if len(parts) > 1 else parts[0]
+
+
+def leaf_expand(v: jnp.ndarray, ranges: LeafRanges) -> jnp.ndarray:
+    """Per-leaf ``v[..., len(ranges)]`` -> per-row ``[..., rows]``: leaf i's
+    value over its rows, by broadcast + reshape per run of equal leaves (the
+    gather ``v[..., row_leaf]`` without the index operand). ``ranges`` must
+    tile ``[0, rows)`` in order, empty leaves aside."""
+    lead = v.shape[:-1]
+    parts = []
+    for first, n, _, each in _range_runs(ranges):
+        if each:
+            seg = jax.lax.slice_in_dim(v, first, first + n, axis=-1)
+            parts.append(
+                jnp.broadcast_to(seg[..., None], (*lead, n, each)).reshape(
+                    *lead, n * each
+                )
+            )
+    return jnp.concatenate(parts, axis=-1) if len(parts) > 1 else parts[0]
 
 
 def make_spec(tree: Any) -> TableSpec:
@@ -151,26 +224,22 @@ def compute_scales(
     spec: TableSpec,
     policy: ScalePolicy = ScalePolicy.POW2_RMS,
 ) -> jnp.ndarray:
-    """Per-leaf step sizes (overflow-safe segment RMS; see codec.compute_scale
-    for the scalar version this generalizes)."""
-    k = spec.num_leaves
+    """Per-leaf step sizes (overflow-safe per-leaf RMS; see
+    codec.compute_scale for the scalar version this generalizes)."""
+    ranges = spec.leaf_rows
     rows = residual.reshape(-1, LANES)
-    row_leaf = jnp.asarray(spec.row_leaf())
     amax_row = jnp.max(jnp.abs(rows), axis=1)
-    amax = jax.ops.segment_max(amax_row, row_leaf, num_segments=k)
-    amax = jnp.maximum(amax, 0.0)  # segment_max identity is -inf
+    amax = jnp.maximum(leaf_reduce(amax_row, ranges, "max"), 0.0)  # empty leaf: -inf
     denom = jnp.where(amax > 0, amax, 1.0)
-    norm = rows / denom[row_leaf][:, None]
+    norm = rows / leaf_expand(denom, ranges)[:, None]
     ns = jnp.asarray(np.asarray(spec.ns, dtype=np.float32))
+    moment = jnp.abs(norm) if policy == ScalePolicy.ABS_MEAN else norm * norm
+    part = jnp.sum(moment, axis=1, dtype=jnp.float32)
+    mean = leaf_reduce(part, ranges, "sum") / ns
     if policy == ScalePolicy.ABS_MEAN:
-        s_row = jnp.sum(jnp.abs(norm), axis=1, dtype=jnp.float32)
-        mean = jax.ops.segment_sum(s_row, row_leaf, num_segments=k) / ns
         scales = amax * mean
     else:
-        ss_row = jnp.sum(norm * norm, axis=1, dtype=jnp.float32)
-        rms = amax * jnp.sqrt(
-            jax.ops.segment_sum(ss_row, row_leaf, num_segments=k) / ns
-        )
+        rms = amax * jnp.sqrt(mean)
         scales = pow2_floor(rms) if policy == ScalePolicy.POW2_RMS else rms
     rms_pos = amax > 0
     return jnp.where(rms_pos & jnp.isfinite(scales), scales, 0.0)
@@ -219,16 +288,16 @@ def _quantize_table(
     impl: str,
 ) -> tuple[TableFrame, jnp.ndarray]:
     scales = _table_scales(residual, spec, policy, per_leaf)
-    row_leaf = jnp.asarray(spec.row_leaf())
+    s_row = leaf_expand(scales, spec.leaf_rows)
     if impl == "pallas":
         from . import codec_pallas
 
         words, new_flat = codec_pallas.quantize_rows(
-            scales[row_leaf], jnp.asarray(spec.live_rowcount()), residual
+            s_row, jnp.asarray(spec.live_rowcount()), residual
         )
         return TableFrame(scales, words), new_flat
     rows = residual.reshape(-1, LANES)
-    s_row = scales[row_leaf][:, None]  # (rows, 1)
+    s_row = s_row[:, None]  # (rows, 1)
     live = jnp.asarray(_live_mask_flat(spec)).reshape(-1, LANES)
     neg = rows <= 0
     bits = jnp.where(live, neg, False)
@@ -303,8 +372,7 @@ def _batch_layout(frames: TableFrame, spec: TableSpec):
     words for row r at [r, 4k:4k+4])."""
     k = frames.scales.shape[0]
     rows = spec.total // LANES
-    row_leaf = jnp.asarray(spec.row_leaf())
-    s_rows = frames.scales[:, row_leaf].T  # (rows, K)
+    s_rows = leaf_expand(frames.scales, spec.leaf_rows).T  # (rows, K)
     words2d = (
         frames.words.reshape(k, rows, LANES // 32)
         .transpose(1, 0, 2)
@@ -317,19 +385,18 @@ def _batch_layout(frames: TableFrame, spec: TableSpec):
 def _apply_table_many(
     arrays: tuple[jnp.ndarray, ...], frame: TableFrame, spec: TableSpec, impl: str
 ) -> tuple[jnp.ndarray, ...]:
-    row_leaf = jnp.asarray(spec.row_leaf())
+    s_row = leaf_expand(frame.scales, spec.leaf_rows)[:, None]  # (rows, 1)
     if impl == "pallas":
         from . import codec_pallas
 
         rows = spec.total // LANES
         return codec_pallas.apply_rows_batch(
-            frame.scales[row_leaf].reshape(rows, 1),
+            s_row,
             jnp.asarray(spec.live_rowcount()),
             frame.words.reshape(rows, LANES // 32),
             arrays,
         )
     bits = unpack_bits(frame.words).reshape(-1, LANES)
-    s_row = frame.scales[row_leaf][:, None]
     live = jnp.asarray(_live_mask_flat(spec)).reshape(-1, LANES)
     delta = jnp.where(live, s_row * (1.0 - 2.0 * bits.astype(jnp.float32)), 0.0)
     flat_delta = delta.reshape(-1)
@@ -367,8 +434,7 @@ def _apply_table_batch(
         )
     k = frames.scales.shape[0]
     bits = unpack_bits(frames.words.reshape(-1)).reshape(k, -1, LANES)
-    row_leaf = jnp.asarray(spec.row_leaf())
-    s_row = frames.scales[:, row_leaf][:, :, None]  # [K, rows, 1]
+    s_row = leaf_expand(frames.scales, spec.leaf_rows)[:, :, None]  # [K, rows, 1]
     live = jnp.asarray(_live_mask_flat(spec)).reshape(-1, LANES)
     delta = jnp.sum(s_row * (1.0 - 2.0 * bits.astype(jnp.float32)), axis=0)
     flat_delta = jnp.where(live, delta, 0.0).reshape(-1)

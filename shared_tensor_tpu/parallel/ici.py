@@ -43,7 +43,7 @@ from __future__ import annotations
 
 import dataclasses
 from functools import partial
-from typing import NamedTuple, Optional, Tuple
+from typing import NamedTuple, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -54,7 +54,15 @@ from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 from ..config import MeshConfig, ScalePolicy
 from ..ops.codec import SAT, pow2_floor
 from ..ops.packing import BITS_PER_WORD, LANES, pack_bits, unpack_bits
-from ..ops.table import TableSpec, flatten, unflatten
+from ..ops.table import (
+    LeafRanges,
+    TableSpec,
+    clip_ranges,
+    flatten,
+    leaf_expand,
+    leaf_reduce,
+    unflatten,
+)
 from .mesh import rows_per_shard
 
 
@@ -161,32 +169,50 @@ def apply_external(state: PeerSyncState, delta: jax.Array) -> PeerSyncState:
 @dataclasses.dataclass(frozen=True)
 class _StepCtx:
     """Static layout shared by the sync-step builders: mesh axes, per-shard
-    row geometry, and the leaf segmentation the scale reductions run over."""
+    row geometry, and each shard's leaf row ranges (``TableSpec.leaf_rows``
+    clipped to the shard), which the scale reductions and the per-leaf ->
+    per-row expansions run over. No per-row index vector exists."""
 
     peer_ax: str
     shard_ax: str
     n_peer: int
     n_shard: int
     rows_local: int
-    k: int
-    row_leaf_full: jnp.ndarray
+    shard_ranges: Tuple[LeafRanges, ...]  # [n_shard][k] local (start, stop)
     rowcount_full: jnp.ndarray
     ns: jnp.ndarray
 
-    def local_slices(self):
-        """This shard's (row_leaf, rowcount, live) views. Call inside
-        shard_map only (uses axis_index)."""
-        sid = jax.lax.axis_index(self.shard_ax)
-        start = sid * self.rows_local
-        row_leaf = jax.lax.dynamic_slice_in_dim(
-            self.row_leaf_full, start, self.rows_local
+    def _on_shard(self, fn, x):
+        """``fn(x, ranges)`` with this shard's leaf ranges. They are static
+        per shard index, so with several shards it is one ``lax.switch`` on
+        ``axis_index`` over a branch per shard. Call inside shard_map only."""
+        if self.n_shard == 1:
+            return fn(x, self.shard_ranges[0])
+        return jax.lax.switch(
+            jax.lax.axis_index(self.shard_ax),
+            [partial(fn, ranges=r) for r in self.shard_ranges],
+            x,
         )
+
+    def leaf_reduce(self, x: jnp.ndarray, op: str) -> jnp.ndarray:
+        """This shard's per-row partials ``[rows_local]`` -> ``[k]`` ("max" or
+        "sum"); leaves outside the shard read the identity."""
+        return self._on_shard(partial(leaf_reduce, op=op), x)
+
+    def leaf_expand(self, v: jnp.ndarray) -> jnp.ndarray:
+        """Per-leaf ``[..., k]`` -> this shard's rows ``[..., rows_local]``."""
+        return self._on_shard(leaf_expand, v)
+
+    def local_slices(self):
+        """This shard's (rowcount, live) views. Call inside shard_map only
+        (uses axis_index)."""
+        sid = jax.lax.axis_index(self.shard_ax)
         rowcount = jax.lax.dynamic_slice_in_dim(
-            self.rowcount_full, start, self.rows_local
+            self.rowcount_full, sid * self.rows_local, self.rows_local
         )
         lane = jax.lax.broadcasted_iota(jnp.int32, (self.rows_local, LANES), 1)
         live = lane < rowcount[:, None]
-        return row_leaf, rowcount, live
+        return rowcount, live
 
 
 def _make_ctx(
@@ -194,70 +220,54 @@ def _make_ctx(
 ) -> _StepCtx:
     peer_ax, shard_ax = cfg.peer_axis, cfg.shard_axis
     n_shard = mesh.shape[shard_ax]
+    rows_local = rows_per_shard(spec.total, n_shard)
     if per_leaf:
-        k = spec.num_leaves
-        row_leaf_full = jnp.asarray(spec.row_leaf())
+        ranges = spec.leaf_rows
         ns = jnp.asarray(np.asarray(spec.ns, dtype=np.float32))
     else:
         # one global scale over the whole table (the reference's exact
-        # behavior, src/sharedtensor.c:153-159) — a single segment
-        k = 1
-        row_leaf_full = jnp.zeros((spec.total // LANES,), jnp.int32)
+        # behavior, src/sharedtensor.c:153-159) — a single range
+        ranges = ((0, spec.total // LANES),)
         ns = jnp.asarray([float(spec.total_n)], jnp.float32)
     return _StepCtx(
         peer_ax=peer_ax,
         shard_ax=shard_ax,
         n_peer=mesh.shape[peer_ax],
         n_shard=n_shard,
-        rows_local=rows_per_shard(spec.total, n_shard),
-        k=k,
-        row_leaf_full=row_leaf_full,
+        rows_local=rows_local,
+        shard_ranges=tuple(
+            clip_ranges(ranges, j * rows_local, (j + 1) * rows_local)
+            for j in range(n_shard)
+        ),
         rowcount_full=jnp.asarray(spec.live_rowcount()),
         ns=ns,
     )
 
 
 def _leaf_scales(
-    rows: jnp.ndarray,
-    row_leaf: jnp.ndarray,
-    live: jnp.ndarray,
-    ns: jnp.ndarray,
-    k: int,
-    policy: ScalePolicy,
-    shard_axis: Optional[str],
+    ctx: _StepCtx, rows: jnp.ndarray, live: jnp.ndarray, policy: ScalePolicy
 ) -> jnp.ndarray:
     """Per-leaf scales from this shard's rows, reduced over the shard axis.
 
-    Same overflow-safe normalized-RMS math as ops.table.compute_scales, with
-    the segment reductions split into a local partial + a cross-shard
+    Same overflow-safe normalized-RMS math as ops.table.compute_scales: two
+    dense row passes over the residual, their per-row partials reduced per
+    leaf over static row ranges (``ctx.leaf_reduce``), then a cross-shard
     psum/pmax (this is where the sharded replica pays one small collective —
     k floats — per frame)."""
     with jax.named_scope("st.leaf_scales"):
         amax_row = jnp.max(jnp.where(live, jnp.abs(rows), 0.0), axis=1)
-        amax = jax.ops.segment_max(amax_row, row_leaf, num_segments=k)
-        amax = jnp.maximum(amax, 0.0)  # segment_max identity is -inf
-        if shard_axis is not None:
-            amax = jax.lax.pmax(amax, shard_axis)
+        # a leaf with no row on this shard reads -inf
+        amax = jnp.maximum(ctx.leaf_reduce(amax_row, "max"), 0.0)
+        amax = jax.lax.pmax(amax, ctx.shard_ax)
         denom = jnp.where(amax > 0, amax, 1.0)
-        norm = jnp.where(live, rows / denom[row_leaf][:, None], 0.0)
+        norm = jnp.where(live, rows / ctx.leaf_expand(denom)[:, None], 0.0)
+        moment = jnp.abs(norm) if policy == ScalePolicy.ABS_MEAN else norm * norm
+        part = jnp.sum(moment, axis=1, dtype=jnp.float32)
+        mean = jax.lax.psum(ctx.leaf_reduce(part, "sum"), ctx.shard_ax) / ctx.ns
         if policy == ScalePolicy.ABS_MEAN:
-            part = jax.ops.segment_sum(
-                jnp.sum(jnp.abs(norm), axis=1, dtype=jnp.float32),
-                row_leaf,
-                num_segments=k,
-            )
-            if shard_axis is not None:
-                part = jax.lax.psum(part, shard_axis)
-            scales = amax * (part / ns)
+            scales = amax * mean
         else:
-            part = jax.ops.segment_sum(
-                jnp.sum(norm * norm, axis=1, dtype=jnp.float32),
-                row_leaf,
-                num_segments=k,
-            )
-            if shard_axis is not None:
-                part = jax.lax.psum(part, shard_axis)
-            rms = amax * jnp.sqrt(part / ns)
+            rms = amax * jnp.sqrt(mean)
             scales = pow2_floor(rms) if policy == ScalePolicy.POW2_RMS else rms
         return jnp.where((amax > 0) & jnp.isfinite(scales), scales, 0.0)
 
@@ -278,12 +288,10 @@ def _codec_send(ctx: _StepCtx, policy: ScalePolicy, pallas_tier: bool, residual)
     scales_all [n_peer, k], scales_local [k])."""
     with jax.named_scope("st.codec_send"):
         r = residual.reshape(ctx.rows_local, LANES)
-        row_leaf, rowcount, live = ctx.local_slices()
-        scales = _leaf_scales(
-            r, row_leaf, live, ctx.ns, ctx.k, policy, ctx.shard_ax
-        )
+        rowcount, live = ctx.local_slices()
+        scales = _leaf_scales(ctx, r, live, policy)
         with jax.named_scope("st.row_scales"):
-            s_row = scales[row_leaf]
+            s_row = ctx.leaf_expand(scales)
         with jax.named_scope("st.quantize"):
             if pallas_tier:
                 from ..ops import codec_pallas
@@ -313,11 +321,13 @@ def _codec_apply(ctx: _StepCtx, pallas_tier: bool, values, words_all, scales_all
     every state-mutating path. Shared by build_sync_step and
     build_sync_phases."""
     with jax.named_scope("st.codec_apply"):
-        row_leaf, rowcount, live = ctx.local_slices()
+        rowcount, live = ctx.local_slices()
         with jax.named_scope("st.row_scales"):
             me = jax.lax.axis_index(ctx.peer_ax)
-            s_all = scales_all[:, row_leaf]  # (n_peer, rows_local)
-            s_all = jnp.where((jnp.arange(ctx.n_peer) == me)[:, None], 0.0, s_all)
+            s_all = jnp.where(
+                (jnp.arange(ctx.n_peer) == me)[:, None], 0.0, scales_all
+            )
+            s_all = ctx.leaf_expand(s_all)  # (n_peer, rows_local)
         with jax.named_scope("st.words_layout"):
             if pallas_tier:
                 words = (
@@ -393,11 +403,11 @@ def build_sync_step(
 
     def _exact(values, residual):
         r = residual.reshape(ctx.rows_local, LANES)
-        row_leaf, rowcount, live = ctx.local_slices()
+        _, live = ctx.local_slices()
         # report the would-have-been scales so both arms expose the same
         # observability surface (the shard-axis reduction inside also lets
         # shard_map infer the scales output is shard-replicated)
-        scales = _leaf_scales(r, row_leaf, live, ctx.ns, ctx.k, policy, shard_ax)
+        scales = _leaf_scales(ctx, r, live, policy)
         delta_others = jax.lax.psum(residual, peer_ax) - residual
         v2 = jnp.clip(values + delta_others, -SAT, SAT)
         v2 = jnp.where(live.reshape(-1), v2, 0.0)

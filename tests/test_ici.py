@@ -6,6 +6,8 @@ on a real v5e-8. The semantic yardsticks come from the reference codec
 (SURVEY.md §6.2 convergence table, Appendix B).
 """
 
+import re
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -275,3 +277,23 @@ def test_sync_phases_compose_to_sync_step(n_peer, n_shard):
     np.testing.assert_array_equal(np.asarray(v2), np.asarray(fused.values))
     np.testing.assert_array_equal(np.asarray(r2), np.asarray(fused.residual))
     np.testing.assert_array_equal(np.asarray(scales), np.asarray(scales_f))
+
+
+@pytest.mark.parametrize("compressed", [True, False], ids=["compressed", "exact"])
+@pytest.mark.parametrize("n_shard", [1, 2])
+def test_sync_step_takes_no_index_operand(n_shard, compressed):
+    """The row <-> leaf maps are static row ranges (TableSpec.leaf_rows), so a
+    many-leaf sync step lowers, and compiles, with no gather and no scatter:
+    the guard against a per-row index vector coming back (on the v5e a
+    3.28 M-row one was 108 ms of a 158 ms step, PERF.md section 6). With
+    several shards the ranges are picked by a switch on the shard index."""
+    mesh = make_mesh(2, n_shard)
+    tpl = {f"leaf{i:02d}": jnp.zeros(n) for i, n in enumerate([3000, 70] + [2048] * 9 + [5, 1100])}
+    spec = make_spec(tpl)
+    step = build_sync_step(mesh, spec, compressed=compressed, impl="xla")
+    lowered = step.lower(init_state(mesh, spec, tpl))
+    stablehlo = lowered.as_text()
+    assert not re.findall(r"stablehlo\.(?:dynamic_)?(?:gather|scatter)\b", stablehlo)
+    assert ("stablehlo.case" in stablehlo) == (n_shard > 1)
+    hlo = lowered.compile().as_text()
+    assert not re.findall(r"= \S+ (?:gather|scatter)\(", hlo)

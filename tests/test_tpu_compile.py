@@ -14,6 +14,7 @@ compiled text. Each test jits a function object of its own, so no trace made
 here is ever served to another test.
 """
 
+import re
 from functools import partial
 
 import jax
@@ -24,7 +25,12 @@ from jax.sharding import NamedSharding, PartitionSpec as P
 
 from shared_tensor_tpu.models import char_rnn as m
 from shared_tensor_tpu.ops import codec_pallas, table
-from shared_tensor_tpu.parallel import PeerSyncState, make_mesh, state_sharding
+from shared_tensor_tpu.parallel import (
+    PeerSyncState,
+    build_sync_step,
+    make_mesh,
+    state_sharding,
+)
 from shared_tensor_tpu.train import build_train_step
 
 
@@ -97,3 +103,28 @@ def test_apply_table_batch_compiles_for_v5e(v5e_devices, k):
         arrays = (arg((spec.total,), jnp.float32),) * n_arrays
         text = apply.lower(arrays, frames).compile().as_text()
         assert "tpu_custom_call" in text, (k, n_arrays)
+
+
+@pytest.mark.parametrize("n_peer,n_shard", [(1, 1), (4, 1), (2, 2)])
+def test_sync_step_compiles_for_v5e_with_no_index_operand(
+    v5e_devices, n_peer, n_shard
+):
+    """The Pallas tier of tests/test_ici.py's structural test: around the two
+    kernels the compiled sync step of a many-leaf table holds no gather and no
+    scatter (the leaves' static row ranges do the row <-> leaf maps), on one
+    shard and, through the switch on the shard index, on two."""
+    sizes = [3000, 70] + [2 * 8 * 1024] * 9 + [5, 1100]
+    spec = table.make_spec(
+        {f"leaf{i:02d}": jax.ShapeDtypeStruct((n,), jnp.float32)
+         for i, n in enumerate(sizes)}
+    )
+    mesh = make_mesh(n_peer, n_shard, devices=v5e_devices)
+    block = jax.ShapeDtypeStruct(
+        (n_peer, spec.total), jnp.float32, sharding=state_sharding(mesh)
+    )
+    text = build_sync_step(mesh, spec).lower(
+        PeerSyncState(block, block)
+    ).compile().as_text()
+    assert text.count("tpu_custom_call") >= 2
+    assert not re.findall(r"= \S+ (?:gather|scatter)\(", text)
+    assert (" conditional(" in text) == (n_shard > 1)
