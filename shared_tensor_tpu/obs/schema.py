@@ -198,6 +198,12 @@ SCHEMA: dict[str, tuple[str, str]] = {
     "st_pod_compile_seconds_total": ("counter", "seconds in those compilations"),
     "st_pod_cache_load_seconds_total": ("counter", "seconds retrieving executables from the persistent compilation cache"),
     "st_pod_last_compile_step": ("gauge", "PodTrainer.steps when the latest compilation happened"),
+    # expert layers (models/mla_moe.py), read from the newest PodTrainer's
+    # aux when the registry is read: the newest step's, over all peers and
+    # expert layers
+    "st_moe_pairs_held_total": ("gauge", "(token, expert) pairs routed to experts held here in the newest step, all expert layers and peers"),
+    "st_moe_load_max_over_mean": ("gauge", "largest held expert's load over the mean held expert's, the worst expert layer of the newest step"),
+    "st_moe_tokens_unrouted_share": ("gauge", "share of tokens that chose no held expert, mean over the expert layers of the newest step"),
     # per-link series (rendered via link_key)
     "st_link_bytes_out_total": ("counter", "wire bytes sent on the link (incl. framing/keepalives)"),
     "st_link_bytes_in_total": ("counter", "wire bytes received on the link"),

@@ -65,7 +65,10 @@ def build_train_step(
     per-peer loss, scales)``.
 
     ``loss_fn(params, batch_item) -> scalar`` sees the caller's parameter
-    pytree; ``batch`` carries a leading peer axis on every leaf. ``lr`` is a
+    pytree; ``batch`` carries a leading peer axis on every leaf. It may
+    return ``(scalar, aux)`` instead, ``aux`` a pytree of arrays (counters,
+    parts of the loss): the step then returns a fifth element, ``aux`` with
+    a leading peer axis, computed in the same program. ``lr`` is a
     traced scalar so schedules don't retrigger compilation. ``sync=False``
     builds the no-communication arm (pure local SGD — the isolation baseline
     for convergence comparisons).
@@ -112,13 +115,22 @@ def build_train_step(
         else None
     )
     k = spec.num_leaves if per_leaf else 1
-    grad_fn = jax.value_and_grad(loss_fn)
+
+    def loss_and_aux(params, batch_item):
+        out = loss_fn(params, batch_item)
+        return out if isinstance(out, tuple) else (out, None)
+
+    grad_fn = jax.value_and_grad(loss_and_aux, has_aux=True)
 
     def per_peer(values_row: jnp.ndarray, batch_item):
         with jax.named_scope("st.grads"):
             params = unflatten(values_row, spec)
-            loss, grads = grad_fn(params, batch_item)
-            return loss, flatten(grads, spec)
+            (loss, aux), grads = grad_fn(params, batch_item)
+            return loss, flatten(grads, spec), aux
+
+    def with_aux(state, opt_state, losses, scales, aux):
+        out = (state, opt_state, losses, scales)
+        return out if aux is None else (*out, aux)
 
     def update_of(g, opt_state, values, lr):
         with jax.named_scope("st.update"):
@@ -136,19 +148,19 @@ def build_train_step(
             # exists at frame time, exactly like the reference's streams).
             send, apply_gathered = phases
             r2, words_all, scales_all = send(state.residual)
-            losses, g = jax.vmap(per_peer)(state.values, batch)
+            losses, g, aux = jax.vmap(per_peer)(state.values, batch)
             updates, opt_state = update_of(g, opt_state, state.values, lr)
             v2 = apply_gathered(state.values, words_all, scales_all)
             state = add_updates_raw(PeerSyncState(v2, r2), updates)
-            return state, opt_state, losses, scales_all
-        losses, g = jax.vmap(per_peer)(state.values, batch)
+            return with_aux(state, opt_state, losses, scales_all, aux)
+        losses, g, aux = jax.vmap(per_peer)(state.values, batch)
         updates, opt_state = update_of(g, opt_state, state.values, lr)
         state = add_updates_raw(state, updates)
         if sync_raw is not None:
             state, scales = sync_raw(state)
         else:
             scales = jnp.zeros((state.values.shape[0], k), jnp.float32)
-        return state, opt_state, losses, scales
+        return with_aux(state, opt_state, losses, scales, aux)
 
     return jax.jit(_step, donate_argnums=(0,) if optimizer is None else (0, 1))
 
@@ -209,6 +221,10 @@ class PodTrainer:
             else None
         )
         self.steps = 0
+        #: the newest step's ``aux`` (leading peer axis, on the device) where
+        #: ``loss_fn`` returns ``(loss, aux)``; None before the first step
+        self.aux: Any = None
+        profiling.pod_tier().watch(self)
 
     def shard_batch(self, batch: Any) -> Any:
         """Pin a [n_peer, ...] batch pytree to the peer axis so each peer's
@@ -235,9 +251,11 @@ class PodTrainer:
             fn = self._step
             if self._step_local is not None and (self.steps + 1) % self.sync_every:
                 fn = self._step_local
-            self.state, self.opt_state, losses, scales = fn(
+            self.state, self.opt_state, losses, scales, *aux = fn(
                 self.state, self.opt_state, batch, jnp.float32(lr)
             )
+        if aux:
+            self.aux = aux[0]
         self.steps += 1
         pod.count_step(self.sync and fn is self._step)
         return losses, scales

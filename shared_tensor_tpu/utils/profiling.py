@@ -36,6 +36,7 @@ import os
 import re
 import threading
 import time
+import weakref
 from collections import deque
 from typing import Iterable, Iterator
 
@@ -100,6 +101,29 @@ class PodTier:
             self.registry.gauge, "st_pod_last_compile_step"
         )
         jax.monitoring.register_event_duration_secs_listener(self._on_duration)
+        self._trainer = lambda: None
+        self.registry.register_collector(self._moe)
+
+    def watch(self, trainer) -> None:
+        """The trainer whose ``aux`` the expert layers' gauges read (the
+        newest made; held weakly)."""
+        self._trainer = weakref.ref(trainer)
+
+    def _moe(self) -> dict:
+        """The newest step's expert-layer counters, fetched from the device
+        when the registry is read and never inside ``step``: over all peers
+        and expert layers the pairs held, the worst load ratio and the mean
+        unrouted share. Empty unless the loss function reports them."""
+        aux = getattr(self._trainer(), "aux", None)
+        if not isinstance(aux, dict) or "moe_pairs_held" not in aux:
+            return {}
+        got = jax.device_get({k: aux[k] for k in (
+            "moe_pairs_held", "moe_load_max_over_mean", "moe_tokens_unrouted_share")})
+        return {
+            "st_moe_pairs_held_total": float(got["moe_pairs_held"].sum()),
+            "st_moe_load_max_over_mean": float(got["moe_load_max_over_mean"].max()),
+            "st_moe_tokens_unrouted_share": float(got["moe_tokens_unrouted_share"].mean()),
+        }
 
     def count_step(self, synced: bool) -> None:
         """One completed ``PodTrainer.step``; ``synced`` says whether the
@@ -139,7 +163,7 @@ def pod_registry():
 
 # --- from the compiled program's text: which scope an instruction is in -------
 
-_SCOPE = re.compile(r"\bst\.[A-Za-z_][A-Za-z0-9_]*")
+_SCOPE = re.compile(r"\bst(?:\.[A-Za-z_][A-Za-z0-9_]*)+")
 _MODULE = re.compile(r"^HloModule (\S+?),", re.M)
 _COMPUTATION = re.compile(r"^(?:ENTRY )?%?([^\s(]+) \(.*\) -> .*\{\s*$")
 _INSTRUCTION = re.compile(r"^\s+(ROOT )?%?(\S+) = ")
@@ -157,10 +181,13 @@ def scope_of(op_name: str) -> str:
     ``st.grads/st.flatten``. The last component is the innermost scope.
     Empty when there is none. The name's own last component is the
     primitive, or a whole argument's path (``st.values`` where a caller
-    named its state ``st``), and is no scope. Of metadata XLA merged
+    named its state ``st``), and is no scope. A scope's name may hold dots
+    (``st.mla.attn``, inside ``st.mla``). Of metadata XLA merged
     (``a;b``) the first name that has a scope counts."""
     for name in op_name.split(";"):
-        scope = "/".join(_SCOPE.findall(name.rpartition("/")[0]))
+        # a backward or recomputed operation repeats its forward's scopes
+        # (``st.grads/.../transpose(jvp(st.grads))/st.mla``): each counts once
+        scope = "/".join(dict.fromkeys(_SCOPE.findall(name.rpartition("/")[0])))
         if scope:
             return scope
     return ""
