@@ -15,7 +15,13 @@ arXiv:2412.19437 (sections 2.1, 2.2). TPU-first choices:
 - attention runs over the causal triangle in tiles of queries by keys with
   an online softmax, the loss in blocks of tokens, each layer under
   ``jax.checkpoint``: no ``[heads, T, T]`` tensor and no second ``[T, vocab]``
-  array is alive.
+  array is alive. On the chip (a tpu backend or ``ST_CODEC=pallas``, bfloat16
+  operands, a length of whole kernel tiles) the tiles are those of the fused
+  kernels of ``ops/attention_pallas.py``, forward and backward, which keep a
+  tile's scores in VMEM and size their own tiles; anywhere else (float32
+  programs, CPU peers, other lengths) one ``lax.scan`` forward and one
+  backward in tiles of ``attn_block``, the same arithmetic at the same
+  precision and the kernels' oracle in tests.
 - the expert layer is told which experts it holds (``experts_held``), routes
   over all of them and computes its own experts' part, dropless: (token,
   expert) pairs are sorted by expert into tiles of rows padded per expert, and
@@ -23,9 +29,9 @@ arXiv:2412.19437 (sections 2.1, 2.2). TPU-first choices:
   pairs that exist. ``PodTrainer`` vmaps the loss over peers and a batched
   trip count would run every peer's loop as long as the longest;
   :func:`_per_example` keeps each peer's call its own program.
-- both loops (attention's tiles, the expert layer's) have one body: the
-  compiler's time goes by distinct shapes (a second a large product), and 16
-  key lengths a layer and five capacities of the expert layer made the step
+- both loops (the attention scan's tiles, the expert layer's) have one body:
+  the compiler's time goes by distinct shapes (a second a large product), and
+  16 key lengths a layer and five capacities of the expert layer made the step
   take five minutes to compile (chip-free compile, PR 29).
 """
 
@@ -42,6 +48,9 @@ import numpy as np
 from jax import lax
 from jax.ad_checkpoint import checkpoint_name
 from jax.custom_batching import custom_vmap
+
+from ..ops import attention_pallas
+from ..utils.profiling import pod_tier
 
 ATTN_OUT = "attn_out"  # the residual a layer's checkpoint keeps
 
@@ -79,10 +88,13 @@ class Config:
     mtp_loss_weight: float = 0.3
     init_std: float = 0.006
     compute_dtype: str = "bfloat16"
-    #: queries by keys a tile of attention. On the chip at 8 192 tokens and 32
-    #: heads, forward + backward: 95.0 ms in tiles of 512, 79.6 in 1 024, 78.4
-    #: in 2 048 (my chip run, PR 29); but the step compiles in 113 s with 512
-    #: and in 146 s with 1 024 in the backward pass alone (chip-free compile)
+    #: queries by keys a tile of the attention scan, the path taken where the
+    #: fused kernels are not (``causal_attention``; the kernels size their own
+    #: tiles from the shapes and read no field here). The scan on the chip at
+    #: 8 192 tokens and 32 heads, forward + backward: 95.0 ms in tiles of 512,
+    #: 79.6 in 1 024, 78.4 in 2 048 (my chip run, PR 29); but the step compiles
+    #: in 113 s with 512 and in 146 s with 1 024 in the backward pass alone
+    #: (chip-free compile)
     attn_block: int = 512
     loss_block: int = 2048  # tokens a block of logits
     expert_tile: int = 128  # rows a tile of one expert's tokens
@@ -310,20 +322,31 @@ def _attention_bwd_tiles(q, k, v, o, lse, g, block: int):
     return dq.astype(q.dtype), dk.astype(k.dtype), dv.astype(v.dtype)
 
 
+def _attention_o_lse(q, k, v, block: int | None):
+    """``(o, lse)`` by the scan in tiles of ``block``, or by the fused
+    kernels (``ops/attention_pallas.py``, which size their own tiles) where
+    ``block`` is None."""
+    if block is None:
+        return attention_pallas.attention_fwd(q, k, v)
+    return _attention_fwd_tiles(q, k, v, block)
+
+
 @partial(jax.custom_vjp, nondiff_argnums=(3,))
-def _attention_tiles(q, k, v, block: int):
-    return _attention_fwd_tiles(q, k, v, block)[0]
+def _attention_tiles(q, k, v, block: int | None):
+    return _attention_o_lse(q, k, v, block)[0]
 
 
 def _attention_tiles_fwd(q, k, v, block):
     # what a layer's checkpoint keeps (64 MB + 1 MB a layer at 8 192 tokens),
     # so that recomputing the layer does not run the attention a third time
-    o, lse = (checkpoint_name(a, ATTN_OUT) for a in _attention_fwd_tiles(q, k, v, block))
+    o, lse = (checkpoint_name(a, ATTN_OUT) for a in _attention_o_lse(q, k, v, block))
     return o, (q, k, v, o, lse)
 
 
 def _attention_tiles_bwd(block, res, g):
     with jax.named_scope("st.mla.attn"):
+        if block is None:
+            return attention_pallas.attention_bwd(*res, g)
         return _attention_bwd_tiles(*res, g, block)
 
 
@@ -331,18 +354,28 @@ _attention_tiles.defvjp(_attention_tiles_fwd, _attention_tiles_bwd)
 
 
 def causal_attention(q, k, v, block: int):
-    """softmax(q k^T / sqrt(dq)) v with a causal mask, ``[T, H, .]`` operands:
-    tiles of ``block`` queries by ``block`` keys, the causal triangle's only,
-    in one loop forward and one backward (which makes each tile's scores
-    again), so no ``[H, T, T]`` tensor exists and the compiler sees one tile
-    shape, not one a query block (16 key lengths at 8 192 tokens cost 100 s
-    of compilation; chip-free compile, PR 29)."""
+    """softmax(q k^T / sqrt(dq)) v with a causal mask, ``[T, H, .]`` operands,
+    over the causal triangle's tiles only, each tile's scores made again in
+    the backward pass, so no ``[H, T, T]`` tensor exists. Two paths, one
+    precision (bfloat16 operands, float32 accumulation and softmax):
+
+    - the fused kernels of ``ops/attention_pallas.py`` where they run
+      (:func:`attention_pallas.takes`: a tpu backend or ``ST_CODEC=pallas``,
+      bfloat16 operands, whole kernel tiles): a tile's scores stay in VMEM;
+    - else the scan in tiles of ``block`` queries by ``block`` keys, one loop
+      forward and one backward: float32 programs, CPU peers, other lengths,
+      and the kernels' oracle. The compiler sees one tile shape, not one a
+      query block (16 key lengths at 8 192 tokens cost 100 s of compilation;
+      chip-free compile, PR 29).
+
+    Which one was traced is counted (``st_attn_traces_total{path}``)."""
     n = q.shape[0]
-    block = min(block, n)
-    if n % block:
+    q, k, v = (jnp.swapaxes(a, 0, 1) for a in (q, k, v))  # heads first
+    block = None if attention_pallas.takes(q, k, v) else min(block, n)
+    if block and n % block:
         raise ValueError(f"{n} positions do not divide into tiles of {block}")
-    heads_first = lambda a: jnp.swapaxes(a, 0, 1)
-    return heads_first(_attention_tiles(heads_first(q), heads_first(k), heads_first(v), block))
+    pod_tier().count_attention_trace("scan" if block else "pallas")
+    return jnp.swapaxes(_attention_tiles(q, k, v, block), 0, 1)
 
 
 def mla(p: dict, x: jax.Array, rope, cfg: Config) -> jax.Array:

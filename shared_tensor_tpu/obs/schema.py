@@ -204,6 +204,9 @@ SCHEMA: dict[str, tuple[str, str]] = {
     "st_moe_pairs_held_total": ("gauge", "(token, expert) pairs routed to experts held here in the newest step, all expert layers and peers"),
     "st_moe_load_max_over_mean": ("gauge", "largest held expert's load over the mean held expert's, the worst expert layer of the newest step"),
     "st_moe_tokens_unrouted_share": ("gauge", "share of tokens that chose no held expert, mean over the expert layers of the newest step"),
+    # causal attention (models/mla_moe.py): which path a traced call took,
+    # decided at trace time (backend, dtype, length), so counted per trace
+    "st_attn_traces_total": ("counter", "traced calls of causal attention (per-path label: pallas = the fused kernels of ops/attention_pallas.py | scan = the portable tile loop)"),
     # per-link series (rendered via link_key)
     "st_link_bytes_out_total": ("counter", "wire bytes sent on the link (incl. framing/keepalives)"),
     "st_link_bytes_in_total": ("counter", "wire bytes received on the link"),

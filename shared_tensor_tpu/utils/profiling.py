@@ -103,6 +103,14 @@ class PodTier:
         jax.monitoring.register_event_duration_secs_listener(self._on_duration)
         self._trainer = lambda: None
         self.registry.register_collector(self._moe)
+        self._attn_traces = {"pallas": 0, "scan": 0}
+        attn_keys = {
+            path: label_key("st_attn_traces_total", "path", path)
+            for path in self._attn_traces
+        }
+        self.registry.register_collector(
+            lambda: {attn_keys[path]: n for path, n in self._attn_traces.items()}
+        )
 
     def watch(self, trainer) -> None:
         """The trainer whose ``aux`` the expert layers' gauges read (the
@@ -130,6 +138,14 @@ class PodTier:
         program it ran held the exchange (the sync beat) or not."""
         with self._mu:
             self._steps[synced] += 1
+
+    def count_attention_trace(self, path: str) -> None:
+        """One traced call of ``models/mla_moe.py``'s causal attention and
+        the path it took: ``pallas`` (the fused kernels) or ``scan``. The
+        choice is made while a program is traced, so this counts traces, not
+        steps."""
+        with self._mu:
+            self._attn_traces[path] += 1
 
     def _on_duration(self, event: str, seconds: float, **_kw) -> None:
         if event == COMPILE_EVENT:

@@ -295,10 +295,14 @@ def test_pod_counters_are_in_the_schema_and_the_exposition():
     names = (
         "st_pod_steps_total", "st_pod_compiles_total", "st_pod_compile_seconds_total",
         "st_pod_cache_load_seconds_total", "st_pod_last_compile_step",
+        "st_attn_traces_total",
     )
     text = pod_registry().prometheus_text()
     for name in names:
         assert name in SCHEMA, name
         assert name in text, name
     assert 'st_pod_steps_total{program="sync"}' in text
+    # a trace-time counter: both paths are there from the start, at 0 or more
+    assert 'st_attn_traces_total{path="pallas"}' in text
+    assert 'st_attn_traces_total{path="scan"}' in text
     assert pod_registry() is pod_registry()

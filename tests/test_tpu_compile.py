@@ -7,9 +7,10 @@ overflows, unsupported Mosaic ops and bad shardings fail here, on the CPU,
 before anybody spends chip time. Compiling is not running — chip_smoke.py
 does that.
 
-The kernels are forced out of interpret mode (``codec_pallas._interpret``)
-and the codec tier onto Pallas (``ST_CODEC``), which is what a tpu backend
-selects by itself; every test asserts the Mosaic custom calls are in the
+The kernels (the codec's and, through the same two switches, the attention's
+of ops/attention_pallas.py) are forced out of interpret mode
+(``codec_pallas._interpret``) and the codec tier onto Pallas (``ST_CODEC``),
+which is what a tpu backend selects by itself; every test asserts the Mosaic custom calls are in the
 compiled text. Each test jits a function object of its own, so no trace made
 here is ever served to another test.
 """
@@ -24,6 +25,7 @@ import pytest
 from jax.sharding import NamedSharding, PartitionSpec as P
 
 from shared_tensor_tpu.models import char_rnn as m
+from shared_tensor_tpu.models import mla_moe
 from shared_tensor_tpu.ops import codec_pallas, table
 from shared_tensor_tpu.parallel import (
     PeerSyncState,
@@ -128,3 +130,39 @@ def test_sync_step_compiles_for_v5e_with_no_index_operand(
     assert text.count("tpu_custom_call") >= 2
     assert not re.findall(r"= \S+ (?:gather|scatter)\(", text)
     assert (" conditional(" in text) == (n_shard > 1)
+
+
+def test_mla_block_grad_compiles_for_v5e_with_the_attention_kernels(v5e_devices):
+    """``value_and_grad`` of one decoder block of models/mla_moe.py at the
+    published widths (32 heads of 192 / 128) and 8 192 tokens, mapped over a
+    peer axis of one as ``build_train_step`` maps the loss: Mosaic takes the
+    two attention kernels at their real tiles and VMEM, both sit in
+    ``st.mla.attn``, and no loop is left there (the scan's tiles are gone from
+    the chip path). About 8 s."""
+    cfg = mla_moe.Config(num_hidden_layers=1, num_nextn_predict_layers=0)
+    t = 8192
+    mesh = make_mesh(1, 1, devices=v5e_devices)
+    arg = lambda *shape: jax.ShapeDtypeStruct(
+        (1, *shape), jnp.float32, sharding=NamedSharding(mesh, P())
+    )
+    prefix = "model.layers.0."
+    params = {
+        name[len(prefix):]: arg(*shape)
+        for name, shape in mla_moe.param_shapes(cfg).items() if name.startswith(prefix)
+    }
+
+    def loss(p, x):
+        rope = mla_moe.rope_tables(t, cfg.qk_rope_head_dim, cfg.rope_theta)
+        return jnp.sum(mla_moe.block(p, x, rope, cfg, is_moe=False)[0])
+
+    text = jax.jit(jax.vmap(jax.value_and_grad(loss))).lower(
+        params, arg(t, cfg.hidden_size)
+    ).compile().as_text()
+    kernels = {
+        name: line for line in text.splitlines() if "tpu_custom_call" in line
+        for name in re.findall(r"%(st_attn_\w+?)(?:\.\d+)? = ", line)
+    }
+    assert sorted(kernels) == ["st_attn_bwd", "st_attn_fwd"]
+    for line in kernels.values():
+        assert "st.mla.attn" in re.search(r'op_name="([^"]*)"', line).group(1)
+    assert not [l for l in text.splitlines() if " while(" in l and "st.mla.attn" in l]
