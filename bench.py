@@ -8,8 +8,8 @@ the wire carries only 0.03 GB/s; one core saturates on the quantize/apply
 loops, which is exactly the work reference README.md:47 wanted moved to an
 accelerator kernel). This bench therefore times that bottleneck work on the
 TPU: per frame, one full sender half (pow2-RMS scale + sign-quantize +
-bit-pack + error feedback, Pallas) plus one receiver half (unpack + apply,
-Pallas) on an n = 1 Mi buffer — the identical per-link per-frame math at
+bit-pack + error feedback) plus one receiver half (unpack + apply) on an
+n = 1 Mi buffer — the identical per-link per-frame math at
 identical approximation error (the codec is bit-for-bit the reference
 arithmetic; tests/test_codec*.py pin that). Frames are chained device-side
 via lax.fori_loop into multi-second runs so per-dispatch cost is a small
@@ -20,9 +20,9 @@ One process per chip: this process NEVER imports jax itself (a parent that
 has touched JAX holds the chip, and a child that needs it then fails or
 hangs). Every measurement runs in a watchdogged subprocess with a hard
 timeout, under a total wall-clock budget (ST_BENCH_BUDGET_S, default 420 s).
-Arm ladder: chip + Pallas (the headline; retried with backoff if the backend
-does not come up) -> chip + XLA codec (only if the backend came up but
-Mosaic failed) -> CPU + native engine E2E (the host production data plane,
+Arm ladder: chip + the scalar XLA codec (ops/codec.py; retried with backoff if
+the backend does not come up; the scalar Pallas kernels this arm once timed
+are gone, PR 31) -> CPU + native engine E2E (the host production data plane,
 2-process loopback through the FULL stack, degraded-labeled) -> CPU + host
 codec component loop (numpy/AVX-512-C, jax-free) -> CPU + XLA (last resort).
 Exactly ONE JSON line is always printed, recording which arms ran and how
@@ -140,19 +140,7 @@ def _worker(codec_name: str) -> None:
         flush=True,
     )
 
-    if codec_name == "pallas":
-        from shared_tensor_tpu.ops import codec_pallas as codec
-
-        if backend != "tpu":
-            # Interpret-mode Pallas is orders of magnitude slower than the
-            # XLA codec and would masquerade as a kernel number — fail fast
-            # so the supervisor falls through to the honest arm.
-            raise RuntimeError(
-                f"pallas arm needs a tpu backend; got {backend} "
-                "(would run interpret mode)"
-            )
-    else:
-        from shared_tensor_tpu.ops import codec
+    from shared_tensor_tpu.ops import codec
 
     from shared_tensor_tpu.config import ScalePolicy
     from shared_tensor_tpu.utils.timing import codec_frame_time
@@ -358,34 +346,21 @@ def main() -> None:
         budget_left = _remaining() - CPU_RESERVE_S
         if budget_left < 75:
             break
-        parsed, backend, outcome, err = _run_arm(None, "pallas", min(budget_left, 270.0))
-        note(None, "pallas", outcome, err)
+        parsed, backend, outcome, err = _run_arm(None, "xla", min(budget_left, 270.0))
+        note(None, "xla", outcome, err)
         if _tpu_like(backend):
             chip_state = "up"
         elif chip_state == "not-tried":
             chip_state = "unavailable"
-        if parsed is not None:
+        if parsed is not None and _tpu_like(backend):
             best = parsed
             break
         if backend is not None:
-            # Backend is fine; the Pallas path itself failed (e.g. Mosaic
-            # rejection). Do NOT re-enter Pallas — try the XLA codec on the
-            # SAME (TPU) backend. If the ambient backend instead resolved
-            # to CPU (no TPU plugin registered at all), skip straight to
-            # Phase B: its ladder puts the native-engine E2E first and
-            # XLA-CPU LAST — before r07 this branch ran XLA-CPU here and
-            # its ~2.6 GB/s short-circuited the ~6x-better engine arm
-            # whenever the backend came up as CPU instead of hanging.
-            if not _tpu_like(backend):
-                break
-            budget_left = _remaining() - CPU_RESERVE_S
-            if budget_left >= 75:
-                parsed, backend, outcome, err = _run_arm(
-                    None, "xla", min(budget_left, 270.0)
-                )
-                note(None, "xla", outcome, err)
-                if parsed is not None:
-                    best = parsed
+            # The backend came up and the measurement failed (a retry would
+            # fail alike), or the ambient backend resolved to CPU (no TPU
+            # plugin registered at all): Phase B's ladder puts the
+            # native-engine E2E first and XLA-CPU LAST, so an XLA-CPU rate
+            # taken here must not short-circuit the ~6x-better engine arm.
             break
         tries += 1
         backoff = min(20.0 * tries, max(0.0, _remaining() - CPU_RESERVE_S - 75))

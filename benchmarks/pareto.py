@@ -73,7 +73,7 @@ def main() -> None:
     args = ap.parse_args()
 
     from shared_tensor_tpu.config import ScalePolicy
-    from shared_tensor_tpu.ops import codec_pallas as codec
+    from shared_tensor_tpu.ops import codec
     from shared_tensor_tpu.utils.compile_cache import enable_compile_cache
 
     enable_compile_cache()

@@ -22,8 +22,8 @@ float32 TPU tile. Invariant: padding lanes of residuals and values are always
 exactly 0 (quantize/apply mask them), so full-array reductions need no mask.
 
 This module is the pure-JAX *golden* implementation; the fused
-single-HBM-pass Pallas kernels (ops/codec_pallas.py, built on top of this)
-must match it bit-for-bit.
+single-HBM-pass Pallas row kernels (ops/codec_pallas.py, behind ops/table.py's
+row codec) must match it bit-for-bit on a single-leaf table.
 """
 
 from __future__ import annotations

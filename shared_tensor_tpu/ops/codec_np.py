@@ -252,7 +252,7 @@ def compute_scales_np(
     per_leaf: bool = True,
 ) -> np.ndarray:
     """Per-leaf scales, overflow-safe (normalize by max|r| before squaring —
-    quirk Q9 fix, matching ops/table.compute_scales). With the native tier
+    quirk Q9 fix, matching ops/table.leaf_scales). With the native tier
     the reductions run as ONE fused C pass with double accumulators
     (overflow-safe without the normalization); scales can differ from the
     f32 tiers by ~1 ulp of rounding, which any tier tolerates — the scale is

@@ -1,26 +1,35 @@
-"""Pallas TPU kernels for the approximate-delta codec.
+"""Pallas TPU kernels for the approximate-delta codec at row granularity.
 
 The reference's hot path is 4-6 sequential CPU passes of n float ops per frame
 (quantize src/sharedtensor.c:153-174, apply :106-111 — measured codec-CPU-bound
-at 202 M elem/s, BASELINE.md). These kernels move that work onto the TPU VPU
-with the minimum number of HBM passes:
+at 202 M elem/s, BASELINE.md). These two kernels move that work onto the TPU
+VPU with the minimum number of HBM passes. The table codec (ops/table.py) runs
+the sign/error-feedback rule with a scale per leaf, and per-leaf padding is
+row-aligned, so at kernel granularity that is "a scale per (1, 128) row" plus
+"live lanes per row":
 
-- ``quantize``: one reduction pass for the scale (XLA — it is a dependency of
-  every element, so a second pass is inherent, exactly as in the reference),
-  then ONE fused pass that sign-quantizes, packs the bits into LSB-first
-  uint32 words, and applies the error feedback to the residual.
-- ``apply_frame_many``: ONE fused pass that unpacks the bits once and adds the
-  reconstructed +/-scale delta to K arrays (replica + other links' residuals —
-  the split-horizon flood), instead of K separate unpack+apply passes.
+- ``quantize_rows``: ONE fused pass that sign-quantizes, packs the bits into
+  LSB-first uint32 words, and applies the error feedback to the residual (the
+  scales are a dependency of every element, so their reduction passes come
+  first, in XLA, exactly as in the reference).
+- ``apply_rows_batch``: ONE fused pass that unpacks K frames once, sums their
+  +/-scale deltas and adds the sum to N arrays (replica + other links'
+  residuals — the split-horizon flood), instead of K x N unpack+apply passes.
+
+Each has exactly one caller, ops/table.py (``quantize_rows`` / ``apply_rows``),
+which holds their XLA twins and builds their operands; both are deliberately
+UN-jitted, since the table functions wrap them in their own jit and
+parallel/ici.py embeds them inside a shard_map'd step.
 
 Bit layout is identical to ops/codec.py (flat bit i -> word[i//32] bit i%32),
-so frames from either implementation interoperate; parity tests in
-tests/test_codec_pallas.py require bit-for-bit equality.
+so frames from either implementation interoperate; tests/test_codec_pallas.py
+and tests/test_table_pallas.py require bit-for-bit equality with the golden
+codec and with the XLA twins.
 
 Kernels run compiled on TPU and fall back to the interpreter on CPU (tests).
 
-Layout: flat padded length n_pad (multiple of 1024) viewed as (n_pad/128, 128)
-float32 rows; packed words viewed as (n_pad/128, 4) uint32 rows. Row r, word k
+Layout: a flat padded buffer (a multiple of 1024) viewed as (rows, 128)
+float32 rows; packed words viewed as (rows, 4) uint32 rows. Row r, word k
 covers flat bits 128*r + 32*k .. +31, so ``words2d.reshape(-1)`` is the flat
 word vector used by the wire layer.
 """
@@ -35,8 +44,7 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu  # noqa: F401  (memory spaces)
 
-from ..config import ScalePolicy
-from .codec import SAT, Frame, compute_scale
+from .codec import SAT
 from .packing import LANES, BITS_PER_WORD
 
 WORDS_PER_ROW = LANES // BITS_PER_WORD  # 4
@@ -62,7 +70,7 @@ def _interpret() -> bool:
 
 
 def use_pallas() -> bool:
-    """Should the production codec paths (ops/table.py, parallel/ici.py) run
+    """Should the production codec path (ops/table.py's row codec) run
     these kernels? Default: yes exactly on a tpu backend, where they
     compile; elsewhere the pure-XLA codec runs (on CPU it is faster than the
     Pallas interpreter). ``ST_CODEC=pallas|xla`` overrides (tests use it to
@@ -73,14 +81,6 @@ def use_pallas() -> bool:
     if mode == "xla":
         return False
     return jax.default_backend() == "tpu"
-
-
-def _live_mask(block_rows: int, pid, n: int):
-    """live[i,j] = (flat index of element (i,j) in this block) < n."""
-    row = jax.lax.broadcasted_iota(jnp.int32, (block_rows, LANES), 0)
-    lane = jax.lax.broadcasted_iota(jnp.int32, (block_rows, LANES), 1)
-    flat = (pid * block_rows + row) * LANES + lane
-    return flat < n
 
 
 def _exact_pow2(e_i32):
@@ -136,136 +136,6 @@ def _unpack_rows(words_u32):
     return (wrep >> shift) & jnp.int32(1)
 
 
-# --- quantize --------------------------------------------------------------
-
-
-def _quantize_kernel(scale_ref, resid_ref, words_ref, new_resid_ref, *, n):
-    s = scale_ref[0, 0]
-    r = resid_ref[...]
-    live = _live_mask(r.shape[0], pl.program_id(0), n)
-    neg = r <= 0.0  # bit set => send -scale (zero counts as negative, Q3)
-    bits = jnp.logical_and(live, neg)
-    words_ref[...] = _pack_rows(bits.astype(jnp.int32))
-    sent = jnp.where(neg, -s, s)
-    # s == 0: idle frame, residual untouched; padding lanes are forced back
-    # to 0 either way (re-establishes the invariant even if the caller handed
-    # us a buffer with garbage past n — matches ops/codec.py exactly).
-    new_r = jnp.where(jnp.logical_and(live, s > 0.0), r - sent, jnp.where(live, r, 0.0))
-    new_resid_ref[...] = new_r
-
-
-@partial(jax.jit, static_argnames=("n", "policy"), donate_argnums=(0,))
-def quantize(
-    residual: jnp.ndarray,
-    n: int,
-    policy: ScalePolicy = ScalePolicy.POW2_RMS,
-) -> tuple[Frame, jnp.ndarray]:
-    """Drop-in replacement for ops.codec.quantize (bit-for-bit identical),
-    with the quantize/pack/error-feedback pass as a single fused kernel.
-
-    The residual argument is donated: on TPU the new residual reuses the old
-    one's HBM buffer (callers in the sync engine always replace it).
-    """
-    n_pad = residual.shape[0]
-    rows = n_pad // LANES
-    block = min(BLOCK_ROWS, rows)
-    scale = compute_scale(residual, n, policy)
-    words2d, new_resid = pl.pallas_call(
-        partial(_quantize_kernel, n=n),
-        grid=(pl.cdiv(rows, block),),
-        in_specs=[
-            pl.BlockSpec((1, 1), lambda i: (0, 0), memory_space=pltpu.SMEM),
-            pl.BlockSpec((block, LANES), lambda i: (i, 0), memory_space=pltpu.VMEM),
-        ],
-        out_specs=[
-            pl.BlockSpec(
-                (block, WORDS_PER_ROW), lambda i: (i, 0), memory_space=pltpu.VMEM
-            ),
-            pl.BlockSpec((block, LANES), lambda i: (i, 0), memory_space=pltpu.VMEM),
-        ],
-        out_shape=[
-            jax.ShapeDtypeStruct((rows, WORDS_PER_ROW), jnp.uint32),
-            jax.ShapeDtypeStruct((rows, LANES), jnp.float32),
-        ],
-        input_output_aliases={1: 1},  # new residual reuses the old buffer
-        interpret=_interpret(),
-        name="st_quantize",
-    )(scale.reshape(1, 1), residual.reshape(rows, LANES))
-    return Frame(scale, words2d.reshape(-1)), new_resid.reshape(-1)
-
-
-# --- apply -----------------------------------------------------------------
-
-
-def _apply_kernel(scale_ref, words_ref, *refs, n, k):
-    s = scale_ref[0, 0]
-    bits = _unpack_rows(words_ref[...])
-    live = _live_mask(bits.shape[0], pl.program_id(0), n)
-    delta = s * (1.0 - 2.0 * bits.astype(jnp.float32))
-    in_refs, out_refs = refs[:k], refs[k:]
-    for i_ref, o_ref in zip(in_refs, out_refs):
-        # Padding lanes forced to 0; result clamped like the golden
-        # apply_frame (codec.SAT — no absorbing inf/NaN state, any tier).
-        o_ref[...] = jnp.where(
-            live, jnp.clip(i_ref[...] + delta, -SAT, SAT), 0.0
-        )
-
-
-@partial(jax.jit, static_argnames=("n",), donate_argnums=(0,))
-def apply_frame_many(
-    arrays: tuple[jnp.ndarray, ...], frame: Frame, n: int
-) -> tuple[jnp.ndarray, ...]:
-    """Fused receive-side flood: unpack the frame once, add the +/-scale delta
-    to every array (replica + other links' residuals) in one HBM pass.
-    Bit-for-bit identical to ops.codec.apply_frame_many. Arrays are donated
-    (updated in place on TPU)."""
-    k = len(arrays)
-    n_pad = arrays[0].shape[0]
-    rows = n_pad // LANES
-    block = min(BLOCK_ROWS, rows)
-    blk = lambda i: (i, 0)
-    vspec = pl.BlockSpec((block, LANES), blk, memory_space=pltpu.VMEM)
-    outs = pl.pallas_call(
-        partial(_apply_kernel, n=n, k=k),
-        grid=(pl.cdiv(rows, block),),
-        in_specs=[
-            pl.BlockSpec((1, 1), lambda i: (0, 0), memory_space=pltpu.SMEM),
-            pl.BlockSpec(
-                (block, WORDS_PER_ROW), blk, memory_space=pltpu.VMEM
-            ),
-        ]
-        + [vspec] * k,
-        out_specs=[vspec] * k,
-        out_shape=[jax.ShapeDtypeStruct((rows, LANES), jnp.float32)] * k,
-        input_output_aliases={2 + i: i for i in range(k)},
-        interpret=_interpret(),
-        name="st_apply_frame_many",
-    )(
-        frame.scale.reshape(1, 1),
-        frame.words.reshape(rows, WORDS_PER_ROW),
-        *[a.reshape(rows, LANES) for a in arrays],
-    )
-    return tuple(o.reshape(-1) for o in outs)
-
-
-@partial(jax.jit, static_argnames=("n",), donate_argnums=(0,))
-def apply_frame(values: jnp.ndarray, frame: Frame, n: int) -> jnp.ndarray:
-    """Single-array apply (see apply_frame_many)."""
-    return apply_frame_many((values,), frame, n)[0]
-
-
-# --- row-granular primitives (the table tier) -------------------------------
-#
-# The table codec (ops/table.py) runs the same sign/error-feedback rule with a
-# DIFFERENT scale per leaf — per-leaf padding is row-aligned, so at kernel
-# granularity that is simply "a scale per (1,128) row" plus "live lanes per
-# row". These two primitives are the fused production tier for it (round-2
-# verdict: the scalar kernels above were proven on chip but only the pure-XLA
-# path shipped; these are what ops/table.py and parallel/ici.py now call).
-# They are deliberately UN-jitted: table.py wraps them in its own jit, and
-# parallel/ici.py embeds them inside a shard_map'd step.
-
-
 def _quantize_rows_kernel(s_ref, cnt_ref, resid_ref, words_ref, new_resid_ref):
     s = s_ref[...]  # (block, 1) per-row scale
     c = cnt_ref[...]  # (block, 1) live lanes per row (0..128)
@@ -292,7 +162,7 @@ def quantize_rows(
     ``s_row`` f32[rows] (leaf scale broadcast to its rows), ``rowcount``
     i32[rows] (live lanes per row), ``residual`` f32[rows*128] flat.
     Returns (words u32[rows*4] flat, new_residual flat). Traceable — callers
-    jit. Bit-for-bit equal to the ops/table.py XLA path.
+    jit. Bit-for-bit equal to its XLA twin in ops/table.py.
     """
     rows = residual.shape[0] // LANES
     block = min(BLOCK_ROWS, rows)

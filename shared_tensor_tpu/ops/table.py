@@ -212,41 +212,138 @@ def unflatten(flat: jnp.ndarray, spec: TableSpec) -> Any:
         return jax.tree.unflatten(spec.treedef, leaves)
 
 
-def _live_mask_flat(spec: TableSpec) -> np.ndarray:
-    """bool[total]: True for live (non-padding) elements."""
-    rows = spec.live_rowcount()
-    lane = np.arange(LANES, dtype=np.int32)
-    return (lane[None, :] < rows[:, None]).reshape(-1)
+def resolve_impl(impl: str) -> str:
+    """'auto' -> the Pallas kernels exactly when they would compile (TPU);
+    pure XLA elsewhere (CPU tests/peers). See codec_pallas.use_pallas."""
+    if impl != "auto":
+        return impl
+    from . import codec_pallas
+
+    return "pallas" if codec_pallas.use_pallas() else "xla"
 
 
-def compute_scales(
-    residual: jnp.ndarray,
-    spec: TableSpec,
-    policy: ScalePolicy = ScalePolicy.POW2_RMS,
+# --- the row codec ----------------------------------------------------------
+#
+# One 1-bit codec pass at row granularity, written once: per-leaf scales, the
+# sender's quantize pass and the receiver's K-frame apply pass, each with its
+# Pallas call and its XLA twin in one body. The table functions below and the
+# pod step (parallel/ici.py) compute their scales and their per-leaf ->
+# per-row expansion, then call these; nothing else knows which operands the
+# kernels of ops/codec_pallas.py take. ``impl`` is a resolved tier ("pallas"
+# or "xla", :func:`resolve_impl`); ``rowcount`` i32[rows] is the number of
+# live lanes of each row (``TableSpec.live_rowcount``, or a shard's slice).
+
+
+def live_lanes(rowcount: jnp.ndarray) -> jnp.ndarray:
+    """bool[rows, 128]: True for live (non-padding) lanes."""
+    lane = jax.lax.broadcasted_iota(jnp.int32, (rowcount.shape[0], LANES), 1)
+    return lane < rowcount[:, None]
+
+
+def leaf_scales(
+    rows: jnp.ndarray,
+    live: jnp.ndarray,
+    reduce,
+    expand,
+    ns: jnp.ndarray,
+    policy: ScalePolicy,
+    shard_axis: str | None = None,
 ) -> jnp.ndarray:
-    """Per-leaf step sizes (overflow-safe per-leaf RMS; see
-    codec.compute_scale for the scalar version this generalizes)."""
-    ranges = spec.leaf_rows
-    rows = residual.reshape(-1, LANES)
-    amax_row = jnp.max(jnp.abs(rows), axis=1)
-    amax = jnp.maximum(leaf_reduce(amax_row, ranges, "max"), 0.0)  # empty leaf: -inf
-    denom = jnp.where(amax > 0, amax, 1.0)
-    norm = rows / leaf_expand(denom, ranges)[:, None]
-    ns = jnp.asarray(np.asarray(spec.ns, dtype=np.float32))
-    moment = jnp.abs(norm) if policy == ScalePolicy.ABS_MEAN else norm * norm
-    part = jnp.sum(moment, axis=1, dtype=jnp.float32)
-    mean = leaf_reduce(part, ranges, "sum") / ns
-    if policy == ScalePolicy.ABS_MEAN:
-        scales = amax * mean
-    else:
-        rms = amax * jnp.sqrt(mean)
-        scales = pow2_floor(rms) if policy == ScalePolicy.POW2_RMS else rms
-    rms_pos = amax > 0
-    return jnp.where(rms_pos & jnp.isfinite(scales), scales, 0.0)
+    """Per-leaf step sizes f32[k] of ``rows`` f32[rows, 128]: the
+    overflow-safe normalized RMS (codec.compute_scale is the scalar version
+    this generalizes). Two dense row passes, their per-row partials reduced
+    per leaf by ``reduce(x[rows], "max" | "sum") -> [k]``, the leaf maximum
+    brought back to rows by ``expand([k]) -> [rows]``; ``ns`` f32[k] is each
+    leaf's live element count. With ``shard_axis`` the rows are one shard's
+    and both reductions cross that mesh axis (k floats a frame)."""
+    with jax.named_scope("st.leaf_scales"):
+        amax_row = jnp.max(jnp.where(live, jnp.abs(rows), 0.0), axis=1)
+        # a leaf with no row here (empty, or on another shard) reads -inf
+        amax = jnp.maximum(reduce(amax_row, "max"), 0.0)
+        if shard_axis is not None:
+            amax = jax.lax.pmax(amax, shard_axis)
+        denom = jnp.where(amax > 0, amax, 1.0)
+        norm = jnp.where(live, rows / expand(denom)[:, None], 0.0)
+        moment = jnp.abs(norm) if policy == ScalePolicy.ABS_MEAN else norm * norm
+        total = reduce(jnp.sum(moment, axis=1, dtype=jnp.float32), "sum")
+        if shard_axis is not None:
+            total = jax.lax.psum(total, shard_axis)
+        mean = total / ns
+        if policy == ScalePolicy.ABS_MEAN:
+            scales = amax * mean
+        else:
+            rms = amax * jnp.sqrt(mean)
+            scales = pow2_floor(rms) if policy == ScalePolicy.POW2_RMS else rms
+        return jnp.where((amax > 0) & jnp.isfinite(scales), scales, 0.0)
+
+
+def quantize_rows(
+    s_row: jnp.ndarray, rowcount: jnp.ndarray, residual: jnp.ndarray, impl: str
+) -> tuple[jnp.ndarray, jnp.ndarray]:
+    """Sender pass: sign-quantize + LSB-first pack + error feedback with a
+    scale per row. ``s_row`` f32[rows] (the leaf's scale over its rows),
+    ``residual`` f32[rows*128] flat -> (words u32[rows*4], residual'). Bit
+    set iff r <= 0; a row at scale 0 idles; padding lanes are forced to 0."""
+    with jax.named_scope("st.quantize"):
+        if impl == "pallas":
+            from . import codec_pallas
+
+            return codec_pallas.quantize_rows(s_row, rowcount, residual)
+        r = residual.reshape(-1, LANES)
+        live = live_lanes(rowcount)
+        s = s_row[:, None]  # (rows, 1)
+        neg = r <= 0.0
+        sent = jnp.where(neg, -s, s)
+        r2 = jnp.where(live & (s > 0), r - sent, jnp.where(live, r, 0.0))
+        return pack_bits(jnp.logical_and(live, neg).reshape(-1)), r2.reshape(-1)
+
+
+def apply_rows(
+    s_rows: jnp.ndarray,
+    rowcount: jnp.ndarray,
+    words: jnp.ndarray,
+    arrays: tuple[jnp.ndarray, ...],
+    impl: str,
+) -> tuple[jnp.ndarray, ...]:
+    """Receiver pass: the summed +/-scale delta of K frames (codec deltas are
+    pure adds, so they commute) added to every array in one pass, clamped to
+    +/-codec.SAT, padding lanes forced to 0. ``s_rows`` f32[K, rows] (a
+    frame's row is 0 where it contributes nothing), ``words`` u32[K, rows*4],
+    ``arrays`` flat f32[rows*128] each. Owns the kernel's operand layout."""
+    k, rows = s_rows.shape
+    if impl == "pallas":
+        from . import codec_pallas
+
+        with jax.named_scope("st.words_layout"):
+            # frame k's words for row r at [r, 4k:4k+4]
+            words2d = (
+                words.reshape(k, rows, LANES // 32)
+                .transpose(1, 0, 2)
+                .reshape(rows, k * (LANES // 32))
+            )
+        with jax.named_scope("st.apply"):
+            return codec_pallas.apply_rows_batch(s_rows.T, rowcount, words2d, arrays)
+    with jax.named_scope("st.words_layout"):
+        bits = unpack_bits(words).reshape(k, rows, LANES).astype(jnp.float32)
+    with jax.named_scope("st.apply"):
+        live = live_lanes(rowcount)
+        # elementwise + sum on the VPU: under the RMS policy a scale is
+        # arbitrary, so the arithmetic stays exact f32, no MXU
+        delta = jnp.sum(s_rows[:, :, None] * (1.0 - 2.0 * bits), axis=0)
+        return tuple(
+            jnp.where(
+                live, jnp.clip(a.reshape(rows, LANES) + delta, -SAT, SAT), 0.0
+            ).reshape(-1)
+            for a in arrays
+        )
+
+
+# --- the table codec --------------------------------------------------------
 
 
 def _table_scales(
     residual: jnp.ndarray,
+    rowcount: jnp.ndarray,
     spec: TableSpec,
     policy: ScalePolicy,
     per_leaf: bool,
@@ -256,27 +353,20 @@ def _table_scales(
     interop with C peers requires it) replicated to every leaf so the apply
     path is uniform."""
     if per_leaf:
-        return compute_scales(residual, spec, policy)
-    one_spec = dataclasses.replace(
-        spec,
-        shapes=((spec.total_n,),),
-        ns=(spec.total_n,),
-        padded=(spec.total,),
+        ranges, ns = spec.leaf_rows, spec.ns
+    else:
+        ranges, ns = ((0, spec.total // LANES),), (spec.total_n,)
+    scales = leaf_scales(
+        residual.reshape(-1, LANES),
+        live_lanes(rowcount),
+        lambda x, op: leaf_reduce(x, ranges, op),
+        lambda v: leaf_expand(v, ranges),
+        jnp.asarray(np.asarray(ns, dtype=np.float32)),
+        policy,
     )
-    # NOTE: valid because padding lanes are 0 by invariant; the single-
-    # leaf view only changes which elements each scale aggregates over.
-    s = compute_scales(residual, one_spec, policy)[0]
-    return jnp.full((spec.num_leaves,), s, jnp.float32)
-
-
-def _resolve_impl(impl: str) -> str:
-    """'auto' -> the Pallas kernels exactly when they would compile (TPU);
-    pure XLA elsewhere (CPU tests/peers). See codec_pallas.use_pallas."""
-    if impl != "auto":
-        return impl
-    from . import codec_pallas
-
-    return "pallas" if codec_pallas.use_pallas() else "xla"
+    if per_leaf:
+        return scales
+    return jnp.full((spec.num_leaves,), scales[0], jnp.float32)
 
 
 @partial(jax.jit, static_argnames=("spec", "policy", "per_leaf", "impl"))
@@ -287,26 +377,12 @@ def _quantize_table(
     per_leaf: bool,
     impl: str,
 ) -> tuple[TableFrame, jnp.ndarray]:
-    scales = _table_scales(residual, spec, policy, per_leaf)
-    s_row = leaf_expand(scales, spec.leaf_rows)
-    if impl == "pallas":
-        from . import codec_pallas
-
-        words, new_flat = codec_pallas.quantize_rows(
-            s_row, jnp.asarray(spec.live_rowcount()), residual
-        )
-        return TableFrame(scales, words), new_flat
-    rows = residual.reshape(-1, LANES)
-    s_row = s_row[:, None]  # (rows, 1)
-    live = jnp.asarray(_live_mask_flat(spec)).reshape(-1, LANES)
-    neg = rows <= 0
-    bits = jnp.where(live, neg, False)
-    sent = jnp.where(neg, -s_row, s_row)
-    new_rows = jnp.where(live & (s_row > 0), rows - sent, jnp.where(live, rows, 0.0))
-    return (
-        TableFrame(scales, pack_bits(bits.reshape(-1))),
-        new_rows.reshape(-1),
+    rowcount = jnp.asarray(spec.live_rowcount())
+    scales = _table_scales(residual, rowcount, spec, policy, per_leaf)
+    words, new_flat = quantize_rows(
+        leaf_expand(scales, spec.leaf_rows), rowcount, residual, impl
     )
+    return TableFrame(scales, words), new_flat
 
 
 def quantize_table(
@@ -322,10 +398,9 @@ def quantize_table(
     residual moves by -+scale of its own leaf, leaves with scale 0 idle.
 
     On TPU the sign/pack/error-feedback pass runs as the fused Pallas kernel
-    (codec_pallas.quantize_rows) — the production tier; the XLA path is the
-    golden reference and the CPU fallback. ``impl`` pins either ("xla" /
-    "pallas") for parity tests."""
-    return _quantize_table(residual, spec, policy, per_leaf, _resolve_impl(impl))
+    (:func:`quantize_rows`) — the production tier; its XLA twin is the CPU
+    fallback. ``impl`` pins either ("xla" / "pallas") for parity tests."""
+    return _quantize_table(residual, spec, policy, per_leaf, resolve_impl(impl))
 
 
 @partial(jax.jit, static_argnames=("spec", "k", "policy", "per_leaf", "impl"))
@@ -362,47 +437,21 @@ def quantize_table_burst(
     frame in the scan is an exact no-op (scale 0 idles), so the host side
     trims the zero tail after the fetch."""
     return _quantize_table_burst(
-        residual, spec, int(k), policy, per_leaf, _resolve_impl(impl)
+        residual, spec, int(k), policy, per_leaf, resolve_impl(impl)
     )
-
-
-def _batch_layout(frames: TableFrame, spec: TableSpec):
-    """(scales [K,L], words [K,W]) -> the row-major layout the Pallas batch
-    kernel consumes: s_rows f32[rows, K], words2d u32[rows, K*4] (frame k's
-    words for row r at [r, 4k:4k+4])."""
-    k = frames.scales.shape[0]
-    rows = spec.total // LANES
-    s_rows = leaf_expand(frames.scales, spec.leaf_rows).T  # (rows, K)
-    words2d = (
-        frames.words.reshape(k, rows, LANES // 32)
-        .transpose(1, 0, 2)
-        .reshape(rows, k * (LANES // 32))
-    )
-    return s_rows, words2d
 
 
 @partial(jax.jit, static_argnames=("spec", "impl"))
-def _apply_table_many(
-    arrays: tuple[jnp.ndarray, ...], frame: TableFrame, spec: TableSpec, impl: str
+def _apply_table_batch(
+    arrays: tuple[jnp.ndarray, ...], frames: TableFrame, spec: TableSpec, impl: str
 ) -> tuple[jnp.ndarray, ...]:
-    s_row = leaf_expand(frame.scales, spec.leaf_rows)[:, None]  # (rows, 1)
-    if impl == "pallas":
-        from . import codec_pallas
-
-        rows = spec.total // LANES
-        return codec_pallas.apply_rows_batch(
-            s_row,
-            jnp.asarray(spec.live_rowcount()),
-            frame.words.reshape(rows, LANES // 32),
-            arrays,
-        )
-    bits = unpack_bits(frame.words).reshape(-1, LANES)
-    live = jnp.asarray(_live_mask_flat(spec)).reshape(-1, LANES)
-    delta = jnp.where(live, s_row * (1.0 - 2.0 * bits.astype(jnp.float32)), 0.0)
-    flat_delta = delta.reshape(-1)
-    return tuple(
-        jnp.where(live.reshape(-1), jnp.clip(a + flat_delta, -SAT, SAT), 0.0)
-        for a in arrays
+    # one frame (scales [L], words [W]) is the K = 1 stack
+    return apply_rows(
+        leaf_expand(jnp.atleast_2d(frames.scales), spec.leaf_rows),  # [K, rows]
+        jnp.asarray(spec.live_rowcount()),
+        jnp.atleast_2d(frames.words),
+        arrays,
+        impl,
     )
 
 
@@ -413,36 +462,13 @@ def apply_table_many(
     impl: str = "auto",
 ) -> tuple[jnp.ndarray, ...]:
     """Receiver step over a table applied to several arrays (replica + other
-    links' residuals — the flood), one fused pass (Pallas on TPU)."""
-    return _apply_table_many(arrays, frame, spec, _resolve_impl(impl))
+    links' residuals — the flood), one fused pass (Pallas on TPU): the K = 1
+    case of :func:`apply_table_batch`."""
+    return _apply_table_batch(arrays, frame, spec, resolve_impl(impl))
 
 
 def apply_table(values: jnp.ndarray, frame: TableFrame, spec: TableSpec) -> jnp.ndarray:
     return apply_table_many((values,), frame, spec)[0]
-
-
-@partial(jax.jit, static_argnames=("spec", "impl"))
-def _apply_table_batch(
-    arrays: tuple[jnp.ndarray, ...], frames: TableFrame, spec: TableSpec, impl: str
-) -> tuple[jnp.ndarray, ...]:
-    if impl == "pallas":
-        from . import codec_pallas
-
-        s_rows, words2d = _batch_layout(frames, spec)
-        return codec_pallas.apply_rows_batch(
-            s_rows, jnp.asarray(spec.live_rowcount()), words2d, arrays
-        )
-    k = frames.scales.shape[0]
-    bits = unpack_bits(frames.words.reshape(-1)).reshape(k, -1, LANES)
-    s_row = leaf_expand(frames.scales, spec.leaf_rows)[:, :, None]  # [K, rows, 1]
-    live = jnp.asarray(_live_mask_flat(spec)).reshape(-1, LANES)
-    delta = jnp.sum(s_row * (1.0 - 2.0 * bits.astype(jnp.float32)), axis=0)
-    flat_delta = jnp.where(live, delta, 0.0).reshape(-1)
-    live_flat = live.reshape(-1)
-    return tuple(
-        jnp.where(live_flat, jnp.clip(a + flat_delta, -SAT, SAT), 0.0)
-        for a in arrays
-    )
 
 
 def apply_table_batch(
@@ -463,8 +489,8 @@ def apply_table_batch(
     batch up to a bucketed K to bound jit specializations.
 
     On TPU the unpack/sum/apply runs as ONE fused Pallas pass
-    (codec_pallas.apply_rows_batch) instead of K XLA unpack passes."""
-    return _apply_table_batch(arrays, frames, spec, _resolve_impl(impl))
+    (:func:`apply_rows`) instead of K XLA unpack passes."""
+    return _apply_table_batch(arrays, frames, spec, resolve_impl(impl))
 
 
 @partial(jax.jit, static_argnames=("spec",))
@@ -473,7 +499,7 @@ def accumulate_table(
 ) -> tuple[jnp.ndarray, ...]:
     """values += u and each link residual += u, sanitized (see
     codec.accumulate)."""
-    live = jnp.asarray(_live_mask_flat(spec))
+    live = live_lanes(jnp.asarray(spec.live_rowcount())).reshape(-1)
     u = jnp.where(live, update, 0.0)
     u = jnp.nan_to_num(u, nan=0.0, posinf=3.0e38, neginf=-3.0e38)
     return tuple(jnp.clip(a + u, -3.0e38, 3.0e38) for a in arrays)

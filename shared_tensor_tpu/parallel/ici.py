@@ -52,15 +52,20 @@ from jax import shard_map
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from ..config import MeshConfig, ScalePolicy
-from ..ops.codec import SAT, pow2_floor
-from ..ops.packing import BITS_PER_WORD, LANES, pack_bits, unpack_bits
+from ..ops.codec import SAT
+from ..ops.packing import BITS_PER_WORD, LANES
 from ..ops.table import (
     LeafRanges,
     TableSpec,
+    apply_rows,
     clip_ranges,
     flatten,
     leaf_expand,
     leaf_reduce,
+    leaf_scales,
+    live_lanes,
+    quantize_rows,
+    resolve_impl,
     unflatten,
 )
 from .mesh import rows_per_shard
@@ -210,9 +215,7 @@ class _StepCtx:
         rowcount = jax.lax.dynamic_slice_in_dim(
             self.rowcount_full, sid * self.rows_local, self.rows_local
         )
-        lane = jax.lax.broadcasted_iota(jnp.int32, (self.rows_local, LANES), 1)
-        live = lane < rowcount[:, None]
-        return rowcount, live
+        return rowcount, live_lanes(rowcount)
 
 
 def _make_ctx(
@@ -247,42 +250,21 @@ def _make_ctx(
 def _leaf_scales(
     ctx: _StepCtx, rows: jnp.ndarray, live: jnp.ndarray, policy: ScalePolicy
 ) -> jnp.ndarray:
-    """Per-leaf scales from this shard's rows, reduced over the shard axis.
-
-    Same overflow-safe normalized-RMS math as ops.table.compute_scales: two
-    dense row passes over the residual, their per-row partials reduced per
-    leaf over static row ranges (``ctx.leaf_reduce``), then a cross-shard
-    psum/pmax (this is where the sharded replica pays one small collective —
-    k floats — per frame)."""
-    with jax.named_scope("st.leaf_scales"):
-        amax_row = jnp.max(jnp.where(live, jnp.abs(rows), 0.0), axis=1)
-        # a leaf with no row on this shard reads -inf
-        amax = jnp.maximum(ctx.leaf_reduce(amax_row, "max"), 0.0)
-        amax = jax.lax.pmax(amax, ctx.shard_ax)
-        denom = jnp.where(amax > 0, amax, 1.0)
-        norm = jnp.where(live, rows / ctx.leaf_expand(denom)[:, None], 0.0)
-        moment = jnp.abs(norm) if policy == ScalePolicy.ABS_MEAN else norm * norm
-        part = jnp.sum(moment, axis=1, dtype=jnp.float32)
-        mean = jax.lax.psum(ctx.leaf_reduce(part, "sum"), ctx.shard_ax) / ctx.ns
-        if policy == ScalePolicy.ABS_MEAN:
-            scales = amax * mean
-        else:
-            rms = amax * jnp.sqrt(mean)
-            scales = pow2_floor(rms) if policy == ScalePolicy.POW2_RMS else rms
-        return jnp.where((amax > 0) & jnp.isfinite(scales), scales, 0.0)
+    """Per-leaf scales from this shard's rows (ops.table.leaf_scales over
+    this shard's leaf ranges), reduced over the shard axis: this is where the
+    sharded replica pays one small collective — k floats — per frame."""
+    return leaf_scales(
+        rows, live, ctx.leaf_reduce, ctx.leaf_expand, ctx.ns, policy, ctx.shard_ax
+    )
 
 
-def _codec_send(ctx: _StepCtx, policy: ScalePolicy, pallas_tier: bool, residual):
+def _codec_send(ctx: _StepCtx, policy: ScalePolicy, impl: str, residual):
     """Sender half of the pod sync, per shard block: per-leaf scales
-    (cross-shard reduction) + sign-quantize/pack/error-feedback + all-gather
-    of the packed frames over the peer axis — the wire is 1 bit/element +
-    k scales per peer over ICI. One source of truth for both the fused step
+    (cross-shard reduction) + sign-quantize/pack/error-feedback
+    (ops.table.quantize_rows, on the resolved tier ``impl``) + all-gather of
+    the packed frames over the peer axis — the wire is 1 bit/element + k
+    scales per peer over ICI. One source of truth for both the fused step
     (build_sync_step) and the overlap phases (build_sync_phases).
-
-    On TPU the quantize pass runs as the fused Pallas row kernel
-    (ops/codec_pallas.quantize_rows) — one HBM pass instead of XLA's
-    multi-pass pack lowering (measured in round 2: the XLA tail cost 49.8%
-    of a training step on chip).
 
     Returns (new_residual [flat], words_all [n_peer, W_local],
     scales_all [n_peer, k], scales_local [k])."""
@@ -292,70 +274,29 @@ def _codec_send(ctx: _StepCtx, policy: ScalePolicy, pallas_tier: bool, residual)
         scales = _leaf_scales(ctx, r, live, policy)
         with jax.named_scope("st.row_scales"):
             s_row = ctx.leaf_expand(scales)
-        with jax.named_scope("st.quantize"):
-            if pallas_tier:
-                from ..ops import codec_pallas
-
-                words, r2 = codec_pallas.quantize_rows(s_row, rowcount, residual)
-            else:
-                s_row = s_row[:, None]  # (rows, 1)
-                # sign-quantize + error feedback (reference :166-174)
-                neg = r <= 0.0
-                bits = jnp.logical_and(live, neg)
-                sent = jnp.where(neg, -s_row, s_row)
-                r2 = jnp.where(
-                    live & (s_row > 0), r - sent, jnp.where(live, r, 0.0)
-                ).reshape(-1)
-                words = pack_bits(bits.reshape(-1))
+        words, r2 = quantize_rows(s_row, rowcount, residual, impl)
         with jax.named_scope("st.allgather"):
             words_all = jax.lax.all_gather(words, ctx.peer_ax)  # (n_peer, W_local)
             scales_all = jax.lax.all_gather(scales, ctx.peer_ax)  # (n_peer, k)
     return r2, words_all, scales_all, scales
 
 
-def _codec_apply(ctx: _StepCtx, pallas_tier: bool, values, words_all, scales_all):
+def _codec_apply(ctx: _StepCtx, impl: str, values, words_all, scales_all):
     """Receiver half, per shard block: apply the sum of every OTHER peer's
-    frame (split horizon = zero out OUR column of the per-frame scales; a
+    frame (split horizon = zero out OUR row of the per-frame scales; a
     zero-scale frame contributes exactly nothing) to the local replica, in
-    one pass (fused Pallas on TPU). Result clamped to +/-codec.SAT like
-    every state-mutating path. Shared by build_sync_step and
-    build_sync_phases."""
+    one pass (ops.table.apply_rows, on the resolved tier ``impl``). Shared by
+    build_sync_step and build_sync_phases."""
     with jax.named_scope("st.codec_apply"):
-        rowcount, live = ctx.local_slices()
+        rowcount, _ = ctx.local_slices()
         with jax.named_scope("st.row_scales"):
             me = jax.lax.axis_index(ctx.peer_ax)
             s_all = jnp.where(
                 (jnp.arange(ctx.n_peer) == me)[:, None], 0.0, scales_all
             )
             s_all = ctx.leaf_expand(s_all)  # (n_peer, rows_local)
-        with jax.named_scope("st.words_layout"):
-            if pallas_tier:
-                words = (
-                    words_all.reshape(ctx.n_peer, ctx.rows_local, LANES // 32)
-                    .transpose(1, 0, 2)
-                    .reshape(ctx.rows_local, ctx.n_peer * (LANES // 32))
-                )
-            else:
-                words = (
-                    unpack_bits(words_all)
-                    .reshape(ctx.n_peer, ctx.rows_local, LANES)
-                    .astype(jnp.float32)
-                )
-        with jax.named_scope("st.apply"):
-            if pallas_tier:
-                from ..ops import codec_pallas
-
-                (v2,) = codec_pallas.apply_rows_batch(
-                    s_all.T, rowcount, words, (values,)
-                )
-                return v2
-            v = values.reshape(ctx.rows_local, LANES)
-            # elementwise+sum (VPU): s is a power of 2 and bits are 0/1, but
-            # under RMS policy s is arbitrary — keep the arithmetic exact
-            # f32, no MXU
-            delta = jnp.sum(s_all[:, :, None] * (1.0 - 2.0 * words), axis=0)
-            v2 = jnp.where(live, jnp.clip(v + delta, -SAT, SAT), 0.0)
-            return v2.reshape(-1)
+        (v2,) = apply_rows(s_all, rowcount, words_all, (values,), impl)
+        return v2
 
 
 def build_sync_step(
@@ -378,27 +319,20 @@ def build_sync_step(
     config 4's comparison): every pending residual is delivered in full fp32
     precision and residuals drop to exactly zero.
 
-    ``impl`` selects the codec tier around the all-gather: "auto" runs the
-    fused Pallas row kernels exactly when they compile (TPU) and pure XLA
-    elsewhere; "pallas"/"xla" pin a tier (parity tests).
+    ``impl`` is the row codec's tier (ops.table.resolve_impl: "auto" is the
+    Pallas kernels exactly where they compile, a TPU; "pallas"/"xla" pin one
+    for parity tests).
     """
     cfg = config or MeshConfig()
     ctx = _make_ctx(mesh, spec, per_leaf, cfg)
     peer_ax, shard_ax = ctx.peer_ax, ctx.shard_ax
-
-    pallas_tier = False
-    if compressed:
-        from ..ops.table import _resolve_impl
-
-        pallas_tier = _resolve_impl(impl) == "pallas"
+    impl = resolve_impl(impl)
 
     def _compressed_body(values, residual):
         """Compose the shared codec halves (same blocks as
         build_sync_phases — the compose-parity test pins the equivalence)."""
-        r2, words_all, scales_all, scales = _codec_send(
-            ctx, policy, pallas_tier, residual
-        )
-        v2 = _codec_apply(ctx, pallas_tier, values, words_all, scales_all)
+        r2, words_all, scales_all, scales = _codec_send(ctx, policy, impl, residual)
+        v2 = _codec_apply(ctx, impl, values, words_all, scales_all)
         return v2, r2, scales
 
     def _exact(values, residual):
@@ -428,7 +362,7 @@ def build_sync_step(
         out_specs=(spec_vr, spec_vr, P(peer_ax, None)),
         # pallas_call outputs carry no varying-mesh-axes annotation; disable
         # the vma checker for the kernel body (the XLA body keeps it)
-        check_vma=not pallas_tier,
+        check_vma=not (compressed and impl == "pallas"),
     )
 
     def sync_step(state: PeerSyncState) -> Tuple[PeerSyncState, jax.Array]:
@@ -478,17 +412,13 @@ def build_sync_phases(
     ``scales_all`` f32[n_peer, num_leaves] replicated (row p = the scales
     peer p transmitted — the same observability surface as build_sync_step).
     """
-    from ..ops.table import _resolve_impl
-
     cfg = config or MeshConfig()
     ctx = _make_ctx(mesh, spec, per_leaf, cfg)
-    pallas_tier = _resolve_impl(impl) == "pallas"
+    impl = resolve_impl(impl)
     spec_vr = P(ctx.peer_ax, ctx.shard_ax)
 
     def _send(residual_blk):
-        r2, words_all, scales_all, _ = _codec_send(
-            ctx, policy, pallas_tier, residual_blk[0]
-        )
+        r2, words_all, scales_all, _ = _codec_send(ctx, policy, impl, residual_blk[0])
         return r2[None], words_all, scales_all
 
     # check_vma off: the gathered outputs ARE peer-replicated (all_gather
@@ -505,7 +435,7 @@ def build_sync_phases(
     )
 
     def _apply(values_blk, words_all, scales_all):
-        v2 = _codec_apply(ctx, pallas_tier, values_blk[0], words_all, scales_all)
+        v2 = _codec_apply(ctx, impl, values_blk[0], words_all, scales_all)
         return v2[None]
 
     apply_gathered = shard_map(

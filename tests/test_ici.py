@@ -18,6 +18,7 @@ from shared_tensor_tpu.ops.table import (
     apply_table,
     make_spec,
     flatten,
+    live_lanes,
     quantize_table,
 )
 from shared_tensor_tpu.parallel import (
@@ -38,6 +39,12 @@ def template(key=0, shape=(40, 64)):
         "w": jax.random.normal(k1, shape, jnp.float32),
         "b": jax.random.normal(k2, (shape[1],), jnp.float32) * 1e-3,
     }
+
+
+def _live_mask(spec):
+    """f32[total]: 1 on live lanes, 0 on padding (what a real flatten leaves)."""
+    live = live_lanes(jnp.asarray(spec.live_rowcount()))
+    return live.reshape(-1).astype(jnp.float32)
 
 
 def test_mesh_shapes():
@@ -117,10 +124,7 @@ def test_conservation_invariant():
     ups = jax.random.normal(key, (4, spec.total)) * (
         jnp.arange(1, 5)[:, None].astype(jnp.float32)
     )
-    # zero the padding lanes like a real flatten would
-    from shared_tensor_tpu.ops.table import _live_mask_flat
-
-    ups = ups * jnp.asarray(_live_mask_flat(spec), jnp.float32)
+    ups = ups * _live_mask(spec)
     state = add_updates(state, ups)
 
     def ledger(st):
@@ -146,9 +150,7 @@ def test_eventual_consistency_convergence():
     state = init_state(mesh, spec, tpl)
     key = jax.random.PRNGKey(11)
     ups = jax.random.uniform(key, (4, spec.total), minval=-1.0, maxval=1.0)
-    from shared_tensor_tpu.ops.table import _live_mask_flat
-
-    ups = ups * jnp.asarray(_live_mask_flat(spec), jnp.float32)
+    ups = ups * _live_mask(spec)
     state = add_updates(state, ups)
     expect = flatten(tpl, spec) + ups.sum(0)
     step = build_sync_step(mesh, spec)

@@ -206,8 +206,6 @@ def test_schema_lint_every_emitted_st_name_is_documented():
         "st_trace": "Chrome trace_event category tag (trace_export.py)",
         "st_quantize_rows": "Pallas kernel name (ops/codec_pallas.py), shown in device traces",
         "st_apply_rows_batch": "Pallas kernel name (ops/codec_pallas.py), shown in device traces",
-        "st_quantize": "Pallas kernel name (ops/codec_pallas.py), shown in device traces",
-        "st_apply_frame_many": "Pallas kernel name (ops/codec_pallas.py), shown in device traces",
         "st_attn_fwd": "Pallas kernel name (ops/attention_pallas.py), shown in device traces",
         "st_attn_bwd": "Pallas kernel name (ops/attention_pallas.py), shown in device traces",
     }

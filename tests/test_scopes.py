@@ -13,7 +13,6 @@ import numpy as np
 import pytest
 
 from shared_tensor_tpu.ops import codec_pallas
-from shared_tensor_tpu.ops.codec import Frame
 from shared_tensor_tpu.ops.table import make_spec
 from shared_tensor_tpu.parallel import build_sync_step, init_state, make_mesh
 from shared_tensor_tpu.parallel.ici import add_updates
@@ -120,7 +119,6 @@ def _pallas_names(fn, *args):
 _ROWS = 16
 _FLAT = jnp.ones((_ROWS * 128,), jnp.float32)
 _COUNT = jnp.full((_ROWS,), 128, jnp.int32)
-_FRAME = Frame(jnp.float32(0.5), jnp.zeros((_ROWS * 4,), jnp.uint32))
 
 
 @pytest.mark.parametrize(
@@ -130,9 +128,6 @@ _FRAME = Frame(jnp.float32(0.5), jnp.zeros((_ROWS * 4,), jnp.uint32))
          (jnp.ones((_ROWS,)), _COUNT, _FLAT)),
         ("st_apply_rows_batch", codec_pallas.apply_rows_batch,
          (jnp.ones((_ROWS, 2)), _COUNT, jnp.zeros((_ROWS, 8), jnp.uint32), (_FLAT,))),
-        ("st_quantize", lambda r: codec_pallas.quantize(r, _ROWS * 128), (_FLAT,)),
-        ("st_apply_frame_many",
-         lambda a: codec_pallas.apply_frame_many((a,), _FRAME, _ROWS * 128), (_FLAT,)),
     ],
     ids=lambda v: v if isinstance(v, str) else "",
 )

@@ -641,3 +641,25 @@ def test_tsan_supp_entries_are_justified():
         )
     # the file documents the policy itself
     assert "TARGET STATE: EMPTY" in text
+
+
+@pytest.mark.parametrize("kernel", ["quantize_rows", "apply_rows_batch"])
+def test_codec_kernel_has_one_caller(kernel):
+    """The row kernels' operands are built in one place, ops/table.py's row
+    codec: a change to what a kernel takes is a change to one module."""
+    import ast
+
+    callers = []
+    for path in sorted((REPO / "shared_tensor_tpu").rglob("*.py")):
+        for node in ast.walk(ast.parse(path.read_text())):
+            if isinstance(node, ast.ImportFrom) and (node.module or "").endswith("codec_pallas"):
+                assert kernel not in [a.name for a in node.names], path
+            if (
+                isinstance(node, ast.Call)
+                and isinstance(node.func, ast.Attribute)
+                and node.func.attr == kernel
+                and isinstance(node.func.value, ast.Name)
+                and node.func.value.id == "codec_pallas"
+            ):
+                callers.append(f"{path.relative_to(REPO)}:{node.lineno}")
+    assert len(callers) == 1 and callers[0].startswith("shared_tensor_tpu/ops/table.py:"), callers

@@ -2,14 +2,19 @@
 capability, exercised against the single-scale golden codec and the
 mixed-magnitude failure mode it fixes (BASELINE.md)."""
 
-import numpy as np
+import re
 
+import jax
 import jax.numpy as jnp
+import numpy as np
+import pytest
 
 from shared_tensor_tpu.ops import codec
 from shared_tensor_tpu.ops.packing import padded_len, unpack_bits
 from shared_tensor_tpu.ops.table import (
+    TableFrame,
     accumulate_table,
+    apply_table_batch,
     apply_table,
     apply_table_many,
     flatten,
@@ -233,3 +238,36 @@ def test_receive_frames_batch_floods_other_links():
         np.asarray(a._links[2]), np.asarray(b._links[2]), atol=1e-6
     )
     assert b.frames_in == len(frames)
+
+
+def _largest_constant(lowered_text):
+    """Element count of the largest ``stablehlo.constant`` in a lowered text."""
+    largest = 0
+    for line in lowered_text.splitlines():
+        if "stablehlo.constant" not in line:
+            continue
+        dims = re.findall(r"(\d+)x", line.rsplit("tensor<", 1)[1])
+        largest = max(largest, int(np.prod([int(d) for d in dims])) if dims else 1)
+    return largest
+
+
+@pytest.mark.parametrize("program", ["quantize_table", "apply_table_batch", "accumulate_table"])
+def test_xla_tier_programs_hold_no_table_sized_constant(program):
+    """The live mask comes from ``live_rowcount`` (one int a row), compared
+    with a lane index on the device: no program bakes in a ``bool[total]``
+    (420 MB at an OLMoE layer's size)."""
+    spec = make_spec({"w": np.zeros((300, 77), np.float32), "b": np.zeros((77,), np.float32)})
+    flat = jax.ShapeDtypeStruct((spec.total,), jnp.float32)
+    frames = TableFrame(
+        jax.ShapeDtypeStruct((3, spec.num_leaves), jnp.float32),
+        jax.ShapeDtypeStruct((3, spec.total // 32), jnp.uint32),
+    )
+    lowered = {
+        "quantize_table": lambda: jax.jit(
+            lambda r: quantize_table(r, spec, impl="xla")).lower(flat),
+        "apply_table_batch": lambda: jax.jit(
+            lambda a, f: apply_table_batch((a,), f, spec, impl="xla")).lower(flat, frames),
+        "accumulate_table": lambda: jax.jit(
+            lambda a, u: accumulate_table((a,), u, spec)).lower(flat, flat),
+    }[program]()
+    assert _largest_constant(lowered.as_text()) <= spec.total // 128
