@@ -207,6 +207,10 @@ SCHEMA: dict[str, tuple[str, str]] = {
     # causal attention (models/mla_moe.py): which path a traced call took,
     # decided at trace time (backend, dtype, length), so counted per trace
     "st_attn_traces_total": ("counter", "traced calls of causal attention (per-path label: pallas = the fused kernels of ops/attention_pallas.py | scan = the portable tile loop)"),
+    # the codec kernels (ops/codec_pallas.py), counted when a program that
+    # calls them is traced
+    "st_codec_kernel_traces_total": ("counter", "traced calls of a codec kernel (per-kernel label: quantize_rows | apply_rows_batch)"),
+    "st_codec_leaves_per_block_max": ("gauge", "most leaves one grid block of the newest traced codec kernel meets (the worst trip count of its loop over leaves)"),
     # per-link series (rendered via link_key)
     "st_link_bytes_out_total": ("counter", "wire bytes sent on the link (incl. framing/keepalives)"),
     "st_link_bytes_in_total": ("counter", "wire bytes received on the link"),

@@ -21,6 +21,7 @@ With a single-leaf table this is byte-for-byte the reference codec.
 from __future__ import annotations
 
 import dataclasses
+import functools
 import itertools
 from functools import partial
 from typing import Any, Iterator, NamedTuple
@@ -100,16 +101,16 @@ class TableSpec:
 
     def live_rowcount(self) -> np.ndarray:
         """int32[rows]: number of live lanes in each row (0..128)."""
-        counts = []
-        for n, p in zip(self.ns, self.padded):
-            rows = p // LANES
-            full, rem = divmod(n, LANES)
-            c = np.zeros(rows, dtype=np.int32)
-            c[:full] = LANES
-            if rem:
-                c[full] = rem
-            counts.append(c)
-        return np.concatenate(counts)
+        return _live_rowcount(self.leaf_rows, self.ns)
+
+
+def _live_rowcount(ranges: LeafRanges, ns: tuple[int, ...]) -> np.ndarray:
+    """int32[rows]: live lanes of each row; row r of a leaf that starts at
+    row ``a`` and holds ``n`` elements has ``clip(n - 128 (r - a), 0, 128)``."""
+    each = [b - a for a, b in ranges]
+    start = np.repeat([a for a, _ in ranges], each)
+    n = np.repeat(np.asarray(ns, np.int64), each)
+    return np.clip(n - LANES * (np.arange(len(n)) - start), 0, LANES).astype(np.int32)
 
 
 def clip_ranges(ranges: LeafRanges, lo: int, hi: int) -> LeafRanges:
@@ -169,6 +170,114 @@ def leaf_expand(v: jnp.ndarray, ranges: LeafRanges) -> jnp.ndarray:
     return jnp.concatenate(parts, axis=-1) if len(parts) > 1 else parts[0]
 
 
+@dataclasses.dataclass(frozen=True)
+class LeafRows:
+    """Static: where a table's leaves lie in the rows of its flat buffer, as
+    each of ``n_windows`` equal row windows sees them (a shard of the pod
+    tier holds one window; a whole table is one). Whatever a codec pass
+    needs per row of what is known per leaf comes from here: the XLA passes
+    take :meth:`reduce`, :meth:`expand` and :meth:`rowcount`, the kernels
+    :meth:`tables`. ``window`` is the index of the window at hand, a traced
+    scalar (``axis_index``) with several windows and ``None`` with one; no
+    per-row index vector exists."""
+
+    ranges: LeafRanges  # every leaf's (start, stop) rows in the whole buffer
+    ns: tuple[int, ...]  # every leaf's live element count
+    rows: int  # rows of one window
+    n_windows: int = 1
+
+    @classmethod
+    def of(cls, spec: TableSpec, n_windows: int = 1) -> "LeafRows":
+        rows, rem = divmod(spec.total // LANES, n_windows)
+        if rem or rows * LANES >= 2**31:
+            raise ValueError(
+                f"{spec.total // LANES} rows do not cut into {n_windows} "
+                f"windows of whole rows with a 32-bit element index"
+            )
+        return cls(spec.leaf_rows, spec.ns, rows, n_windows)
+
+    def whole(self) -> "LeafRows":
+        """The same rows as ONE leaf, for the scale reductions of a single
+        global scale (:meth:`reduce`, :meth:`expand`, ``ns``). Not for live
+        lanes: the leaves' padding lies inside it."""
+        return LeafRows(
+            ((0, self.rows * self.n_windows),), (sum(self.ns),), self.rows, self.n_windows
+        )
+
+    @functools.cached_property
+    def window_ranges(self) -> tuple[LeafRanges, ...]:
+        """Per window, the leaves' ranges clipped to it (window-local)."""
+        return tuple(
+            clip_ranges(self.ranges, w * self.rows, (w + 1) * self.rows)
+            for w in range(self.n_windows)
+        )
+
+    def _pick(self, table: np.ndarray, window) -> jnp.ndarray:
+        """``table[window]`` of a static per-window table."""
+        if window is None:
+            return jnp.asarray(table[0])
+        return jax.lax.dynamic_index_in_dim(jnp.asarray(table), window, keepdims=False)
+
+    def _on_window(self, fn, x, window):
+        """``fn(x, ranges)`` with the window's leaf ranges: static per
+        window, so with several it is one ``lax.switch`` over a branch a
+        window (XLA passes only; the kernels take :meth:`tables`)."""
+        if window is None:
+            return fn(x, self.window_ranges[0])
+        return jax.lax.switch(
+            window, [partial(fn, ranges=r) for r in self.window_ranges], x
+        )
+
+    def reduce(self, x: jnp.ndarray, op: str, window=None) -> jnp.ndarray:
+        """The window's per-row partials ``[rows]`` -> ``[k]`` ("max" or
+        "sum"); leaves outside the window read the identity."""
+        return self._on_window(partial(leaf_reduce, op=op), x, window)
+
+    def expand(self, v: jnp.ndarray, window=None) -> jnp.ndarray:
+        """Per-leaf ``[..., k]`` -> the window's rows ``[..., rows]``."""
+        return self._on_window(leaf_expand, v, window)
+
+    def rowcount(self, window=None) -> jnp.ndarray:
+        """i32[rows]: live lanes (0..128) of each row of the window."""
+        return self._pick(self._rowcounts, window)
+
+    @functools.cached_property
+    def _rowcounts(self) -> np.ndarray:
+        return _live_rowcount(self.ranges, self.ns).reshape(self.n_windows, self.rows)
+
+    def tables(self, block: int, window=None):
+        """The window's :class:`~.codec_pallas.LeafTables` for kernels that
+        step through it ``block`` rows at a time: each leaf's first element
+        and live end, flat within the window and clipped to it, and per block
+        the first and the last leaf it meets; k + k + 2 x blocks scalars,
+        picked from static ``[n_windows, ...]`` tables by ``window``."""
+        from .codec_pallas import LeafTables
+
+        starts, stops = (np.asarray(x, np.int64) for x in zip(*self.ranges))
+        edges = np.arange(0, self.rows, block)
+        lo, end, first, last = [], [], [], []
+        for w in range(self.n_windows):
+            a, b = starts - w * self.rows, stops - w * self.rows
+            ca, cb = a.clip(0, self.rows), b.clip(0, self.rows)
+            lo.append(ca * LANES)
+            end.append((a * LANES + self.ns).clip(0, self.rows * LANES))
+            first.append(np.searchsorted(cb, edges, side="right"))
+            last.append(
+                np.searchsorted(ca, np.minimum(edges + block, self.rows), side="left") - 1
+            )
+        leaves_max = int((np.asarray(last) - np.asarray(first)).max()) + 1
+        return LeafTables(
+            block,
+            leaves_max,
+            *(self._pick(np.asarray(t, np.int32), window) for t in (lo, end, first, last)),
+        )
+
+    def one_a_leaf(self, scales: jnp.ndarray) -> jnp.ndarray:
+        """``scales`` [..., k] as they are, or a single global scale [..., 1]
+        (taken over :meth:`whole`) repeated to one a leaf."""
+        return jnp.broadcast_to(scales, (*scales.shape[:-1], len(self.ns)))
+
+
 def make_spec(tree: Any) -> TableSpec:
     """Build the static layout for a pytree of arrays."""
     leaves, treedef = jax.tree.flatten(tree)
@@ -226,18 +335,23 @@ def resolve_impl(impl: str) -> str:
 #
 # One 1-bit codec pass at row granularity, written once: per-leaf scales, the
 # sender's quantize pass and the receiver's K-frame apply pass, each with its
-# Pallas call and its XLA twin in one body. The table functions below and the
-# pod step (parallel/ici.py) compute their scales and their per-leaf ->
-# per-row expansion, then call these; nothing else knows which operands the
-# kernels of ops/codec_pallas.py take. ``impl`` is a resolved tier ("pallas"
-# or "xla", :func:`resolve_impl`); ``rowcount`` i32[rows] is the number of
-# live lanes of each row (``TableSpec.live_rowcount``, or a shard's slice).
+# Pallas call and its XLA twin in one body. Callers (the table functions
+# below, the pod step of parallel/ici.py) hand over what is per leaf as it
+# is, ``scales`` f32[k] or f32[K, k], with the static :class:`LeafRows` and
+# the window at hand. What is per row is built here and nowhere else: the
+# XLA twins expand scales and live lanes inside their own bodies
+# (``LeafRows.expand`` / ``rowcount``), and the kernels of
+# ops/codec_pallas.py get ``LeafRows.tables`` in scalar memory and derive a
+# block's per-row scale and live lanes themselves, so no per-row operand is
+# built, stored or streamed for them. ``impl`` is a resolved tier ("pallas"
+# or "xla", :func:`resolve_impl`).
 
 
 def live_lanes(rowcount: jnp.ndarray) -> jnp.ndarray:
     """bool[rows, 128]: True for live (non-padding) lanes."""
-    lane = jax.lax.broadcasted_iota(jnp.int32, (rowcount.shape[0], LANES), 1)
-    return lane < rowcount[:, None]
+    rows = rowcount.shape[0]
+    lane = jax.lax.broadcasted_iota(jnp.int32, (rows, LANES), 1)
+    return lane < jax.lax.broadcast_in_dim(rowcount, (rows, LANES), (0,))
 
 
 def leaf_scales(
@@ -278,20 +392,25 @@ def leaf_scales(
 
 
 def quantize_rows(
-    s_row: jnp.ndarray, rowcount: jnp.ndarray, residual: jnp.ndarray, impl: str
+    scales: jnp.ndarray, leaves: LeafRows, window, residual: jnp.ndarray, impl: str
 ) -> tuple[jnp.ndarray, jnp.ndarray]:
     """Sender pass: sign-quantize + LSB-first pack + error feedback with a
-    scale per row. ``s_row`` f32[rows] (the leaf's scale over its rows),
-    ``residual`` f32[rows*128] flat -> (words u32[rows*4], residual'). Bit
-    set iff r <= 0; a row at scale 0 idles; padding lanes are forced to 0."""
+    scale per leaf. ``scales`` f32[k] (or the one global scale, f32[1]),
+    ``residual`` f32[rows*128] flat (the
+    rows of ``window``) -> (words u32[rows*4], residual'). Bit set iff
+    r <= 0; a leaf at scale 0 idles; padding lanes are forced to 0."""
     with jax.named_scope("st.quantize"):
+        scales = leaves.one_a_leaf(scales)
         if impl == "pallas":
             from . import codec_pallas
 
-            return codec_pallas.quantize_rows(s_row, rowcount, residual)
+            block = codec_pallas.quantize_block_rows(leaves.rows)
+            return codec_pallas.quantize_rows(
+                scales, leaves.tables(block, window), residual
+            )
         r = residual.reshape(-1, LANES)
-        live = live_lanes(rowcount)
-        s = s_row[:, None]  # (rows, 1)
+        live = live_lanes(leaves.rowcount(window))
+        s = leaves.expand(scales, window)[:, None]  # (rows, 1)
         neg = r <= 0.0
         sent = jnp.where(neg, -s, s)
         r2 = jnp.where(live & (s > 0), r - sent, jnp.where(live, r, 0.0))
@@ -299,18 +418,22 @@ def quantize_rows(
 
 
 def apply_rows(
-    s_rows: jnp.ndarray,
-    rowcount: jnp.ndarray,
+    scales: jnp.ndarray,
+    leaves: LeafRows,
+    window,
     words: jnp.ndarray,
     arrays: tuple[jnp.ndarray, ...],
     impl: str,
 ) -> tuple[jnp.ndarray, ...]:
     """Receiver pass: the summed +/-scale delta of K frames (codec deltas are
     pure adds, so they commute) added to every array in one pass, clamped to
-    +/-codec.SAT, padding lanes forced to 0. ``s_rows`` f32[K, rows] (a
-    frame's row is 0 where it contributes nothing), ``words`` u32[K, rows*4],
-    ``arrays`` flat f32[rows*128] each. Owns the kernel's operand layout."""
-    k, rows = s_rows.shape
+    +/-codec.SAT, padding lanes forced to 0. ``scales`` f32[K, k] or, one
+    global scale a frame, f32[K, 1] (a frame's entry is 0 where it
+    contributes nothing), ``words`` u32[K, rows*4],
+    ``arrays`` flat f32[rows*128] each (the rows of ``window``). Owns the
+    kernel's operand layout."""
+    k, rows = scales.shape[0], leaves.rows
+    scales = leaves.one_a_leaf(scales)
     if impl == "pallas":
         from . import codec_pallas
 
@@ -322,11 +445,15 @@ def apply_rows(
                 .reshape(rows, k * (LANES // 32))
             )
         with jax.named_scope("st.apply"):
-            return codec_pallas.apply_rows_batch(s_rows.T, rowcount, words2d, arrays)
+            block = codec_pallas.apply_block_rows(rows, k, len(arrays))
+            return codec_pallas.apply_rows_batch(
+                scales, leaves.tables(block, window), words2d, arrays
+            )
     with jax.named_scope("st.words_layout"):
         bits = unpack_bits(words).reshape(k, rows, LANES).astype(jnp.float32)
     with jax.named_scope("st.apply"):
-        live = live_lanes(rowcount)
+        live = live_lanes(leaves.rowcount(window))
+        s_rows = leaves.expand(scales, window)  # (K, rows)
         # elementwise + sum on the VPU: under the RMS policy a scale is
         # arbitrary, so the arithmetic stays exact f32, no MXU
         delta = jnp.sum(s_rows[:, :, None] * (1.0 - 2.0 * bits), axis=0)
@@ -342,31 +469,22 @@ def apply_rows(
 
 
 def _table_scales(
-    residual: jnp.ndarray,
-    rowcount: jnp.ndarray,
-    spec: TableSpec,
-    policy: ScalePolicy,
-    per_leaf: bool,
+    residual: jnp.ndarray, leaves: LeafRows, policy: ScalePolicy, per_leaf: bool
 ) -> jnp.ndarray:
     """Per-leaf scales; ``per_leaf=False`` computes ONE scale over the whole
     table (the reference's behavior, src/sharedtensor.c:153-159 — wire-compat
     interop with C peers requires it) replicated to every leaf so the apply
     path is uniform."""
-    if per_leaf:
-        ranges, ns = spec.leaf_rows, spec.ns
-    else:
-        ranges, ns = ((0, spec.total // LANES),), (spec.total_n,)
+    over = leaves if per_leaf else leaves.whole()
     scales = leaf_scales(
         residual.reshape(-1, LANES),
-        live_lanes(rowcount),
-        lambda x, op: leaf_reduce(x, ranges, op),
-        lambda v: leaf_expand(v, ranges),
-        jnp.asarray(np.asarray(ns, dtype=np.float32)),
+        live_lanes(leaves.rowcount()),
+        over.reduce,
+        over.expand,
+        jnp.asarray(np.asarray(over.ns, dtype=np.float32)),
         policy,
     )
-    if per_leaf:
-        return scales
-    return jnp.full((spec.num_leaves,), scales[0], jnp.float32)
+    return leaves.one_a_leaf(scales)
 
 
 @partial(jax.jit, static_argnames=("spec", "policy", "per_leaf", "impl"))
@@ -377,11 +495,9 @@ def _quantize_table(
     per_leaf: bool,
     impl: str,
 ) -> tuple[TableFrame, jnp.ndarray]:
-    rowcount = jnp.asarray(spec.live_rowcount())
-    scales = _table_scales(residual, rowcount, spec, policy, per_leaf)
-    words, new_flat = quantize_rows(
-        leaf_expand(scales, spec.leaf_rows), rowcount, residual, impl
-    )
+    leaves = LeafRows.of(spec)
+    scales = _table_scales(residual, leaves, policy, per_leaf)
+    words, new_flat = quantize_rows(scales, leaves, None, residual, impl)
     return TableFrame(scales, words), new_flat
 
 
@@ -447,8 +563,9 @@ def _apply_table_batch(
 ) -> tuple[jnp.ndarray, ...]:
     # one frame (scales [L], words [W]) is the K = 1 stack
     return apply_rows(
-        leaf_expand(jnp.atleast_2d(frames.scales), spec.leaf_rows),  # [K, rows]
-        jnp.asarray(spec.live_rowcount()),
+        jnp.atleast_2d(frames.scales),
+        LeafRows.of(spec),
+        None,
         jnp.atleast_2d(frames.words),
         arrays,
         impl,

@@ -55,13 +55,10 @@ from ..config import MeshConfig, ScalePolicy
 from ..ops.codec import SAT
 from ..ops.packing import BITS_PER_WORD, LANES
 from ..ops.table import (
-    LeafRanges,
+    LeafRows,
     TableSpec,
     apply_rows,
-    clip_ranges,
     flatten,
-    leaf_expand,
-    leaf_reduce,
     leaf_scales,
     live_lanes,
     quantize_rows,
@@ -173,77 +170,47 @@ def apply_external(state: PeerSyncState, delta: jax.Array) -> PeerSyncState:
 
 @dataclasses.dataclass(frozen=True)
 class _StepCtx:
-    """Static layout shared by the sync-step builders: mesh axes, per-shard
-    row geometry, and each shard's leaf row ranges (``TableSpec.leaf_rows``
-    clipped to the shard), which the scale reductions and the per-leaf ->
-    per-row expansions run over. No per-row index vector exists."""
+    """Static layout shared by the sync-step builders: mesh axes and the
+    table's leaves as each shard's rows see them (``ops.table.LeafRows``, a
+    window a shard), which the scale reductions, the XLA passes and the
+    kernels' scalar tables all come from. No per-row index vector exists."""
 
     peer_ax: str
     shard_ax: str
     n_peer: int
-    n_shard: int
-    rows_local: int
-    shard_ranges: Tuple[LeafRanges, ...]  # [n_shard][k] local (start, stop)
-    rowcount_full: jnp.ndarray
-    ns: jnp.ndarray
+    leaves: LeafRows
+    over: LeafRows  # what a scale is taken over: the leaves, or the table whole
 
-    def _on_shard(self, fn, x):
-        """``fn(x, ranges)`` with this shard's leaf ranges. They are static
-        per shard index, so with several shards it is one ``lax.switch`` on
-        ``axis_index`` over a branch per shard. Call inside shard_map only."""
-        if self.n_shard == 1:
-            return fn(x, self.shard_ranges[0])
-        return jax.lax.switch(
-            jax.lax.axis_index(self.shard_ax),
-            [partial(fn, ranges=r) for r in self.shard_ranges],
-            x,
-        )
+    @property
+    def rows_local(self) -> int:
+        return self.leaves.rows
 
-    def leaf_reduce(self, x: jnp.ndarray, op: str) -> jnp.ndarray:
-        """This shard's per-row partials ``[rows_local]`` -> ``[k]`` ("max" or
-        "sum"); leaves outside the shard read the identity."""
-        return self._on_shard(partial(leaf_reduce, op=op), x)
-
-    def leaf_expand(self, v: jnp.ndarray) -> jnp.ndarray:
-        """Per-leaf ``[..., k]`` -> this shard's rows ``[..., rows_local]``."""
-        return self._on_shard(leaf_expand, v)
-
-    def local_slices(self):
-        """This shard's (rowcount, live) views. Call inside shard_map only
+    def window(self):
+        """This shard's window of ``leaves``. Call inside shard_map only
         (uses axis_index)."""
-        sid = jax.lax.axis_index(self.shard_ax)
-        rowcount = jax.lax.dynamic_slice_in_dim(
-            self.rowcount_full, sid * self.rows_local, self.rows_local
-        )
-        return rowcount, live_lanes(rowcount)
+        if self.leaves.n_windows == 1:
+            return None
+        return jax.lax.axis_index(self.shard_ax)
+
+    def live(self) -> jnp.ndarray:
+        """bool[rows_local, 128]: this shard's live lanes, for the XLA
+        passes. Call inside shard_map only."""
+        return live_lanes(self.leaves.rowcount(self.window()))
 
 
 def _make_ctx(
     mesh: Mesh, spec: TableSpec, per_leaf: bool, cfg: MeshConfig
 ) -> _StepCtx:
-    peer_ax, shard_ax = cfg.peer_axis, cfg.shard_axis
-    n_shard = mesh.shape[shard_ax]
-    rows_local = rows_per_shard(spec.total, n_shard)
-    if per_leaf:
-        ranges = spec.leaf_rows
-        ns = jnp.asarray(np.asarray(spec.ns, dtype=np.float32))
-    else:
-        # one global scale over the whole table (the reference's exact
-        # behavior, src/sharedtensor.c:153-159) — a single range
-        ranges = ((0, spec.total // LANES),)
-        ns = jnp.asarray([float(spec.total_n)], jnp.float32)
+    rows_per_shard(spec.total, mesh.shape[cfg.shard_axis])  # validate divisibility
+    leaves = LeafRows.of(spec, mesh.shape[cfg.shard_axis])
     return _StepCtx(
-        peer_ax=peer_ax,
-        shard_ax=shard_ax,
-        n_peer=mesh.shape[peer_ax],
-        n_shard=n_shard,
-        rows_local=rows_local,
-        shard_ranges=tuple(
-            clip_ranges(ranges, j * rows_local, (j + 1) * rows_local)
-            for j in range(n_shard)
-        ),
-        rowcount_full=jnp.asarray(spec.live_rowcount()),
-        ns=ns,
+        peer_ax=cfg.peer_axis,
+        shard_ax=cfg.shard_axis,
+        n_peer=mesh.shape[cfg.peer_axis],
+        leaves=leaves,
+        # per_leaf=False: one global scale over the whole table (the
+        # reference's exact behavior, src/sharedtensor.c:153-159)
+        over=leaves if per_leaf else leaves.whole(),
     )
 
 
@@ -253,28 +220,33 @@ def _leaf_scales(
     """Per-leaf scales from this shard's rows (ops.table.leaf_scales over
     this shard's leaf ranges), reduced over the shard axis: this is where the
     sharded replica pays one small collective — k floats — per frame."""
+    window = ctx.window()
     return leaf_scales(
-        rows, live, ctx.leaf_reduce, ctx.leaf_expand, ctx.ns, policy, ctx.shard_ax
+        rows,
+        live,
+        partial(ctx.over.reduce, window=window),
+        partial(ctx.over.expand, window=window),
+        jnp.asarray(np.asarray(ctx.over.ns, dtype=np.float32)),
+        policy,
+        ctx.shard_ax,
     )
 
 
 def _codec_send(ctx: _StepCtx, policy: ScalePolicy, impl: str, residual):
     """Sender half of the pod sync, per shard block: per-leaf scales
     (cross-shard reduction) + sign-quantize/pack/error-feedback
-    (ops.table.quantize_rows, on the resolved tier ``impl``) + all-gather of
-    the packed frames over the peer axis — the wire is 1 bit/element + k
-    scales per peer over ICI. One source of truth for both the fused step
-    (build_sync_step) and the overlap phases (build_sync_phases).
+    (ops.table.quantize_rows, on the resolved tier ``impl``, handed the
+    scales per leaf as they are) + all-gather of the packed frames over the
+    peer axis — the wire is 1 bit/element + k scales per peer over ICI. One
+    source of truth for both the fused step (build_sync_step) and the
+    overlap phases (build_sync_phases).
 
     Returns (new_residual [flat], words_all [n_peer, W_local],
     scales_all [n_peer, k], scales_local [k])."""
     with jax.named_scope("st.codec_send"):
         r = residual.reshape(ctx.rows_local, LANES)
-        rowcount, live = ctx.local_slices()
-        scales = _leaf_scales(ctx, r, live, policy)
-        with jax.named_scope("st.row_scales"):
-            s_row = ctx.leaf_expand(scales)
-        words, r2 = quantize_rows(s_row, rowcount, residual, impl)
+        scales = _leaf_scales(ctx, r, ctx.live(), policy)
+        words, r2 = quantize_rows(scales, ctx.leaves, ctx.window(), residual, impl)
         with jax.named_scope("st.allgather"):
             words_all = jax.lax.all_gather(words, ctx.peer_ax)  # (n_peer, W_local)
             scales_all = jax.lax.all_gather(scales, ctx.peer_ax)  # (n_peer, k)
@@ -288,14 +260,11 @@ def _codec_apply(ctx: _StepCtx, impl: str, values, words_all, scales_all):
     one pass (ops.table.apply_rows, on the resolved tier ``impl``). Shared by
     build_sync_step and build_sync_phases."""
     with jax.named_scope("st.codec_apply"):
-        rowcount, _ = ctx.local_slices()
-        with jax.named_scope("st.row_scales"):
-            me = jax.lax.axis_index(ctx.peer_ax)
-            s_all = jnp.where(
-                (jnp.arange(ctx.n_peer) == me)[:, None], 0.0, scales_all
-            )
-            s_all = ctx.leaf_expand(s_all)  # (n_peer, rows_local)
-        (v2,) = apply_rows(s_all, rowcount, words_all, (values,), impl)
+        me = jax.lax.axis_index(ctx.peer_ax)
+        s_all = jnp.where((jnp.arange(ctx.n_peer) == me)[:, None], 0.0, scales_all)
+        (v2,) = apply_rows(
+            s_all, ctx.leaves, ctx.window(), words_all, (values,), impl
+        )
         return v2
 
 
@@ -337,7 +306,7 @@ def build_sync_step(
 
     def _exact(values, residual):
         r = residual.reshape(ctx.rows_local, LANES)
-        _, live = ctx.local_slices()
+        live = ctx.live()
         # report the would-have-been scales so both arms expose the same
         # observability surface (the shard-axis reduction inside also lets
         # shard_map infer the scales output is shard-replicated)

@@ -66,9 +66,9 @@ def build_sign2_sync_step(
 
     def _body(values, residual):
         r = residual.reshape(ctx.rows_local, LANES)
-        _, live = ctx.local_slices()
+        live = ctx.live()
         scales = _leaf_scales(ctx, r, live, policy)
-        s_row = ctx.leaf_expand(scales)[:, None]  # (rows, 1)
+        s_row = ctx.over.expand(scales, ctx.window())[:, None]  # (rows, 1)
         # 2-bit sign-magnitude quantize + error feedback (the codec-lab
         # Sign2 rule; sign convention matches the production codec: r <= 0
         # sends negative, quirk Q3's zero-negative kept)
@@ -89,7 +89,7 @@ def build_sign2_sync_step(
         # receiver half: sum of every OTHER peer's 2-bit frame, one pass
         me = jax.lax.axis_index(peer_ax)
         s_all = jnp.where((jnp.arange(ctx.n_peer) == me)[:, None], 0.0, scales_all)
-        s_all = ctx.leaf_expand(s_all)  # (n_peer, rows_local)
+        s_all = ctx.over.expand(s_all, ctx.window())  # (n_peer, rows_local)
         neg_all = (
             unpack_bits(words_all[:, 0])
             .reshape(ctx.n_peer, ctx.rows_local, LANES)

@@ -112,6 +112,18 @@ class PodTier:
             lambda: {attn_keys[path]: n for path, n in self._attn_traces.items()}
         )
 
+        self._codec_traces = {"quantize_rows": 0, "apply_rows_batch": 0}
+        codec_keys = {
+            kernel: label_key("st_codec_kernel_traces_total", "kernel", kernel)
+            for kernel in self._codec_traces
+        }
+        self.registry.register_collector(
+            lambda: {codec_keys[k]: n for k, n in self._codec_traces.items()}
+        )
+        self._leaves_per_block = instrument(
+            self.registry.gauge, "st_codec_leaves_per_block_max"
+        )
+
     def watch(self, trainer) -> None:
         """The trainer whose ``aux`` the expert layers' gauges read (the
         newest made; held weakly)."""
@@ -146,6 +158,15 @@ class PodTier:
         steps."""
         with self._mu:
             self._attn_traces[path] += 1
+
+    def count_codec_kernel_trace(self, kernel: str, leaves_per_block: int) -> None:
+        """One traced call of a codec kernel of ``ops/codec_pallas.py``
+        (``quantize_rows`` or ``apply_rows_batch``) and the most leaves one
+        of its grid blocks meets: the worst trip count of the kernel's loop
+        over leaves. Traces, not steps."""
+        with self._mu:
+            self._codec_traces[kernel] += 1
+        self._leaves_per_block.set(leaves_per_block)
 
     def _on_duration(self, event: str, seconds: float, **_kw) -> None:
         if event == COMPILE_EVENT:

@@ -295,7 +295,8 @@ def test_pod_counters_are_in_the_schema_and_the_exposition():
     names = (
         "st_pod_steps_total", "st_pod_compiles_total", "st_pod_compile_seconds_total",
         "st_pod_cache_load_seconds_total", "st_pod_last_compile_step",
-        "st_attn_traces_total",
+        "st_attn_traces_total", "st_codec_kernel_traces_total",
+        "st_codec_leaves_per_block_max",
     )
     text = pod_registry().prometheus_text()
     for name in names:
@@ -305,4 +306,42 @@ def test_pod_counters_are_in_the_schema_and_the_exposition():
     # a trace-time counter: both paths are there from the start, at 0 or more
     assert 'st_attn_traces_total{path="pallas"}' in text
     assert 'st_attn_traces_total{path="scan"}' in text
+    assert 'st_codec_kernel_traces_total{kernel="quantize_rows"}' in text
+    assert 'st_codec_kernel_traces_total{kernel="apply_rows_batch"}' in text
     assert pod_registry() is pod_registry()
+
+
+def test_traced_sync_step_counts_its_codec_kernels():
+    """Tracing ``build_sync_step`` on the kernel tier counts one call of each
+    codec kernel and leaves the most leaves one of their grid blocks meets in
+    the gauge; the XLA tier traces no kernel."""
+    import jax
+
+    from shared_tensor_tpu.obs.schema import label_key
+    from shared_tensor_tpu.ops.table import make_spec
+    from shared_tensor_tpu.parallel import build_sync_step, init_state, make_mesh
+
+    def counts():
+        snap, _ = _pod_counts()
+        return (
+            [snap[label_key("st_codec_kernel_traces_total", "kernel", k)]
+             for k in ("quantize_rows", "apply_rows_batch")],
+            snap["st_codec_leaves_per_block_max"],
+        )
+
+    mesh = make_mesh(2, 1)
+    # five leaves of 8 rows and one of 1100: the first block meets all six
+    tree = {f"l{i}": jnp.zeros(n) for i, n in enumerate([3, 1000, 70, 1024, 5, 1100 * 128])}
+    spec = make_spec(tree)
+    state = init_state(mesh, spec)
+    before, _ = counts()
+    build_sync_step(mesh, spec, impl="xla").lower(state)
+    assert counts()[0] == before
+    build_sync_step(mesh, spec, impl="pallas").lower(state)
+    after, leaves = counts()
+    assert [a - b for a, b in zip(after, before)] == [1, 1]
+    assert leaves == 6
+    build_sync_step(mesh, make_spec({"w": jnp.zeros(4096)}), impl="pallas").lower(
+        jax.tree.map(lambda x: x[:, :4096], state)
+    )
+    assert counts()[1] == 1  # the newest traced table's

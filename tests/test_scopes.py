@@ -13,7 +13,7 @@ import numpy as np
 import pytest
 
 from shared_tensor_tpu.ops import codec_pallas
-from shared_tensor_tpu.ops.table import make_spec
+from shared_tensor_tpu.ops.table import LeafRows, make_spec
 from shared_tensor_tpu.parallel import build_sync_step, init_state, make_mesh
 from shared_tensor_tpu.parallel.ici import add_updates
 from shared_tensor_tpu.train import PodTrainer
@@ -21,15 +21,14 @@ from shared_tensor_tpu.utils import profiling
 
 #: The innermost scopes that partition a sync step (ISSUE 27's table).
 SYNC_SCOPES = {
-    "st.leaf_scales", "st.row_scales", "st.quantize", "st.allgather",
+    "st.leaf_scales", "st.quantize", "st.allgather",
     "st.words_layout", "st.apply",
 }
 #: Every path a sync step's operations may sit under.
 SYNC_PATHS = {
-    "st.codec_send", "st.codec_send/st.leaf_scales", "st.codec_send/st.row_scales",
+    "st.codec_send", "st.codec_send/st.leaf_scales",
     "st.codec_send/st.quantize", "st.codec_send/st.allgather",
-    "st.codec_apply", "st.codec_apply/st.row_scales",
-    "st.codec_apply/st.words_layout", "st.codec_apply/st.apply",
+    "st.codec_apply", "st.codec_apply/st.words_layout", "st.codec_apply/st.apply",
 }
 TRAIN_SCOPES = {"st.grads", "st.unflatten", "st.flatten", "st.update", "st.add_updates"}
 
@@ -118,16 +117,18 @@ def _pallas_names(fn, *args):
 
 _ROWS = 16
 _FLAT = jnp.ones((_ROWS * 128,), jnp.float32)
-_COUNT = jnp.full((_ROWS,), 128, jnp.int32)
+_TABLES = LeafRows.of(make_spec({"a": jnp.ones(1000), "b": jnp.ones(1024)})).tables(_ROWS)
 
 
 @pytest.mark.parametrize(
     "name, fn, args",
     [
-        ("st_quantize_rows", codec_pallas.quantize_rows,
-         (jnp.ones((_ROWS,)), _COUNT, _FLAT)),
-        ("st_apply_rows_batch", codec_pallas.apply_rows_batch,
-         (jnp.ones((_ROWS, 2)), _COUNT, jnp.zeros((_ROWS, 8), jnp.uint32), (_FLAT,))),
+        ("st_quantize_rows",
+         lambda s, r: codec_pallas.quantize_rows(s, _TABLES, r),
+         (jnp.ones((2,)), _FLAT)),
+        ("st_apply_rows_batch",
+         lambda s, w, a: codec_pallas.apply_rows_batch(s, _TABLES, w, (a,)),
+         (jnp.ones((2, 2)), jnp.zeros((_ROWS, 8), jnp.uint32), _FLAT)),
     ],
     ids=lambda v: v if isinstance(v, str) else "",
 )
@@ -171,8 +172,8 @@ def test_scope_times_of_a_sync_step(tmp_path):
     smap = profiling.scope_map(compiled)
     t = profiling.scope_times(str(tmp_path), smap, steps=3)
     assert t["devices"] == 4 and t["steps"] == 3
-    # words_layout and row_scales are a reshape and a gather XLA fuses into
-    # their consumers at this size: the program has them, a trace need not
+    # words_layout is a reshape XLA fuses into its consumer at this size: the
+    # program has it, a trace need not
     _check_table(
         t, {"st.leaf_scales", "st.quantize", "st.allgather", "st.apply"},
         set(smap.values()),

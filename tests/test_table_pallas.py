@@ -120,9 +120,151 @@ def test_pallas_roundtrip_convergence():
         )
 
 
-def test_ici_sync_step_pallas_parity():
+# --- the kernels derive per-row scale and live lanes from per-leaf scalars -----
+#
+# The row codec is handed scales per leaf and the static LeafRows; its Pallas
+# tier (interpreter here) is held bit for bit to a plain NumPy statement of
+# the rule at the same scales, and to its XLA twin.
+
+#: name -> leaf sizes (elements). A grid block is 1024 rows (quantize, apply at
+#: K = 1) or 512 (apply from K = 2 on); a leaf of n elements takes
+#: ceil(n / 1024) * 8 rows.
+_LEAF_TABLES = {
+    # 1536 rows in one leaf: two blocks, each inside it
+    "one_leaf_a_block": [1536 * 128 - 5],
+    # 704 + 904 rows: the first block meets both leaves, the second one only
+    "two_leaves_a_block": [704 * 128 - 77, 904 * 128],
+    # 64 leaves of 8 rows (ResNet's BatchNorm leaves), one of them of a single
+    # live element, then 600 rows: a 512-row block meets 64 leaves, a
+    # 1024-row block 65
+    "64_leaves_a_block": [1 + (37 * i) % 1024 for i in range(63)] + [1, 600 * 128 - 1],
+}
+
+
+def _leaf_table(name, seed):
+    sizes = _LEAF_TABLES[name]
+    spec = T.make_spec({f"l{i:03d}": np.zeros(n, np.float32) for i, n in enumerate(sizes)})
+    rng = np.random.default_rng(seed)
+    live = np.repeat(spec.live_rowcount(), 128) > np.tile(np.arange(128), spec.total // 128)
+    return spec, rng, live
+
+
+def _scales(spec, rng, k=None):
+    """Distinct per-leaf scales, not powers of two; leaf 1 (or the only leaf's
+    second frame) idles at scale 0."""
+    s = rng.uniform(0.1, 3.0, size=(k or 1, spec.num_leaves)).astype(np.float32)
+    s[-1, min(1, spec.num_leaves - 1)] = 0.0
+    return s if k else s[0]
+
+
+def _per_element(v, spec):
+    return np.repeat(np.asarray(v, np.float32), spec.padded)
+
+
+def _np_quantize(scales, spec, live, r):
+    s = _per_element(scales, spec)
+    neg = r <= 0
+    words = np.packbits(neg & live, bitorder="little").view("<u4")
+    sent = np.where(neg, -s, s)
+    return words, np.where(live & (s > 0), r - sent, np.where(live, r, 0)).astype(np.float32)
+
+
+def _np_apply(scales, spec, live, words, a):
+    delta = np.zeros(spec.total, np.float32)
+    for s, w in zip(scales, words):  # frame by frame, as the kernel sums them
+        bits = np.unpackbits(np.ascontiguousarray(w).view(np.uint8), bitorder="little")
+        delta = delta + _per_element(s, spec) * (np.float32(1) - np.float32(2) * bits)
+    return np.where(live, np.clip(a + np.where(live, delta, 0), -T.SAT, T.SAT), 0).astype(np.float32)
+
+
+@pytest.mark.parametrize("name", list(_LEAF_TABLES))
+def test_quantize_rows_from_leaf_scalars(name):
+    spec, rng, live = _leaf_table(name, 11)
+    leaves = T.LeafRows.of(spec)
+    scales = _scales(spec, rng)
+    # residual with garbage in the padding lanes: the pass forces them to 0
+    r = rng.normal(size=spec.total).astype(np.float32)
+    want_w, want_r = _np_quantize(scales, spec, live, r)
+    for impl in ("pallas", "xla"):
+        w, r2 = T.quantize_rows(jnp.asarray(scales), leaves, None, jnp.asarray(r), impl)
+        np.testing.assert_array_equal(np.asarray(w), want_w, err_msg=impl)
+        np.testing.assert_array_equal(np.asarray(r2), want_r, err_msg=impl)
+
+
+@pytest.mark.parametrize("k", [1, 4, 5])
+@pytest.mark.parametrize("name", list(_LEAF_TABLES))
+def test_apply_rows_from_leaf_scalars(name, k):
+    spec, rng, live = _leaf_table(name, 12)
+    leaves = T.LeafRows.of(spec)
+    scales = _scales(spec, rng, k)
+    words = rng.integers(0, 2**32, size=(k, spec.total // 32), dtype=np.uint32)
+    arrays = tuple(rng.normal(size=spec.total).astype(np.float32) for _ in range(2))
+    outs = {
+        impl: T.apply_rows(
+            jnp.asarray(scales), leaves, None, jnp.asarray(words),
+            tuple(jnp.asarray(a) for a in arrays), impl,
+        )
+        for impl in ("pallas", "xla")
+    }
+    for a, got_p, got_x in zip(arrays, outs["pallas"], outs["xla"]):
+        np.testing.assert_array_equal(np.asarray(got_p), _np_apply(scales, spec, live, words, a))
+        if k == 1:
+            np.testing.assert_array_equal(np.asarray(got_x), np.asarray(got_p))
+        else:  # the XLA twin sums the K frames in its own order
+            np.testing.assert_allclose(np.asarray(got_x), np.asarray(got_p), rtol=1e-6, atol=1e-6)
+
+
+@pytest.mark.parametrize("name", ["two_leaves_a_block", "64_leaves_a_block"])
+def test_row_codec_on_a_window_that_cuts_a_leaf(name):
+    """Two windows of the table's rows, as two shards hold them: the cut falls
+    inside a leaf, and each window's pass equals the whole table's on its
+    rows (tables picked from the static [n_windows, ...] ones by index)."""
+    spec, rng, live = _leaf_table(name, 13)
+    halves = T.LeafRows.of(spec, 2)
+    cut = halves.rows
+    assert any(a < cut < b for a, b in spec.leaf_rows)
+    scales = _scales(spec, rng, 4)
+    r = rng.normal(size=spec.total).astype(np.float32)
+    words = rng.integers(0, 2**32, size=(4, spec.total // 32), dtype=np.uint32)
+    v = rng.normal(size=spec.total).astype(np.float32)
+    want_w, want_r = _np_quantize(scales[0], spec, live, r)
+    want_v = _np_apply(scales, spec, live, words, v)
+    for w in (0, 1):
+        el = slice(w * cut * 128, (w + 1) * cut * 128)
+        wd = slice(w * cut * 4, (w + 1) * cut * 4)
+        for impl in ("pallas", "xla"):
+            got_w, got_r = T.quantize_rows(
+                jnp.asarray(scales[0]), halves, jnp.int32(w), jnp.asarray(r[el]), impl
+            )
+            np.testing.assert_array_equal(np.asarray(got_w), want_w[wd])
+            np.testing.assert_array_equal(np.asarray(got_r), want_r[el])
+        (got_v,) = T.apply_rows(
+            jnp.asarray(scales), halves, jnp.int32(w), jnp.asarray(words[:, wd]),
+            (jnp.asarray(v[el]),), "pallas",
+        )
+        np.testing.assert_array_equal(np.asarray(got_v), want_v[el])
+
+
+def test_leaf_tables_name_the_leaves_each_block_meets():
+    spec, _, _ = _leaf_table("64_leaves_a_block", 0)
+    whole = T.LeafRows.of(spec).tables(512)
+    assert whole.leaves_max == 64
+    np.testing.assert_array_equal(whole.first, [0, 64, 64])
+    np.testing.assert_array_equal(whole.last, [63, 64, 64])
+    assert int(whole.end[63]) == 63 * 1024 + 1  # the leaf of one live element
+    halves = T.LeafRows.of(spec, 2)  # 556 rows a window
+    second = halves.tables(512, jnp.int32(1))
+    np.testing.assert_array_equal(second.first, [64, 64])
+    assert int(second.lo[64]) == 0 and int(second.end[64]) == 556 * 128 - 1
+    assert int(second.end[0]) == 0  # a leaf before the window: nothing live
+
+
+@pytest.mark.parametrize("per_leaf", [True, False])
+def test_ici_sync_step_pallas_parity(per_leaf):
     """The fused pod sync step built on the Pallas tier matches the XLA tier
-    exactly (same state in, same state out) on a (4 peers x 2 shards) mesh."""
+    exactly (same state in, same state out) on a (4 peers x 2 shards) mesh,
+    whose shard boundary cuts the first leaf; per leaf and with the one
+    global scale."""
     from shared_tensor_tpu.ops.table import make_spec, flatten
     from shared_tensor_tpu.parallel.ici import build_sync_step, init_state
     from shared_tensor_tpu.parallel.mesh import make_mesh
@@ -142,7 +284,7 @@ def test_ici_sync_step_pallas_parity():
         from shared_tensor_tpu.parallel.ici import add_updates
 
         state = add_updates(state, upd)
-        step = build_sync_step(mesh, spec, impl=impl)
+        step = build_sync_step(mesh, spec, per_leaf=per_leaf, impl=impl)
         for _ in range(3):
             state, scales = step(state)
         return np.asarray(state.values), np.asarray(state.residual), np.asarray(scales)

@@ -15,8 +15,11 @@ compiled text. Each test jits a function object of its own, so no trace made
 here is ever served to another test.
 """
 
+import json
 import re
+import sys
 from functools import partial
+from pathlib import Path
 
 import jax
 import jax.numpy as jnp
@@ -34,6 +37,11 @@ from shared_tensor_tpu.parallel import (
     state_sharding,
 )
 from shared_tensor_tpu.train import build_train_step
+
+REPO = Path(__file__).resolve().parents[1]
+sys.path.insert(0, str(REPO))
+
+from chipbench.jobs.table_sync import leaf_layout  # noqa: E402
 
 
 @pytest.fixture(scope="module")
@@ -84,12 +92,42 @@ def test_flagship_train_step_compiles_for_v5e(
     assert compiled.as_text().count("tpu_custom_call") >= 2
 
 
-@pytest.mark.parametrize("k", [1, 8, 16])
-def test_apply_table_batch_compiles_for_v5e(v5e_devices, k):
+def _olmoe_spec(rehearsal: bool) -> table.TableSpec:
+    cfg = json.loads((REPO / "chipbench/configs/olmoe-layer-table.json").read_text())
+    return table.make_spec(
+        {k: jax.ShapeDtypeStruct(v, jnp.float32)
+         for k, v in leaf_layout(cfg, rehearsal).items()}
+    )
+
+
+@pytest.fixture(scope="module")
+def olmoe_spec():
+    """``olmoe-layer-table`` as the table cells run it: the 201 leaves of one
+    OLMoE decoder layer, 3 277 888 rows (shapes only)."""
+    spec = _olmoe_spec(rehearsal=False)
+    assert spec.num_leaves == 201
+    return spec
+
+
+def _per_row_arrays(text: str, rows: int) -> list[str]:
+    """Arrays of the compiled text that hold a number or a few for each of
+    ``rows`` table rows: ``f32[rows,1]``, ``s32[rows,1]``, ``f32[rows,K]``.
+    XLA pads such an array to 128 lanes (1.68 GB for 13 MB of numbers at
+    this size). The packed words ``u32[rows,4K]`` are no such copy of
+    per-leaf numbers and stay (ROADMAP S2)."""
+    return sorted(set(re.findall(r"\b[fs]32\[%d,\d{1,2}\]" % rows, text)))
+
+
+@pytest.mark.parametrize("k", [1, 8, 16, 64])
+def test_apply_table_batch_compiles_for_v5e(v5e_devices, olmoe_spec, k):
     """K = 16 is the device tier's default burst (comm/peer.py) and what
-    SharedTensor.receive_frames pads up to; at the seed it asked for more
-    VMEM than a kernel gets."""
-    spec = table.make_spec(np.zeros(1 << 20, np.float32))
+    SharedTensor.receive_frames pads up to (at the seed it asked for more
+    VMEM than a kernel gets); K = 64 is its largest: 64 x 201 scales in
+    scalar memory beside the leaf tables of 34 145 grid blocks of 96 rows.
+    At the table cells' real size but for K = 1, whose kernel at that size
+    the (1,1) sync step below compiles: alone, XLA spends 7 minutes lowering
+    the reshape of one frame's words to (rows, 4) (ROADMAP S2)."""
+    spec = olmoe_spec if k > 1 else _olmoe_spec(rehearsal=True)
     mesh = make_mesh(1, 1, devices=v5e_devices)
     arg = lambda shape, dtype: jax.ShapeDtypeStruct(
         shape, dtype, sharding=NamedSharding(mesh, P())
@@ -101,25 +139,27 @@ def test_apply_table_batch_compiles_for_v5e(v5e_devices, k):
     apply = jax.jit(
         partial(table._apply_table_batch.__wrapped__, spec=spec, impl="pallas")
     )
-    for n_arrays in (1, 3):  # the replica alone; with two links' residuals
+    # the replica alone; with two links' residuals (64 frames and three
+    # arrays of 1.68 GB do not fit the chip)
+    for n_arrays in (1, 3) if k < 64 else (1,):
         arrays = (arg((spec.total,), jnp.float32),) * n_arrays
         text = apply.lower(arrays, frames).compile().as_text()
         assert "tpu_custom_call" in text, (k, n_arrays)
+        assert not _per_row_arrays(text, spec.total // 128), (k, n_arrays)
 
 
 @pytest.mark.parametrize("n_peer,n_shard", [(1, 1), (4, 1), (2, 2)])
-def test_sync_step_compiles_for_v5e_with_no_index_operand(
-    v5e_devices, n_peer, n_shard
+def test_sync_step_compiles_for_v5e_with_no_per_row_operand(
+    v5e_devices, olmoe_spec, n_peer, n_shard
 ):
-    """The Pallas tier of tests/test_ici.py's structural test: around the two
-    kernels the compiled sync step of a many-leaf table holds no gather and no
-    scatter (the leaves' static row ranges do the row <-> leaf maps), on one
-    shard and, through the switch on the shard index, on two."""
-    sizes = [3000, 70] + [2 * 8 * 1024] * 9 + [5, 1100]
-    spec = table.make_spec(
-        {f"leaf{i:02d}": jax.ShapeDtypeStruct((n,), jnp.float32)
-         for i, n in enumerate(sizes)}
-    )
+    """The Pallas tier of tests/test_ici.py's structural test, at the table
+    cells' real leaves: around the two kernels the compiled sync step holds no
+    gather and no scatter (the leaves' static row ranges do the row <-> leaf
+    maps) and no per-row copy of a per-leaf number (the kernels read scales
+    and row ranges from scalar memory), on one shard and on two, where the
+    XLA row passes switch on the shard index and the kernels' tables are
+    picked by it."""
+    spec = olmoe_spec
     mesh = make_mesh(n_peer, n_shard, devices=v5e_devices)
     block = jax.ShapeDtypeStruct(
         (n_peer, spec.total), jnp.float32, sharding=state_sharding(mesh)
@@ -129,6 +169,7 @@ def test_sync_step_compiles_for_v5e_with_no_index_operand(
     ).compile().as_text()
     assert text.count("tpu_custom_call") >= 2
     assert not re.findall(r"= \S+ (?:gather|scatter)\(", text)
+    assert not _per_row_arrays(text, spec.total // 128 // n_shard)
     assert (" conditional(" in text) == (n_shard > 1)
 
 
