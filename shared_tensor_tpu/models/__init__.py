@@ -4,9 +4,12 @@ these models exist because its README names them as the intended workloads
 (char-rnn, reference README.md:37) and benchmark arms (ResNet async-DP).
 ``mla_moe`` is the first transformer: a DeepSeek-V3-family decoder whose
 table is of deployment size (its ``Config``, ``init_params``, ``forward`` and
-``loss_fn`` stay under ``mla_moe.``: char-rnn's are the package's)."""
+``loss_fn`` stay under ``mla_moe.``: char-rnn's are the package's);
+``swa_moe`` the second, a grouped-query decoder with window and full
+attention layers, an early router and ReGLU experts, which takes what the two
+share from ``mla_moe``."""
 
-from . import char_rnn, mla_moe, resnet
+from . import char_rnn, mla_moe, resnet, swa_moe
 from .char_rnn import (
     CharRNNConfig,
     encode_corpus,
@@ -18,12 +21,15 @@ from .char_rnn import (
 )
 from .mla_moe import Config as MlaMoeConfig
 from .resnet import ResNetConfig
+from .swa_moe import Config as SwaMoeConfig
 
 __all__ = [
     "char_rnn",
     "resnet",
     "mla_moe",
+    "swa_moe",
     "MlaMoeConfig",
+    "SwaMoeConfig",
     "CharRNNConfig",
     "ResNetConfig",
     "init_params",

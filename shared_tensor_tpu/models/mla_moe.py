@@ -233,11 +233,13 @@ def rope_interleaved(x: jax.Array, cos: jax.Array, sin: jax.Array) -> jax.Array:
     return jnp.stack([a * c - b * s, a * s + b * c], axis=-1).reshape(shape)
 
 
-def _tile_pairs(n_tiles: int):
-    """The tiles ``(i, j <= i)`` of the causal triangle, row by row: query
-    tile ``i`` meets key tiles ``0..i`` one after the other."""
-    i, j = np.tril_indices(n_tiles)
-    return jnp.asarray(i, jnp.int32), jnp.asarray(j, jnp.int32)
+def _tile_pairs(n: int, block: int, window: int | None):
+    """The tiles ``(i, j <= i)`` of the causal triangle over ``n`` positions,
+    row by row: query tile ``i`` meets key tiles ``0..i`` one after the
+    other; with a ``window`` the band's tiles, each row from its diagonal
+    back (the kernels' lists, ``attention_pallas.tile_list``)."""
+    i, j = np.asarray(attention_pallas.tile_list(n, block, block, False, window), np.int32).T
+    return jnp.asarray(i), jnp.asarray(j)
 
 
 def _rows(x, i, block: int):
@@ -249,22 +251,34 @@ def _put_rows(x, rows, i, block: int):
     return lax.dynamic_update_slice_in_dim(x, rows, i * block, axis=1)
 
 
-def _tile_scores(qi, kj, i, j, block: int, scale: float):
+def _tile_scores(qi, kj, i, j, block: int, scale: float, window: int | None):
     """Scaled scores ``[H, block, block]`` of query tile ``i`` against key
-    tile ``j``, float32, keys after their query at ``-inf`` (only the
-    diagonal tile has any)."""
+    tile ``j``, float32, keys after their query (only the diagonal tile has
+    any) and keys ``window`` or more before it at ``-inf``."""
     s = jnp.einsum("hqd,hkd->hqk", qi, kj, preferred_element_type=jnp.float32,
                    precision=_precision(qi.dtype)) * scale
     q_pos = i * block + lax.broadcasted_iota(jnp.int32, s.shape[1:], 0)
     k_pos = j * block + lax.broadcasted_iota(jnp.int32, s.shape[1:], 1)
-    return jnp.where(k_pos <= q_pos, s, -jnp.inf)
+    seen = k_pos <= q_pos
+    if window is not None:
+        seen &= q_pos - k_pos < window
+    return jnp.where(seen, s, -jnp.inf)
 
 
-def _attention_fwd_tiles(q, k, v, block: int):
-    """``(o [H, T, dv], lse [H, T])`` of ``q, k [H, T, dq]``, ``v [H, T, dv]``:
-    one loop over the causal triangle's tiles with a running row maximum,
-    denominator and numerator (the online softmax), so the program holds one
-    tile's scores and one loop body whatever the length."""
+def _of_groups(q, k, v):
+    """K/V heads repeated to one a query head (query head ``h`` reads K/V
+    head ``h // group``), for the scan, which batches over query heads."""
+    group = q.shape[0] // k.shape[0]
+    return (k, v) if group == 1 else (jnp.repeat(k, group, axis=0), jnp.repeat(v, group, axis=0))
+
+
+def _attention_fwd_tiles(q, k, v, block: int, window: int | None = None):
+    """``(o [H, T, dv], lse [H, T])`` of ``q [H, T, dq]``, ``k [H_kv, T,
+    dq]``, ``v [H_kv, T, dv]``: one loop over the causal triangle's tiles
+    (the band's, with a ``window``) with a running row maximum, denominator
+    and numerator (the online softmax), so the program holds one tile's
+    scores and one loop body whatever the length."""
+    k, v = _of_groups(q, k, v)
     h, n, _ = q.shape
     scale = 1.0 / math.sqrt(q.shape[-1])
     prec = _precision(v.dtype)
@@ -272,7 +286,7 @@ def _attention_fwd_tiles(q, k, v, block: int):
     def tile(carry, ij):
         top, den, num = carry
         i, j = ij
-        s = _tile_scores(_rows(q, i, block), _rows(k, j, block), i, j, block, scale)
+        s = _tile_scores(_rows(q, i, block), _rows(k, j, block), i, j, block, scale, window)
         top_i, den_i, num_i = (_rows(a, i, block) for a in carry)
         # the maximum as an operation of its own: fused into the exp pass XLA
         # makes it a reduce-window over every element (47 ms a pass where the
@@ -290,13 +304,16 @@ def _attention_fwd_tiles(q, k, v, block: int):
 
     start = (jnp.full((h, n), -jnp.inf, jnp.float32), jnp.zeros((h, n), jnp.float32),
              jnp.zeros((h, n, v.shape[-1]), jnp.float32))
-    (top, den, num), _ = lax.scan(tile, start, _tile_pairs(n // block))
+    (top, den, num), _ = lax.scan(tile, start, _tile_pairs(n, block, window))
     return (num / den[..., None]).astype(v.dtype), top + jnp.log(den)
 
 
-def _attention_bwd_tiles(q, k, v, o, lse, g, block: int):
+def _attention_bwd_tiles(q, k, v, o, lse, g, block: int, window: int | None = None):
     """Cotangents of ``(q, k, v)`` for the cotangent ``g`` of ``o``: the same
-    loop, every tile's probabilities made again from ``lse``."""
+    loop, every tile's probabilities made again from ``lse``; a group's
+    ``dk``, ``dv`` added in float32."""
+    h_kv = k.shape[0]
+    k, v = _of_groups(q, k, v)
     n = q.shape[1]
     scale = 1.0 / math.sqrt(q.shape[-1])
     prec = _precision(v.dtype)
@@ -308,7 +325,7 @@ def _attention_bwd_tiles(q, k, v, o, lse, g, block: int):
         dq, dk, dv = carry
         i, j = ij
         qi, kj, vj, gi = _rows(q, i, block), _rows(k, j, block), _rows(v, j, block), _rows(g, i, block)
-        s = _tile_scores(qi, kj, i, j, block, scale)
+        s = _tile_scores(qi, kj, i, j, block, scale, window)
         p = jnp.exp(s - _rows(lse, i, block)[..., None])
         dp = mm("hqd,hkd->hqk", gi, vj)
         ds = (p * (dp - _rows(drop, i, block)[..., None]) * scale).astype(q.dtype)
@@ -318,46 +335,54 @@ def _attention_bwd_tiles(q, k, v, o, lse, g, block: int):
         return (dq, dk, dv), None
 
     start = tuple(jnp.zeros(a.shape, jnp.float32) for a in (q, k, v))
-    (dq, dk, dv), _ = lax.scan(tile, start, _tile_pairs(n // block))
+    (dq, dk, dv), _ = lax.scan(tile, start, _tile_pairs(n, block, window))
+    if h_kv != q.shape[0]:
+        dk, dv = (jnp.sum(a.reshape(h_kv, -1, *a.shape[1:]), axis=1) for a in (dk, dv))
     return dq.astype(q.dtype), dk.astype(k.dtype), dv.astype(v.dtype)
 
 
-def _attention_o_lse(q, k, v, block: int | None):
+def _attention_o_lse(q, k, v, block: int | None, window: int | None):
     """``(o, lse)`` by the scan in tiles of ``block``, or by the fused
     kernels (``ops/attention_pallas.py``, which size their own tiles) where
     ``block`` is None."""
     if block is None:
-        return attention_pallas.attention_fwd(q, k, v)
-    return _attention_fwd_tiles(q, k, v, block)
+        return attention_pallas.attention_fwd(q, k, v, window=window)
+    return _attention_fwd_tiles(q, k, v, block, window)
 
 
-@partial(jax.custom_vjp, nondiff_argnums=(3,))
-def _attention_tiles(q, k, v, block: int | None):
-    return _attention_o_lse(q, k, v, block)[0]
+@partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5))
+def _attention_tiles(q, k, v, block: int | None, window: int | None, scope: str):
+    return _attention_o_lse(q, k, v, block, window)[0]
 
 
-def _attention_tiles_fwd(q, k, v, block):
+def _attention_tiles_fwd(q, k, v, block, window, scope):
     # what a layer's checkpoint keeps (64 MB + 1 MB a layer at 8 192 tokens),
     # so that recomputing the layer does not run the attention a third time
-    o, lse = (checkpoint_name(a, ATTN_OUT) for a in _attention_o_lse(q, k, v, block))
+    o, lse = (checkpoint_name(a, ATTN_OUT) for a in _attention_o_lse(q, k, v, block, window))
     return o, (q, k, v, o, lse)
 
 
-def _attention_tiles_bwd(block, res, g):
-    with jax.named_scope("st.mla.attn"):
+def _attention_tiles_bwd(block, window, scope, res, g):
+    with jax.named_scope(scope):  # the caller's: a backward rule is traced outside it
         if block is None:
-            return attention_pallas.attention_bwd(*res, g)
-        return _attention_bwd_tiles(*res, g, block)
+            return attention_pallas.attention_bwd(*res, g, window=window)
+        return _attention_bwd_tiles(*res, g, block, window)
 
 
 _attention_tiles.defvjp(_attention_tiles_fwd, _attention_tiles_bwd)
 
 
-def causal_attention(q, k, v, block: int):
-    """softmax(q k^T / sqrt(dq)) v with a causal mask, ``[T, H, .]`` operands,
-    over the causal triangle's tiles only, each tile's scores made again in
-    the backward pass, so no ``[H, T, T]`` tensor exists. Two paths, one
-    precision (bfloat16 operands, float32 accumulation and softmax):
+def causal_attention(q, k, v, block: int, window: int | None = None,
+                     scope: str = "st.mla.attn"):
+    """softmax(q k^T / sqrt(dq)) v with a causal mask, ``q [T, H, .]`` and
+    ``k, v [T, H_kv, .]`` (query head ``h`` reads K/V head ``h // (H //
+    H_kv)``), over the causal triangle's tiles only or, with a ``window``
+    shorter than the sequence, over the tiles of the band of each query's
+    ``window`` newest keys, its own among them; each tile's scores are made
+    again in the backward pass, so no ``[H, T, T]`` tensor exists. ``scope``
+    is the ``jax.named_scope`` the caller wraps this call in, opened again
+    around the backward pass. Two paths, one precision (bfloat16 operands,
+    float32 accumulation and softmax):
 
     - the fused kernels of ``ops/attention_pallas.py`` where they run
       (:func:`attention_pallas.takes`: a tpu backend or ``ST_CODEC=pallas``,
@@ -368,14 +393,20 @@ def causal_attention(q, k, v, block: int):
       query block (16 key lengths at 8 192 tokens cost 100 s of compilation;
       chip-free compile, PR 29).
 
-    Which one was traced is counted (``st_attn_traces_total{path}``)."""
+    Which one was traced is counted (``st_attn_traces_total{path}`` and
+    ``{kind}``), and the tiles its forward pass lists
+    (``st_attn_tiles_listed{kind}``)."""
     n = q.shape[0]
+    window = window if window is not None and window < n else None
     q, k, v = (jnp.swapaxes(a, 0, 1) for a in (q, k, v))  # heads first
     block = None if attention_pallas.takes(q, k, v) else min(block, n)
     if block and n % block:
         raise ValueError(f"{n} positions do not divide into tiles of {block}")
-    pod_tier().count_attention_trace("scan" if block else "pallas")
-    return jnp.swapaxes(_attention_tiles(q, k, v, block), 0, 1)
+    bq, bk = (block, block) if block else attention_pallas._fwd_tiles(n)
+    pod_tier().count_attention_trace(
+        "scan" if block else "pallas", "full" if window is None else "window",
+        len(attention_pallas.tile_list(n, bq, bk, False, window)))
+    return jnp.swapaxes(_attention_tiles(q, k, v, block, window, scope), 0, 1)
 
 
 def mla(p: dict, x: jax.Array, rope, cfg: Config) -> jax.Array:
@@ -460,16 +491,22 @@ def _expert_operand(x: jax.Array, dt) -> jax.Array:
     return x.astype(dt)
 
 
-def _tile_ffn(x, wg, wu, wd, w_row, dt):
-    """One expert's weighted SwiGLU of one tile's rows: ``x [tile, hidden]``,
-    ``wg``/``wu [F, hidden]``, ``wd [hidden, F]``, ``w_row [tile]``."""
+#: the gate's activation of a gated expert, by the name a configuration gives
+#: it: SwiGLU's and ReGLU's
+ACTIVATIONS = {"silu": jax.nn.silu, "relu": jax.nn.relu}
+
+
+def _tile_ffn(x, wg, wu, wd, w_row, dt, act):
+    """One expert's weighted gated unit (``down(act(gate x) * up x)``) of one
+    tile's rows: ``x [tile, hidden]``, ``wg``/``wu [F, hidden]``, ``wd
+    [hidden, F]``, ``w_row [tile]``."""
     a = _mm(_expert_operand(x, dt), _expert_operand(wg, dt), dt)
     b = _mm(_expert_operand(x, dt), _expert_operand(wu, dt), dt)
-    y = _mm(_expert_operand(jax.nn.silu(a) * b, dt), _expert_operand(wd, dt), dt)
+    y = _mm(_expert_operand(act(a) * b, dt), _expert_operand(wd, dt), dt)
     return y * w_row[:, None]
 
 
-def _tiles_to_run(counts, n_tokens: int, cfg: Config):
+def _tiles_to_run(counts, n_tokens: int, cfg):
     """``(run, most)``: the tiles of ``expert_tile`` rows the expert layer's
     loop runs for these ``counts [held]`` (every expert's pairs padded to
     whole tiles; no fewer than ``expert_spare`` x the expected pairs would
@@ -482,7 +519,7 @@ def _tiles_to_run(counts, n_tokens: int, cfg: Config):
     return jnp.maximum(jnp.sum((counts + tile - 1) // tile), least), most
 
 
-def _tile_table(w_pair, order, counts, n_tokens: int, cfg: Config):
+def _tile_table(w_pair, order, counts, n_tokens: int, cfg):
     """Where every row of every tile comes from. The (token, slot) pairs
     ``order [T*k]`` lists sorted by held expert (the pairs of absent experts
     last; ``counts [held]`` how many each expert has) are laid out in tiles of
@@ -506,7 +543,7 @@ def _tile_table(w_pair, order, counts, n_tokens: int, cfg: Config):
                 weight=jnp.where(valid, w_pair[pair], 0.0), valid=valid), run
 
 
-def _routed_impl(cfg: Config, u, wg, wu, wd, w_pair, order, counts):
+def _routed_impl(cfg, act: str, u, wg, wu, wd, w_pair, order, counts):
     """The held experts' part of the layer for ``u [T, hidden]``:
     ``wg``/``wu [held, F, hidden]``, ``wd [held, hidden, F]``, ``w_pair
     [T*k]`` every pair's weight. A loop over the tiles the real counts need
@@ -522,14 +559,14 @@ def _routed_impl(cfg: Config, u, wg, wu, wd, w_pair, order, counts):
             x = u[row["token"]]
         with jax.named_scope("st.moe.experts"):
             e = row["expert"]
-            y = _tile_ffn(x, wg[e], wu[e], wd[e], row["weight"], cfg.dtype)
+            y = _tile_ffn(x, wg[e], wu[e], wd[e], row["weight"], cfg.dtype, ACTIVATIONS[act])
         with jax.named_scope("st.moe.combine"):
             return out.at[row["token"]].add(y)
 
     return lax.fori_loop(0, n_tiles, tile, jnp.zeros(u.shape, jnp.float32))
 
 
-def _routed_grad_impl(cfg: Config, u, wg, wu, wd, w_pair, order, counts, g):
+def _routed_grad_impl(cfg, act: str, u, wg, wu, wd, w_pair, order, counts, g):
     """Cotangents of (u, wg, wu, wd, w_pair): the same loop, each tile
     differentiating itself and adding into its expert's and its tokens'
     rows."""
@@ -544,7 +581,8 @@ def _routed_grad_impl(cfg: Config, u, wg, wu, wd, w_pair, order, counts, g):
         with jax.named_scope("st.moe.experts"):
             e = row["expert"]
             dx, dg, du_, dd, dw_row = jax.vjp(
-                partial(_tile_ffn, dt=cfg.dtype), x, wg[e], wu[e], wd[e], row["weight"])[1](gy)
+                partial(_tile_ffn, dt=cfg.dtype, act=ACTIVATIONS[act]),
+                x, wg[e], wu[e], wd[e], row["weight"])[1](gy)
             dwg, dwu, dwd = dwg.at[e].add(dg), dwu.at[e].add(du_), dwd.at[e].add(dd)
         with jax.named_scope("st.moe.combine"):
             du = du.at[row["token"]].add(dx)
@@ -556,35 +594,40 @@ def _routed_grad_impl(cfg: Config, u, wg, wu, wd, w_pair, order, counts, g):
     return lax.fori_loop(0, n_tiles, tile, start)
 
 
-@partial(jax.custom_vjp, nondiff_argnums=(0,))
-def routed_experts(cfg: Config, u, wg, wu, wd, w_pair, order, counts):
+@partial(jax.custom_vjp, nondiff_argnums=(0, 1))
+def routed_experts(cfg, act: str, u, wg, wu, wd, w_pair, order, counts):
     """``sum_{i chosen and held} w_i E_i(u)`` for every token, ``[T, hidden]``
-    float32. Dropless: every (token, chosen-and-held expert) pair is computed
-    whatever the imbalance."""
-    return _per_example(partial(_routed_impl, cfg))(u, wg, wu, wd, w_pair, order, counts)
+    float32, ``E`` gated by ``ACTIVATIONS[act]``. Dropless: every (token,
+    chosen-and-held expert) pair is computed whatever the imbalance. ``cfg``
+    is either decoder's ``Config``: what is read of it is ``expert_tile``,
+    ``expert_spare``, ``num_experts_per_tok``, ``n_routed_experts``,
+    ``dtype``."""
+    return _per_example(partial(_routed_impl, cfg, act))(u, wg, wu, wd, w_pair, order, counts)
 
 
-def _routed_fwd(cfg, *args):
-    return _per_example(partial(_routed_impl, cfg))(*args), args
+def _routed_fwd(cfg, act, *args):
+    return _per_example(partial(_routed_impl, cfg, act))(*args), args
 
 
-def _routed_bwd(cfg, args, g):
+def _routed_bwd(cfg, act, args, g):
     with jax.named_scope("st.moe"):
-        grads = _per_example(partial(_routed_grad_impl, cfg))(*args, g)
+        grads = _per_example(partial(_routed_grad_impl, cfg, act))(*args, g)
     return (*grads, None, None)
 
 
 routed_experts.defvjp(_routed_fwd, _routed_bwd)
 
 
-def moe(p: dict, u: jax.Array, cfg: Config):
-    """The expert layer of ``u [T, hidden]``: shared expert + the held
-    experts' weighted part; ``p`` holds the ``mlp.*`` leaves. Returns the
-    output and this layer's counters."""
+def held_experts(p: dict, u: jax.Array, idx, w, cfg, act: str,
+                 names=("gate_proj", "up_proj", "down_proj")):
+    """The held experts' part of an expert layer of ``u [T, hidden]`` for the
+    router's ``idx, w [T, k]`` (every token's chosen experts of all
+    ``n_routed_experts`` and their weights), and what
+    :func:`expert_counters` counts (which pairs are held, how many each
+    expert has). ``p`` holds ``experts.<e>.<name>.weight`` for the experts
+    ``cfg.experts_held`` = (first, count) and the three ``names`` (gate, up,
+    down); what absent experts would add is left out."""
     first, held = cfg.experts_held
-    k, dt = cfg.num_experts_per_tok, cfg.dtype
-    with jax.named_scope("st.moe.router"):
-        idx, w = route(p, u, cfg)
     with jax.named_scope("st.moe.dispatch"):
         local = idx - first
         here = (local >= 0) & (local < held)
@@ -592,22 +635,40 @@ def moe(p: dict, u: jax.Array, cfg: Config):
         counts = jnp.sum(key[:, None] == jnp.arange(held)[None, :], axis=0,
                          dtype=jnp.int32)
         order = jnp.argsort(key).astype(jnp.int32)
-        stack = lambda name: jnp.stack([
-            p[f"experts.{e}.{name}_proj.weight"] for e in range(first, first + held)])
-    routed = routed_experts(cfg, u, stack("gate"), stack("up"), stack("down"),
-                            w.reshape(-1), order, counts)
-    with jax.named_scope("st.moe.shared"):
-        shared = swiglu({n: p["shared_experts." + n] for n in
-                         ("gate_proj.weight", "up_proj.weight", "down_proj.weight")}, u, dt)
+        wg, wu, wd = (jnp.stack([
+            p[f"experts.{e}.{name}.weight"] for e in range(first, first + held)])
+            for name in names)
+    routed = routed_experts(cfg, act, u, wg, wu, wd, w.reshape(-1), order, counts)
+    return routed, (idx, here, counts)
+
+
+def expert_counters(held, cfg) -> dict:
+    """One expert layer's counters, from :func:`held_experts`' second
+    result."""
+    idx, here, counts = held
     pairs = jnp.sum(counts)
-    mean = pairs.astype(jnp.float32) / held
-    aux = {
+    mean = pairs.astype(jnp.float32) / counts.shape[0]
+    return {
         "choices": idx,
         "moe_pairs_held": pairs,
         "moe_load_max_over_mean": jnp.max(counts) / jnp.maximum(mean, 1.0),
         "moe_tokens_unrouted_share": jnp.mean(~jnp.any(here, axis=1), dtype=jnp.float32),
-        "moe_rows_executed": cfg.expert_tile * _tiles_to_run(counts, u.shape[0], cfg)[0],
+        "moe_rows_executed": cfg.expert_tile * _tiles_to_run(counts, idx.shape[0], cfg)[0],
     }
+
+
+def moe(p: dict, u: jax.Array, cfg: Config):
+    """The expert layer of ``u [T, hidden]``: shared expert + the held
+    experts' weighted part; ``p`` holds the ``mlp.*`` leaves. Returns the
+    output and this layer's counters."""
+    with jax.named_scope("st.moe.router"):
+        idx, w = route(p, u, cfg)
+    routed, held = held_experts(p, u, idx, w, cfg, "silu")
+    with jax.named_scope("st.moe.shared"):
+        shared = swiglu({n: p["shared_experts." + n] for n in
+                         ("gate_proj.weight", "up_proj.weight", "down_proj.weight")},
+                        u, cfg.dtype)
+    aux = expert_counters(held, cfg)
     return shared + routed, aux
 
 
@@ -642,12 +703,12 @@ def _block(params: dict, i: int, x, rope, cfg: Config):
     return fn(_sub(params, _layer(i)), x, rope)
 
 
-def head_logits(x, norm_w, head_w, cfg: Config) -> jax.Array:
+def head_logits(x, norm_w, head_w, cfg) -> jax.Array:
     """float32 logits over the held vocabulary of ``x [.., hidden]``."""
     return _mm(rms_norm(x, norm_w, cfg.rms_norm_eps), head_w, cfg.dtype)
 
 
-def head_loss(x, norm_w, head_w, targets, weights, cfg: Config) -> jax.Array:
+def head_loss(x, norm_w, head_w, targets, weights, cfg) -> jax.Array:
     """Sum over tokens of ``weights x`` the float32 cross-entropy of
     ``targets``, the logits made (and made again in the backward pass) in
     blocks of ``loss_block`` tokens."""

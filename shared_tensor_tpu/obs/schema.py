@@ -206,7 +206,8 @@ SCHEMA: dict[str, tuple[str, str]] = {
     "st_moe_tokens_unrouted_share": ("gauge", "share of tokens that chose no held expert, mean over the expert layers of the newest step"),
     # causal attention (models/mla_moe.py): which path a traced call took,
     # decided at trace time (backend, dtype, length), so counted per trace
-    "st_attn_traces_total": ("counter", "traced calls of causal attention (per-path label: pallas = the fused kernels of ops/attention_pallas.py | scan = the portable tile loop)"),
+    "st_attn_traces_total": ("counter", "traced calls of causal attention, each counted under two single-label series (per-path label: pallas = the fused kernels of ops/attention_pallas.py | scan = the portable tile loop; per-kind label: full = the whole causal triangle | window = the band of a sliding window)"),
+    "st_attn_tiles_listed": ("gauge", "tiles the forward pass of the newest traced causal attention lists (per-kind label: full | window): the band against the triangle"),
     # the codec kernels (ops/codec_pallas.py), counted when a program that
     # calls them is traced
     "st_codec_kernel_traces_total": ("counter", "traced calls of a codec kernel (per-kernel label: quantize_rows | apply_rows_batch)"),

@@ -1,6 +1,9 @@
 """Causal softmax attention as fused Pallas (Mosaic) kernels, forward and
-backward, over ``q, k [H, T, d_qk]`` and ``v [H, T, d_v]`` (heads are a
-batch; ``d_qk`` need not equal ``d_v`` nor be a multiple of 128).
+backward, over ``q [H, T, d_qk]``, ``k [H_kv, T, d_qk]`` and ``v [H_kv, T,
+d_v]`` (heads are a batch; ``d_qk`` need not equal ``d_v`` nor be a multiple
+of 128), over the whole causal triangle or, with a ``window``, over the band
+of the ``window`` newest keys of every query (its own among them). Query head
+``h`` reads K/V head ``h // (H // H_kv)``: grouped queries.
 
 A tile's scores, probabilities and their cotangents live in VMEM between the
 products, so no ``[H, tile, tile]`` array goes through HBM and no carry is
@@ -13,7 +16,19 @@ tiles from its diagonal down, makes each tile's probabilities again from
 the whole head that stays in VMEM until the head is done. Key tiles after the
 diagonal are never visited (the grid is the list of the causal triangle's
 tiles, handed to the index maps as prefetched scalars) and only tiles that
-straddle the diagonal build a mask.
+straddle the diagonal build a mask. With a window the list holds the band's
+tiles only, a tile that straddles the window's far edge builds the mask too,
+and the forward kernel walks a query tile's key tiles from its diagonal
+*back*: the diagonal tile holds every query's own key, so the running maximum
+is finite from the first tile on, which the band's oldest tile (where a late
+query of the tile sees nothing) could not promise. With fewer K/V heads than
+query heads the K/V blocks are the query head's group's, and the backward
+kernel writes ``dk``, ``dv`` a query head in float32, which XLA adds over
+each group (a group's queries cannot share one ``dk`` in scratch: the kernel
+holds one head's whole ``dq`` in VMEM, 16.8 MB at 16 384 x 128, and seven do
+not fit).
+With no window and as many K/V heads the tile lists, the masks and the
+compiled bodies are what they were before either existed.
 
 Precision, the same as ``models/mla_moe.py``'s scan, which is the portable
 path and these kernels' oracle: bfloat16 operands into every product with
@@ -76,14 +91,16 @@ def _lanes(d: int) -> int:
     return -(-d // LANES) * LANES
 
 
-def _bwd_vmem_bytes(t: int, d_qk: int, d_v: int, bq: int, bk: int) -> int:
+def _bwd_vmem_bytes(t: int, d_qk: int, d_v: int, bq: int, bk: int,
+                    grouped: bool = False) -> int:
     """What the backward kernel holds in VMEM: the head's ``dq`` (a float32
     accumulator and the output block's two buffers), the tiles of its six
-    operands twice each, ``dk`` and ``dv`` with their outputs, and a tile's
-    float32 scores and their three companions with their bfloat16 casts."""
+    operands twice each, ``dk`` and ``dv`` with their outputs (float32 ones
+    where the queries are ``grouped``), and a tile's float32 scores and their
+    three companions with their bfloat16 casts."""
     dq = t * _lanes(d_qk) * (4 + 2 * 2)
     operands = 2 * 2 * ((bq + bk) * _lanes(d_qk) + (bq + bk) * _lanes(d_v)) + 4 * 2 * 8 * bq * 4
-    dkv = bk * (_lanes(d_qk) + _lanes(d_v)) * (4 + 2 * 2)
+    dkv = bk * (_lanes(d_qk) + _lanes(d_v)) * (4 + 2 * (4 if grouped else 2))
     tiles = bq * bk * (4 * 4 + 2 * 2)
     return dq + operands + dkv + tiles
 
@@ -91,25 +108,35 @@ def _bwd_vmem_bytes(t: int, d_qk: int, d_v: int, bq: int, bk: int) -> int:
 def takes(q, k, v) -> bool:
     """Do the kernels run for these operands? Where the codec's kernels do
     (``use_pallas``: a tpu backend, or ``ST_CODEC=pallas``), on bfloat16
-    ``[H, T, d]`` operands whose ``T`` is whole tiles and whose ``dq`` of one
-    head fits VMEM."""
-    if not codec_pallas.use_pallas() or q.ndim != 3:
+    ``[H, T, d]`` operands whose ``T`` is whole tiles, whose query heads are
+    whole groups of the K/V heads and whose ``dq`` of one head fits VMEM."""
+    if not codec_pallas.use_pallas() or q.ndim != 3 or q.shape[0] % k.shape[0]:
         return False
     if not all(a.dtype == jnp.bfloat16 for a in (q, k, v)):
         return False
     t, b = q.shape[1], _tile(q.shape[1])
-    return b > 0 and _bwd_vmem_bytes(t, q.shape[-1], v.shape[-1], b, b) <= VMEM_BUDGET
+    return b > 0 and _bwd_vmem_bytes(
+        t, q.shape[-1], v.shape[-1], b, b, q.shape[0] != k.shape[0]) <= VMEM_BUDGET
 
 
-def _tiles(t: int, bq: int, bk: int, by_key: bool):
-    """``(query tile, key tile)`` of every tile of the causal triangle over
-    ``t`` positions (one that holds a key at or before one of its queries), a
-    query tile's key tiles one after the other or, ``by_key``, a key tile's
-    query tiles."""
-    pairs = [(i, j) for i in range(t // bq) for j in range(t // bk) if j * bk < (i + 1) * bq]
+def tile_list(t: int, bq: int, bk: int, by_key: bool, window: int | None = None):
+    """``[(query tile, key tile)]`` of every tile of the causal triangle over
+    ``t`` positions (one that holds a key at or before one of its queries)
+    or, with a ``window``, of the band (a key at most ``window - 1`` before
+    one of its queries as well). A query tile's key tiles one after the
+    other, oldest first (newest first with a window), or, ``by_key``, a key
+    tile's query tiles."""
+    pairs = [(i, j) for i in range(t // bq) for j in range(t // bk)
+             if j * bk < (i + 1) * bq and (window is None or (j + 1) * bk > i * bq - window + 1)]
     if by_key:
         pairs.sort(key=lambda p: (p[1], p[0]))
-    i, j = np.asarray(pairs, np.int32).T
+    elif window is not None:
+        pairs.sort(key=lambda p: (p[0], -p[1]))
+    return pairs
+
+
+def _tiles(t: int, bq: int, bk: int, by_key: bool, window: int | None = None):
+    i, j = np.asarray(tile_list(t, bq, bk, by_key, window), np.int32).T
     return jnp.asarray(i), jnp.asarray(j)
 
 
@@ -120,17 +147,32 @@ def _params(need: int):
     )
 
 
-def _on_diagonal(first_q, first_k, bk: int):
-    """Has the tile a key after its first query (so a mask to build)?"""
-    return first_k + bk - 1 > first_q
+def _on_an_edge(first_q, first_k, bq: int, bk: int, window: int | None):
+    """Has the tile a mask to build: a key after its first query (it
+    straddles the diagonal) or, with a window, a key ``window`` or more
+    before its last query (it straddles the band's far edge)?"""
+    edge = first_k + bk - 1 > first_q
+    if window is not None:
+        edge = jnp.logical_or(edge, first_q + bq - first_k > window)
+    return edge
 
 
-def _masked(s, first_q, first_k, q_axis: int):
+def _masked(s, first_q, first_k, q_axis: int, window: int | None):
     """The tile's scores ``s`` (queries along ``q_axis``, keys along the
-    other) with keys after their query at ``-inf``."""
+    other) with keys after their query, and keys ``window`` or more before
+    it, at ``-inf``."""
     q_pos = first_q + lax.broadcasted_iota(jnp.int32, s.shape, q_axis)
     k_pos = first_k + lax.broadcasted_iota(jnp.int32, s.shape, 1 - q_axis)
-    return jnp.where(k_pos <= q_pos, s, -jnp.inf)
+    seen = k_pos <= q_pos
+    if window is not None:
+        seen = jnp.logical_and(seen, q_pos - k_pos < window)
+    return jnp.where(seen, s, -jnp.inf)
+
+
+def _kv_head(h_q: int, h_kv: int):
+    """A query head's K/V head, for the index maps."""
+    group = h_q // h_kv
+    return (lambda h: h) if group == 1 else (lambda h: h // group)
 
 
 def _either(flag, fn):
@@ -143,12 +185,17 @@ def _either(flag, fn):
 
 
 def _fwd_kernel(qi_ref, kj_ref, q_ref, k_ref, v_ref, o_ref, lse_ref,
-                top_ref, den_ref, num_ref, *, scale: float, bq: int, bk: int):
+                top_ref, den_ref, num_ref, *, scale: float, bq: int, bk: int,
+                window: int | None):
     t = pl.program_id(1)
     i, j = qi_ref[t], kj_ref[t]
     first_q, first_k = i * bq, j * bk
+    # the key tile that holds the queries' own keys: a query tile's last
+    # (oldest first, key tile 0 to the diagonal) or, with a window, its first
+    # (newest first, the diagonal back to the band's oldest tile)
+    newest = lambda: first_k + bk >= first_q + bq
 
-    @pl.when(j == 0)
+    @pl.when(j == 0 if window is None else newest())
     def _():
         top_ref[...] = jnp.full_like(top_ref, -jnp.inf)
         den_ref[...] = jnp.zeros_like(den_ref)
@@ -158,10 +205,10 @@ def _fwd_kernel(qi_ref, kj_ref, q_ref, k_ref, v_ref, o_ref, lse_ref,
         s = lax.dot_general(q_ref[...], k_ref[...], _NT,
                             preferred_element_type=jnp.float32) * scale
         if masked:
-            s = _masked(s, first_q, first_k, q_axis=0)
+            s = _masked(s, first_q, first_k, 0, window)
         top = top_ref[...]
-        # key tile 0 comes first and holds key 0, which every query sees: the
-        # maximum is finite from the first tile on
+        # the first tile holds a key every query sees (key 0, or with a
+        # window each query's own): the maximum is finite from it on
         new_top = jnp.maximum(top, jnp.max(s, axis=-1, keepdims=True))
         shrink = jnp.exp(top - new_top)
         e = jnp.exp(s - new_top)
@@ -170,9 +217,10 @@ def _fwd_kernel(qi_ref, kj_ref, q_ref, k_ref, v_ref, o_ref, lse_ref,
         num_ref[...] = num_ref[...] * shrink + jnp.dot(
             e.astype(v_ref.dtype), v_ref[...], preferred_element_type=jnp.float32)
 
-    _either(_on_diagonal(first_q, first_k, bk), tile)
+    _either(_on_an_edge(first_q, first_k, bq, bk, window), tile)
 
-    @pl.when(first_k + bk >= first_q + bq)  # the query tile's last key tile
+    @pl.when(newest() if window is None
+             else j == jnp.maximum(first_q - window + 1, 0) // bk)
     def _():
         den = den_ref[...]
         o_ref[...] = (num_ref[...] / den).astype(o_ref.dtype)
@@ -180,25 +228,28 @@ def _fwd_kernel(qi_ref, kj_ref, q_ref, k_ref, v_ref, o_ref, lse_ref,
         lse_ref[...] = jnp.transpose(jnp.broadcast_to(lse, (bq, LANES)))[:1]
 
 
-def attention_fwd(q, k, v, *, block_q: int | None = None, block_k: int | None = None):
+def attention_fwd(q, k, v, *, window: int | None = None,
+                  block_q: int | None = None, block_k: int | None = None):
     """``(o [H, T, d_v], lse [H, T])`` of softmax(q k^T / sqrt(d_qk)) v under
-    the causal mask; ``o`` in the operands' dtype, ``lse`` float32."""
+    the causal mask (and, with a ``window``, over each query's ``window``
+    newest keys); ``o`` in the operands' dtype, ``lse`` float32."""
     h, t, d = q.shape
     dv = v.shape[-1]
+    kv = _kv_head(h, k.shape[0])
     bq, bk = _fwd_tiles(t)
     bq, bk = block_q or bq, block_k or bk
-    qi, kj = _tiles(t, bq, bk, by_key=False)
+    qi, kj = _tiles(t, bq, bk, False, window)
     need = (2 * 2 * (bq * _lanes(d) + bk * _lanes(d) + bk * _lanes(dv) + bq * _lanes(dv))
             + bq * _lanes(dv) * 4 + bq * bk * (3 * 4 + 2))
     o, lse = pl.pallas_call(
-        partial(_fwd_kernel, scale=1.0 / math.sqrt(d), bq=bq, bk=bk),
+        partial(_fwd_kernel, scale=1.0 / math.sqrt(d), bq=bq, bk=bk, window=window),
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=2,
             grid=(h, qi.shape[0]),
             in_specs=[
                 pl.BlockSpec((None, bq, d), lambda h, t, qi, kj: (h, qi[t], 0)),
-                pl.BlockSpec((None, bk, d), lambda h, t, qi, kj: (h, kj[t], 0)),
-                pl.BlockSpec((None, bk, dv), lambda h, t, qi, kj: (h, kj[t], 0)),
+                pl.BlockSpec((None, bk, d), lambda h, t, qi, kj: (kv(h), kj[t], 0)),
+                pl.BlockSpec((None, bk, dv), lambda h, t, qi, kj: (kv(h), kj[t], 0)),
             ],
             out_specs=[
                 pl.BlockSpec((None, bq, dv), lambda h, t, qi, kj: (h, qi[t], 0)),
@@ -226,7 +277,7 @@ def attention_fwd(q, k, v, *, block_q: int | None = None, block_k: int | None = 
 
 def _bwd_kernel(qi_ref, kj_ref, q_ref, k_ref, v_ref, g_ref, lse_ref, drop_ref,
                 dq_ref, dk_ref, dv_ref, dq_acc, dk_acc, dv_acc,
-                *, scale: float, bq: int, bk: int, n_q: int):
+                *, scale: float, bq: int, bk: int, n_q: int, window: int | None):
     """One tile, keys down the sublanes and queries along the lanes (``lse``
     and ``drop`` are rows of the query tile, as they lie in memory)."""
     t = pl.program_id(1)
@@ -247,7 +298,7 @@ def _bwd_kernel(qi_ref, kj_ref, q_ref, k_ref, v_ref, g_ref, lse_ref, drop_ref,
         mm = partial(lax.dot_general, preferred_element_type=jnp.float32)
         s = mm(k, q, _NT) * scale  # [bk, bq]
         if masked:
-            s = _masked(s, first_q, first_k, q_axis=1)
+            s = _masked(s, first_q, first_k, 1, window)
         p = jnp.exp(s - lse_ref[...])
         dp = mm(v, g, _NT)
         ds = (p * (dp - drop_ref[...]) * scale).astype(q.dtype)
@@ -256,9 +307,13 @@ def _bwd_kernel(qi_ref, kj_ref, q_ref, k_ref, v_ref, g_ref, lse_ref, drop_ref,
         rows = pl.ds(pl.multiple_of(first_q, bq), bq)
         dq_acc[rows, :] += mm(ds, k, _TN)
 
-    _either(_on_diagonal(first_q, first_k, bk), tile)
+    _either(_on_an_edge(first_q, first_k, bq, bk, window), tile)
 
-    @pl.when(i == n_q - 1)
+    last_q = n_q - 1  # the key tile's last query tile: the sequence's, or the band's
+    if window is not None:
+        last_q = jnp.minimum(last_q, (first_k + bk + window - 2) // bq)
+
+    @pl.when(i == last_q)
     def _():
         dk_ref[...] = dk_acc[...].astype(dk_ref.dtype)
         dv_ref[...] = dv_acc[...].astype(dv_ref.dtype)
@@ -268,25 +323,29 @@ def _bwd_kernel(qi_ref, kj_ref, q_ref, k_ref, v_ref, g_ref, lse_ref, drop_ref,
         dq_ref[...] = dq_acc[...].astype(dq_ref.dtype)
 
 
-def attention_bwd(q, k, v, o, lse, g, *, block_q: int | None = None,
-                  block_k: int | None = None):
+def attention_bwd(q, k, v, o, lse, g, *, window: int | None = None,
+                  block_q: int | None = None, block_k: int | None = None):
     """Cotangents of ``(q, k, v)`` for the cotangent ``g`` of ``o``, in the
     operands' dtypes, every tile's probabilities made again from ``lse``."""
     h, t, d = q.shape
     dv = v.shape[-1]
+    h_kv = k.shape[0]
+    kv = _kv_head(h, h_kv)
     bq, bk = block_q or _tile(t), block_k or _tile(t)
-    qi, kj = _tiles(t, bq, bk, by_key=True)
+    qi, kj = _tiles(t, bq, bk, True, window)
     # sum_k p dp, a row: what the softmax's normalisation takes back
     drop = jnp.sum(g.astype(jnp.float32) * o.astype(jnp.float32), axis=-1)
     by_q = lambda width: pl.BlockSpec((None, bq, width), lambda h, t, qi, kj: (h, qi[t], 0))
-    by_k = lambda width: pl.BlockSpec((None, bk, width), lambda h, t, qi, kj: (h, kj[t], 0))
+    by_k = lambda width, of=lambda h: h: pl.BlockSpec(
+        (None, bk, width), lambda h, t, qi, kj: (of(h), kj[t], 0))
     row = pl.BlockSpec((None, 1, bq), lambda h, t, qi, kj: (h, 0, qi[t]))
     dq, dk, dv_ = pl.pallas_call(
-        partial(_bwd_kernel, scale=1.0 / math.sqrt(d), bq=bq, bk=bk, n_q=t // bq),
+        partial(_bwd_kernel, scale=1.0 / math.sqrt(d), bq=bq, bk=bk, n_q=t // bq,
+                window=window),
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=2,
             grid=(h, qi.shape[0]),
-            in_specs=[by_q(d), by_k(d), by_k(dv), by_q(dv), row, row],
+            in_specs=[by_q(d), by_k(d, kv), by_k(dv, kv), by_q(dv), row, row],
             out_specs=[
                 pl.BlockSpec((None, t, d), lambda h, t, qi, kj: (h, 0, 0)),
                 by_k(d), by_k(dv),
@@ -299,11 +358,17 @@ def attention_bwd(q, k, v, o, lse, g, *, block_q: int | None = None,
         ),
         out_shape=[
             jax.ShapeDtypeStruct(q.shape, q.dtype),
-            jax.ShapeDtypeStruct(k.shape, k.dtype),
-            jax.ShapeDtypeStruct(v.shape, v.dtype),
+            # a query head each; a group's are added below, in float32 as the
+            # scratch held them (470 MB a layer at 28 x 16 384 x 128, written
+            # and read once: 1.2 ms of HBM time beside a kernel of tens)
+            jax.ShapeDtypeStruct((h, t, d), k.dtype if h_kv == h else jnp.float32),
+            jax.ShapeDtypeStruct((h, t, dv), v.dtype if h_kv == h else jnp.float32),
         ],
-        compiler_params=_params(_bwd_vmem_bytes(t, d, dv, bq, bk)),
+        compiler_params=_params(_bwd_vmem_bytes(t, d, dv, bq, bk, h_kv != h)),
         interpret=codec_pallas._interpret(),
         name="st_attn_bwd",
     )(qi, kj, q, k, v, g, lse.reshape(h, 1, t), drop.reshape(h, 1, t))
+    if h_kv != h:
+        dk, dv_ = (jnp.sum(a.reshape(h_kv, h // h_kv, t, -1), axis=1).astype(like.dtype)
+                   for a, like in ((dk, k), (dv_, v)))
     return dq, dk, dv_

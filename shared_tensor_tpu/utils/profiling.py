@@ -103,14 +103,20 @@ class PodTier:
         jax.monitoring.register_event_duration_secs_listener(self._on_duration)
         self._trainer = lambda: None
         self.registry.register_collector(self._moe)
-        self._attn_traces = {"pallas": 0, "scan": 0}
-        attn_keys = {
-            path: label_key("st_attn_traces_total", "path", path)
-            for path in self._attn_traces
+        # one count a traced call under each of its two labels, and the
+        # newest traced call's tile count a kind
+        self._attn_traces = {
+            ("path", "pallas"): 0, ("path", "scan"): 0, ("kind", "full"): 0, ("kind", "window"): 0,
         }
-        self.registry.register_collector(
-            lambda: {attn_keys[path]: n for path, n in self._attn_traces.items()}
-        )
+        self._attn_tiles = {"full": 0, "window": 0}
+        attn_keys = {lv: label_key("st_attn_traces_total", *lv) for lv in self._attn_traces}
+        tiles_keys = {
+            kind: label_key("st_attn_tiles_listed", "kind", kind) for kind in self._attn_tiles
+        }
+        self.registry.register_collector(lambda: {
+            **{attn_keys[lv]: n for lv, n in self._attn_traces.items()},
+            **{tiles_keys[kind]: n for kind, n in self._attn_tiles.items()},
+        })
 
         self._codec_traces = {"quantize_rows": 0, "apply_rows_batch": 0}
         codec_keys = {
@@ -151,13 +157,17 @@ class PodTier:
         with self._mu:
             self._steps[synced] += 1
 
-    def count_attention_trace(self, path: str) -> None:
-        """One traced call of ``models/mla_moe.py``'s causal attention and
-        the path it took: ``pallas`` (the fused kernels) or ``scan``. The
-        choice is made while a program is traced, so this counts traces, not
-        steps."""
+    def count_attention_trace(self, path: str, kind: str, tiles: int) -> None:
+        """One traced call of ``models/mla_moe.py``'s causal attention: the
+        path it took, ``pallas`` (the fused kernels) or ``scan``; its kind,
+        ``full`` (the whole causal triangle) or ``window`` (a band of it);
+        and the tiles its forward pass lists (the band against the
+        triangle). The choice is made while a program is traced, so this
+        counts traces, not steps."""
         with self._mu:
-            self._attn_traces[path] += 1
+            self._attn_traces["path", path] += 1
+            self._attn_traces["kind", kind] += 1
+            self._attn_tiles[kind] = tiles
 
     def count_codec_kernel_trace(self, kernel: str, leaves_per_block: int) -> None:
         """One traced call of a codec kernel of ``ops/codec_pallas.py``

@@ -16,22 +16,32 @@ from shared_tensor_tpu.ops import attention_pallas as A
 from shared_tensor_tpu.utils.profiling import pod_registry
 
 
-def plain(q, k, v):
+def plain(q, k, v, window=None):
     """``(o, lse)`` of causal softmax attention, float32 at precision
-    ``highest``, the ``[H, T, T]`` scores whole."""
+    ``highest``, the ``[H, T, T]`` scores whole under a dense mask: key j for
+    query i iff ``j <= i`` and, with a ``window``, ``i - j < window``. Fewer
+    K/V heads than query heads are repeated over their groups."""
     q, k, v = (a.astype(jnp.float32) for a in (q, k, v))
+    group = q.shape[0] // k.shape[0]
+    k, v = jnp.repeat(k, group, axis=0), jnp.repeat(v, group, axis=0)
     s = jnp.einsum("hqd,hkd->hqk", q, k, precision="highest") / np.sqrt(q.shape[-1])
     t = q.shape[1]
-    s = jnp.where(jnp.tril(jnp.ones((t, t), bool)), s, -jnp.inf)
+    i = jnp.arange(t)
+    seen = i[None, :] <= i[:, None]
+    if window is not None:
+        seen &= i[:, None] - i[None, :] < window
+    s = jnp.where(seen, s, -jnp.inf)
     o = jnp.einsum("hqk,hkd->hqd", jax.nn.softmax(s, axis=-1), v, precision="highest")
     return o, jax.nn.logsumexp(s, axis=-1)
 
 
 @functools.lru_cache(maxsize=None)
-def operands(h, t, d_qk, d_v):
+def operands(h, t, d_qk, d_v, h_kv=None):
+    """``q, k, v, g``; ``k`` and ``v`` of ``h_kv`` heads (``h`` by default)."""
     keys = jax.random.split(jax.random.key(t + d_qk), 4)
-    return tuple(jax.random.normal(key, (h, t, d), jnp.float32).astype(jnp.bfloat16)
-                 for key, d in zip(keys, (d_qk, d_qk, d_v, d_v)))
+    return tuple(jax.random.normal(key, (heads, t, d), jnp.float32).astype(jnp.bfloat16)
+                 for key, heads, d in zip(keys, (h, h_kv or h, h_kv or h, h),
+                                          (d_qk, d_qk, d_v, d_v)))
 
 
 def worst(a, b):
@@ -92,6 +102,89 @@ def test_kernels_equal_the_scan_and_the_plain_softmax(case):
         assert worst(a, c) <= 1.5 * room, name
 
 
+BAND = {
+    # (H, H_kv, T, window, block_q, block_k): lengths of several windows
+    # the second model's layout in small: 7 query heads a K/V head, 3.1 windows
+    "grouped_7_window_of_no_whole_tile": (7, 1, 640, 200, 128, 128),
+    # ungrouped, keys twice as wide as queries, a window just over a tile
+    "ungrouped_wide_keys": (2, 2, 512, 130, 128, 256),
+    # the window is exactly one tile: every far-edge tile is half masked
+    "window_of_one_tile": (4, 2, 384, 128, 128, 128),
+    # the kernels' own tiles (512 x 1 024 forward) and a window under one
+    "own_tiles_short_window": (2, 1, 1024, 300, None, None),
+    # grouped over the whole triangle: no window, the K/V index maps alone
+    "grouped_no_window": (4, 2, 256, None, 128, 128),
+    # a window the sequence never fills: the triangle under the band's code
+    "window_longer_than_the_sequence": (2, 2, 256, 1000, 128, 128),
+}
+
+
+@pytest.mark.parametrize("case", sorted(BAND))
+def test_band_and_groups_equal_the_scan_and_the_dense_mask(case):
+    """The band and grouped queries, forward and backward, three ways: the
+    interpreted kernels, the scan (their oracle, whose tile of 128 the
+    windows here do not divide into) and a dense masked softmax in float32.
+    Tolerances as in the triangle's test: the scan's own error against
+    float32."""
+    h, h_kv, t, window, bq, bk = BAND[case]
+    q, k, v, g = operands(h, t, 128, 128, h_kv)
+    o_scan, lse_scan = M._attention_fwd_tiles(q, k, v, 128, window)
+    o_plain, lse_plain = plain(q, k, v, window)
+    o, lse = A.attention_fwd(q, k, v, window=window, block_q=bq, block_k=bk)
+    assert o.dtype == jnp.bfloat16 and o.shape == q.shape and lse.shape == q.shape[:2]
+    room = worst(o_scan, o_plain)
+    assert 0 < room < 0.02
+    assert worst(o, o_scan) <= room and worst(o, o_plain) <= 1.5 * room
+    assert worst(lse, lse_scan) <= 1e-5 and worst(lse_scan, lse_plain) <= 1e-5
+
+    got = A.attention_bwd(q, k, v, o_scan, lse_scan, g, window=window, block_q=bq, block_k=bk)
+    scan = M._attention_bwd_tiles(q, k, v, o_scan, lse_scan, g, 128, window)
+    exact = jax.grad(lambda *a: jnp.sum(plain(*a, window)[0] * g.astype(jnp.float32)),
+                     (0, 1, 2))(*(a.astype(jnp.float32) for a in (q, k, v)))
+    for name, a, b, c, like in zip(("dq", "dk", "dv"), got, scan, exact, (q, k, v)):
+        assert a.dtype == jnp.bfloat16 and a.shape == b.shape == c.shape == like.shape, name
+        room = worst(b, c)
+        assert 0 < room < 0.05 * float(jnp.max(jnp.abs(c))), name
+        assert worst(a, b) <= room, name
+        assert worst(a, c) <= 1.5 * room, name
+
+
+def test_a_window_one_key_short_is_told_apart():
+    """The far edge is exact: the band of ``window - 1`` keys differs from
+    the band of ``window`` in the kernels, the scan and the dense mask alike,
+    by far more than their rounding."""
+    h, h_kv, t, window, bq, bk = BAND["grouped_7_window_of_no_whole_tile"]
+    q, k, v, _ = operands(h, t, 128, 128, h_kv)
+    right = plain(q, k, v, window)[0]
+    for short in (A.attention_fwd(q, k, v, window=window - 1, block_q=bq, block_k=bk)[0],
+                  M._attention_fwd_tiles(q, k, v, 128, window - 1)[0],
+                  plain(q, k, v, window - 1)[0]):
+        assert worst(short, right) > 0.05
+    assert worst(A.attention_fwd(q, k, v, window=window, block_q=bq, block_k=bk)[0], right) < 0.01
+
+
+def test_without_a_window_the_tile_lists_are_the_triangles():
+    """No window and as many K/V heads: the lists are what they were before
+    either existed (the triangle, a query tile's key tiles oldest first; by
+    key, a key tile's query tiles), and the index maps take the head as it
+    is. With a window the band's tiles only, each query tile's newest
+    first."""
+    for t, bq, bk in ((8192, 512, 1024), (8192, 512, 512), (1024, 128, 256)):
+        triangle = [(i, j) for i in range(t // bq) for j in range(t // bk) if j * bk < (i + 1) * bq]
+        assert A.tile_list(t, bq, bk, False) == triangle
+        assert A.tile_list(t, bq, bk, True) == sorted(triangle, key=lambda p: (p[1], p[0]))
+    i, j = np.tril_indices(16)
+    assert [tuple(p) for p in zip(*map(np.asarray, M._tile_pairs(16 * 512, 512, None)))] == \
+        list(zip(i.tolist(), j.tolist()))
+    assert A._kv_head(32, 32)(5) == 5 and A._kv_head(28, 4)(13) == 1
+    # 16 384 positions, a window of 4 096: what the second model's cell runs
+    band = A.tile_list(16384, 512, 512, True, 4096)
+    assert len(band) == 252 and len(A.tile_list(16384, 512, 512, True)) == 528
+    assert all(0 <= i * 512 + 511 - j * 512 and i * 512 - (j * 512 + 511) < 4096 for i, j in band)
+    fwd = A.tile_list(16384, 512, 1024, False, 4096)
+    assert fwd[:5] == [(0, 0), (1, 0), (2, 1), (2, 0), (3, 1)]  # the diagonal back
+
+
 def _trace_counts():
     snap = pod_registry().snapshot()
     return {p: snap[label_key("st_attn_traces_total", "path", p)] for p in ("pallas", "scan")}
@@ -145,6 +238,10 @@ def test_which_path_causal_attention_takes(monkeypatch, case):
 
 def test_takes_refuses_a_head_whose_dq_does_not_fit_vmem(monkeypatch):
     monkeypatch.setenv("ST_CODEC", "pallas")
-    arg = lambda t, d: jax.ShapeDtypeStruct((32, t, d), jnp.bfloat16)
+    arg = lambda t, d, h=32: jax.ShapeDtypeStruct((h, t, d), jnp.bfloat16)
     assert A.takes(arg(8192, 192), arg(8192, 192), arg(8192, 128))
     assert not A.takes(arg(1 << 16, 192), arg(1 << 16, 192), arg(1 << 16, 128))
+    # the second model's heads at its whole context: one head's dq is 16.8 MB
+    assert A.takes(arg(16384, 128, 28), arg(16384, 128, 4), arg(16384, 128, 4))
+    assert A._bwd_vmem_bytes(16384, 128, 128, 512, 512, grouped=True) < A.VMEM_BUDGET // 2
+    assert not A.takes(arg(16384, 128, 28), arg(16384, 128, 8), arg(16384, 128, 8))  # 28 % 8

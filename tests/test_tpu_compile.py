@@ -28,7 +28,7 @@ import pytest
 from jax.sharding import NamedSharding, PartitionSpec as P
 
 from shared_tensor_tpu.models import char_rnn as m
-from shared_tensor_tpu.models import mla_moe
+from shared_tensor_tpu.models import mla_moe, swa_moe
 from shared_tensor_tpu.ops import codec_pallas, table
 from shared_tensor_tpu.parallel import (
     PeerSyncState,
@@ -207,3 +207,43 @@ def test_mla_block_grad_compiles_for_v5e_with_the_attention_kernels(v5e_devices)
     for line in kernels.values():
         assert "st.mla.attn" in re.search(r'op_name="([^"]*)"', line).group(1)
     assert not [l for l in text.splitlines() if " while(" in l and "st.mla.attn" in l]
+
+
+@pytest.mark.parametrize("kind", ["full", "window"])
+def test_swa_block_grad_compiles_for_v5e_with_the_attention_kernels(v5e_devices, kind):
+    """``value_and_grad`` of one layer of models/swa_moe.py at the published
+    widths (28 query heads on 4 K/V heads of 128, 16 of 64 experts held) and
+    the whole 16 384-token context, mapped over a peer axis of one: Mosaic
+    takes the two attention kernels with a band's tile list (window 4 096)
+    and with the triangle's, grouped K/V index maps and float32 ``dk``,
+    ``dv`` a query head; both sit in the layer kind's own scope and no loop
+    is left there."""
+    cfg = swa_moe.Config(num_hidden_layers=1, experts_held=(0, 16))
+    t = cfg.sliding_window_size * 4
+    window = cfg.sliding_window_size if kind == "window" else None
+    mesh = make_mesh(1, 1, devices=v5e_devices)
+    arg = lambda *shape: jax.ShapeDtypeStruct(
+        (1, *shape), jnp.float32, sharding=NamedSharding(mesh, P())
+    )
+    prefix = "model.layers.0."
+    params = {
+        name[len(prefix):]: arg(*shape)
+        for name, shape in swa_moe.param_shapes(cfg).items() if name.startswith(prefix)
+    }
+
+    def loss(p, x):
+        rope = mla_moe.rope_tables(t, cfg.head_dim, cfg.rope_theta) if window else None
+        return jnp.sum(swa_moe.block(p, x, rope, cfg, window)[0])
+
+    text = jax.jit(jax.vmap(jax.value_and_grad(loss))).lower(
+        params, arg(t, cfg.hidden_size)
+    ).compile().as_text()
+    kernels = {
+        name: line for line in text.splitlines() if "tpu_custom_call" in line
+        for name in re.findall(r"%(st_attn_\w+?)(?:\.\d+)? = ", line)
+    }
+    assert sorted(kernels) == ["st_attn_bwd", "st_attn_fwd"]
+    for line in kernels.values():
+        assert f"st.attn.{kind}" in re.search(r'op_name="([^"]*)"', line).group(1)
+    assert f"f32[28,{t},128]" in kernels["st_attn_bwd"]  # dk, dv a query head
+    assert not [l for l in text.splitlines() if " while(" in l and f"st.attn.{kind}" in l]
