@@ -49,7 +49,7 @@ from jax import lax
 from jax.ad_checkpoint import checkpoint_name
 from jax.custom_batching import custom_vmap
 
-from ..ops import attention_pallas
+from ..ops import attention_pallas, moe_pallas
 from ..utils.profiling import pod_tier
 
 ATTN_OUT = "attn_out"  # the residual a layer's checkpoint keeps
@@ -527,8 +527,8 @@ def _tile_table(w_pair, order, counts, n_tokens: int, cfg):
     tile is one expert's. For the most tiles any counts can need (every pair
     held, every expert's last tile part empty): the tile's expert ``[tiles]``
     and, ``[tiles, tile]`` each, the row's pair, token, weight (0 on padding)
-    and whether it is a pair at all; and the tiles to run
-    (:func:`_tiles_to_run`)."""
+    and whether it is a pair at all (a tile's ``live`` pairs come first); and
+    the tiles to run (:func:`_tiles_to_run`)."""
     held, tile = counts.shape[0], cfg.expert_tile
     run, most = _tiles_to_run(counts, n_tokens, cfg)
     tiles_of = (counts + tile - 1) // tile
@@ -540,7 +540,51 @@ def _tile_table(w_pair, order, counts, n_tokens: int, cfg):
     valid = (t < tile_end[-1])[:, None] & (off < counts[e][:, None])
     pair = order[jnp.where(valid, first_pair[e][:, None] + off, 0)]
     return dict(expert=e, pair=pair, token=pair // cfg.num_experts_per_tok,
-                weight=jnp.where(valid, w_pair[pair], 0.0), valid=valid), run
+                weight=jnp.where(valid, w_pair[pair], 0.0), valid=valid,
+                live=jnp.sum(valid, axis=1, dtype=jnp.int32)), run
+
+
+def _token_major(shape) -> tuple:
+    """The shape the tile loop carries a ``[T, hidden]`` float32 accumulator
+    in: ``[T, hidden / 128, 128]`` where ``hidden`` is whole lanes (and ``T``
+    whole sublane groups, for the way back), so that a token's row is (8,
+    128) tiles of its own. In two dimensions a row shares its tiles with the
+    seven other rows of its sublane group: XLA's scatter-add reads and writes
+    all eight to change one (0.139 ms a tile of 512 rows of 2 560 floats; my
+    chip run, PR 34), and Mosaic refuses to copy one."""
+    t, d = shape
+    return (t, d // 128, 128) if d % 128 == 0 and t % 8 == 0 else (t, d)
+
+
+def _two_dimensional(acc: jax.Array) -> jax.Array:
+    """A :func:`_token_major` accumulator as ``[T, hidden]`` again, once,
+    after the loop: eight tokens' ``[8, S, 128]`` turned to ``[S, 8, 128]``,
+    which are ``[T, hidden]``'s own (8, 128) tiles in their order, so the one
+    transposition is the whole relayout and the reshape after it a bitcast.
+    Behind barriers: left to itself XLA goes through two transpositions of
+    the whole array (tokens to the lanes and back), or moves the reshape past
+    the sums that read the result and lays those out token-major too
+    (chip-free compile, PR 34)."""
+    if acc.ndim == 2:
+        return acc
+    t, s, lanes = acc.shape
+    with jax.named_scope("st.moe.combine"):
+        tiles = lax.optimization_barrier(acc.reshape(t // 8, 8, s, lanes).transpose(0, 2, 1, 3))
+        return lax.optimization_barrier(tiles.transpose(0, 2, 1, 3).reshape(t, s * lanes))
+
+
+def _add_rows(acc: jax.Array, y: jax.Array, row: dict) -> jax.Array:
+    """``acc`` with one tile's rows ``y [tile, hidden]`` added to their tokens'
+    rows, one add a row: by the kernel of ``ops/moe_pallas.py`` where it runs
+    (a :func:`_token_major` accumulator where the codec's kernels run), else
+    by XLA's scatter-add, the kernel's twin. A padding row adds a zero to
+    the table's first token, or is skipped. Which was traced is counted
+    (``st_moe_combine_traces_total{path}``)."""
+    kernel = moe_pallas.takes(acc, y)
+    pod_tier().count_combine_trace("pallas" if kernel else "xla")
+    if kernel:
+        return moe_pallas.combine_rows(acc, y, row["token"], row["live"])
+    return acc.at[row["token"]].add(y.reshape(-1, *acc.shape[1:]))
 
 
 def _routed_impl(cfg, act: str, u, wg, wu, wd, w_pair, order, counts):
@@ -549,7 +593,8 @@ def _routed_impl(cfg, act: str, u, wg, wu, wd, w_pair, order, counts):
     [T*k]`` every pair's weight. A loop over the tiles the real counts need
     (:func:`_tile_table`), each tile a product with its own expert's weights:
     the work follows the pairs that exist, and the program holds one tile's
-    shape."""
+    shape. The sum is carried :func:`_token_major` and brought back to ``[T,
+    hidden]`` once, after the loop."""
     with jax.named_scope("st.moe.dispatch"):
         table, n_tiles = _tile_table(w_pair, order, counts, u.shape[0], cfg)
 
@@ -561,9 +606,10 @@ def _routed_impl(cfg, act: str, u, wg, wu, wd, w_pair, order, counts):
             e = row["expert"]
             y = _tile_ffn(x, wg[e], wu[e], wd[e], row["weight"], cfg.dtype, ACTIVATIONS[act])
         with jax.named_scope("st.moe.combine"):
-            return out.at[row["token"]].add(y)
+            return _add_rows(out, y, row)
 
-    return lax.fori_loop(0, n_tiles, tile, jnp.zeros(u.shape, jnp.float32))
+    return _two_dimensional(lax.fori_loop(
+        0, n_tiles, tile, jnp.zeros(_token_major(u.shape), jnp.float32)))
 
 
 def _routed_grad_impl(cfg, act: str, u, wg, wu, wd, w_pair, order, counts, g):
@@ -585,13 +631,15 @@ def _routed_grad_impl(cfg, act: str, u, wg, wu, wd, w_pair, order, counts, g):
                 x, wg[e], wu[e], wd[e], row["weight"])[1](gy)
             dwg, dwu, dwd = dwg.at[e].add(dg), dwu.at[e].add(du_), dwd.at[e].add(dd)
         with jax.named_scope("st.moe.combine"):
-            du = du.at[row["token"]].add(dx)
+            du = _add_rows(du, dx, row)
             # a padding row has pair 0 and no weight of its own
             dw_pair = dw_pair.at[row["pair"]].add(jnp.where(row["valid"], dw_row, 0.0))
         return du, dwg, dwu, dwd, dw_pair
 
-    start = tuple(jnp.zeros(a.shape, jnp.float32) for a in (u, wg, wu, wd, w_pair))
-    return lax.fori_loop(0, n_tiles, tile, start)
+    start = tuple(jnp.zeros(shape, jnp.float32) for shape in (
+        _token_major(u.shape), wg.shape, wu.shape, wd.shape, w_pair.shape))
+    du, *rest = lax.fori_loop(0, n_tiles, tile, start)
+    return (_two_dimensional(du), *rest)
 
 
 @partial(jax.custom_vjp, nondiff_argnums=(0, 1))

@@ -208,6 +208,10 @@ SCHEMA: dict[str, tuple[str, str]] = {
     # decided at trace time (backend, dtype, length), so counted per trace
     "st_attn_traces_total": ("counter", "traced calls of causal attention, each counted under two single-label series (per-path label: pallas = the fused kernels of ops/attention_pallas.py | scan = the portable tile loop; per-kind label: full = the whole causal triangle | window = the band of a sliding window)"),
     "st_attn_tiles_listed": ("gauge", "tiles the forward pass of the newest traced causal attention lists (per-kind label: full | window): the band against the triangle"),
+    # the expert loop's combine (models/mla_moe.py): which path a traced
+    # add of a tile's rows took, decided at trace time (backend, the
+    # accumulator's shape)
+    "st_moe_combine_traces_total": ("counter", "traced adds of an expert tile's rows into the loop's accumulator (per-path label: pallas = the kernel of ops/moe_pallas.py on the token-major accumulator | xla = XLA's scatter-add)"),
     # the codec kernels (ops/codec_pallas.py), counted when a program that
     # calls them is traced
     "st_codec_kernel_traces_total": ("counter", "traced calls of a codec kernel (per-kernel label: quantize_rows | apply_rows_batch)"),

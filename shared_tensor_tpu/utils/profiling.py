@@ -118,6 +118,14 @@ class PodTier:
             **{tiles_keys[kind]: n for kind, n in self._attn_tiles.items()},
         })
 
+        self._combine_traces = {"pallas": 0, "xla": 0}
+        combine_keys = {
+            path: label_key("st_moe_combine_traces_total", "path", path)
+            for path in self._combine_traces
+        }
+        self.registry.register_collector(
+            lambda: {combine_keys[p]: n for p, n in self._combine_traces.items()}
+        )
         self._codec_traces = {"quantize_rows": 0, "apply_rows_batch": 0}
         codec_keys = {
             kernel: label_key("st_codec_kernel_traces_total", "kernel", kernel)
@@ -168,6 +176,14 @@ class PodTier:
             self._attn_traces["path", path] += 1
             self._attn_traces["kind", kind] += 1
             self._attn_tiles[kind] = tiles
+
+    def count_combine_trace(self, path: str) -> None:
+        """One traced add of a tile's rows into an expert loop's accumulator
+        (``models/mla_moe.py``): by the kernel of ``ops/moe_pallas.py``
+        (``pallas``) or by XLA's scatter-add (``xla``). Two a layer's
+        gradient (the forward loop's and the backward's). Traces, not steps."""
+        with self._mu:
+            self._combine_traces[path] += 1
 
     def count_codec_kernel_trace(self, kernel: str, leaves_per_block: int) -> None:
         """One traced call of a codec kernel of ``ops/codec_pallas.py``

@@ -6,6 +6,7 @@ import functools
 import json
 import os
 import sys
+from functools import partial
 
 import jax
 import jax.numpy as jnp
@@ -18,9 +19,12 @@ sys.path.insert(0, ROOT)
 from chipbench.jobs import train_lm  # noqa: E402
 from chipbench.reference import joyai_ref as R  # noqa: E402
 from shared_tensor_tpu.models import mla_moe as M  # noqa: E402
+from shared_tensor_tpu.obs.schema import label_key  # noqa: E402
+from shared_tensor_tpu.ops import moe_pallas  # noqa: E402
 from shared_tensor_tpu.ops.table import make_spec  # noqa: E402
 from shared_tensor_tpu.parallel import make_mesh  # noqa: E402
 from shared_tensor_tpu.train import PodTrainer  # noqa: E402
+from shared_tensor_tpu.utils.profiling import pod_registry  # noqa: E402
 
 with open(os.path.join(ROOT, "chipbench", "configs", "joyai-llm-flash.json")) as _f:
     FILE = json.load(_f)
@@ -251,6 +255,136 @@ def test_the_rows_executed_follow_the_pairs_held():
     assert table["pair"].shape == (512 + 8, 128) and n_tiles.shape == ()
     assert [int(a) for a in M._tiles_to_run(counts, 8192, cfg)] == [24 + 8, 512 + 8]
     assert int(M._tiles_to_run(3 * counts, 8192, cfg)[0]) == 8 * 6 + 1
+
+
+# the token-major accumulator (ISSUE 34) -------------------------------------------
+
+
+def routed_case(case, hidden, tile, dtype="float32", k=4, experts=16, held=(4, 5), t=64):
+    """``held_experts``' operands for ``t`` tokens choosing ``k`` of
+    ``experts``, experts 4-8 held. ``mixed``: choices at random (experts'
+    last tiles part empty: padding rows), but token 0 has all its k pairs
+    held and expert 8 no pair at all, and the loop runs its floor of tiles;
+    ``past_floor``: every token's every pair is held (by 4-7), more tiles
+    than the floor."""
+    cfg = M.Config(hidden_size=hidden, moe_intermediate_size=24, n_routed_experts=experts,
+                   num_experts_per_tok=k, experts_held=held, expert_tile=tile,
+                   compute_dtype=dtype)
+    keys = jax.random.split(jax.random.key(hidden + tile), 6)
+    if case == "mixed":
+        idx = jnp.argsort(jax.random.uniform(keys[0], (t, experts)), axis=1)[:, :k]
+        idx = jnp.where(idx == 8, 9, idx).at[0].set(jnp.arange(4, 4 + k))
+    else:
+        idx = jnp.tile(jnp.arange(4, 4 + k), (t, 1))
+    f = cfg.moe_intermediate_size
+    p = {}
+    for e in range(held[0], held[0] + held[1]):
+        ke = jax.random.split(jax.random.fold_in(keys[1], e), 3)
+        p[f"experts.{e}.gate_proj.weight"] = 0.3 * jax.random.normal(ke[0], (f, hidden))
+        p[f"experts.{e}.up_proj.weight"] = 0.3 * jax.random.normal(ke[1], (f, hidden))
+        p[f"experts.{e}.down_proj.weight"] = 0.3 * jax.random.normal(ke[2], (hidden, f))
+    u = jax.random.normal(keys[2], (t, hidden))
+    w = jax.random.uniform(keys[3], (t, k), minval=0.1)
+    g = jax.random.normal(keys[4], (t, hidden))
+    return cfg, p, u, idx.astype(jnp.int32), w, g
+
+
+def routed_and_cotangents(cfg, act, p, u, idx, w, g, names=("gate_proj", "up_proj", "down_proj")):
+    """``routed_experts``' output and its five cotangents (u, the three
+    stacked weights by leaf, the pairs' weights) for the cotangent ``g``."""
+    def fn(p, u, w):
+        return M.held_experts(p, u, idx, w, cfg, act, names)[0]
+
+    out, vjp = jax.vjp(fn, p, u, w)
+    return out, vjp(g)
+
+
+def combine_traces():
+    snap = pod_registry().snapshot()
+    return {p: snap[label_key("st_moe_combine_traces_total", "path", p)] for p in ("pallas", "xla")}
+
+
+@pytest.mark.parametrize("dtype", ["float32", "bfloat16"])
+@pytest.mark.parametrize("case", ["mixed", "past_floor"])
+@pytest.mark.parametrize("hidden,tile", [(128, 8), (256, 16)])
+@pytest.mark.parametrize("path", ["xla", "pallas"])
+def test_the_token_major_accumulator_equals_the_two_dimensional_one_to_the_bit(
+        monkeypatch, path, hidden, tile, case, dtype):
+    """The same adds in the same order: forward and all five cotangents of
+    ``routed_experts`` with the sums carried ``[T, hidden / 128, 128]`` equal,
+    element for element, what the ``[T, hidden]`` carry gives: by XLA's
+    scatter-add on that carry, and by the kernel of ``ops/moe_pallas.py`` (in
+    the interpreter), which skips the padding rows the scatter adds as zeros.
+    Which path was traced is counted."""
+    monkeypatch.setenv("ST_CODEC", path)
+    before = combine_traces()
+    cfg, *args = routed_case(case, hidden, tile, dtype)
+    idx = args[2]
+    counts = jnp.sum((idx[..., None] - 4 == jnp.arange(5)).reshape(-1, 5), axis=0)
+    run, most = (int(a) for a in M._tiles_to_run(counts, idx.shape[0], cfg))
+    needed = int(jnp.sum(-(-counts // tile)))
+    if case == "mixed":
+        assert int(counts[4]) == 0 and bool(jnp.all((idx[0] >= 4) & (idx[0] < 8)))
+        assert bool(jnp.any(counts % tile != 0)) and needed < run < most
+    else:
+        assert run == needed and int(jnp.sum(counts)) == idx.size
+    assert M._token_major(args[1].shape) == (64, hidden // 128, 128)
+    got = jax.jit(partial(routed_and_cotangents, cfg, "silu"))(*args)
+    after = combine_traces()
+    # the forward loop's and the backward's
+    assert {p: after[p] - before[p] for p in after} == {path: 2, "xla" if path == "pallas" else "pallas": 0}
+    monkeypatch.setattr(M, "_token_major", lambda shape: tuple(shape))
+    want = jax.jit(partial(routed_and_cotangents, cfg, "silu"))(*args)
+    assert combine_traces()["xla"] == after["xla"] + 2  # two dimensions: XLA's, whatever the tier
+    assert float(jnp.max(jnp.abs(want[0]))) > 0
+    for a, b in zip(jax.tree.leaves(got), jax.tree.leaves(want), strict=True):
+        assert a.shape == b.shape and a.dtype == b.dtype
+        assert np.array_equal(np.asarray(a), np.asarray(b))
+
+
+@pytest.mark.parametrize("live", [40, 37, 16, 9, 1, 0])
+def test_the_combine_kernel_adds_the_live_rows_and_only_them(live):
+    """``moe_pallas.combine_rows`` in the interpreter on five blocks of eight
+    rows (both slots used again; whole blocks, a part block, empty ones)
+    against XLA's scatter-add of the live rows, element for element. The
+    rows past the live count name token 0, as the tile table's padding
+    does, and leave it alone."""
+    t, s, tile = 96, 2, 40
+    acc = jax.random.normal(jax.random.key(0), (t, s, 128))
+    y = jax.random.normal(jax.random.key(1), (tile, s * 128))
+    tokens = jax.random.permutation(jax.random.key(2), t)[:tile].astype(jnp.int32)
+    tokens = jnp.where(jnp.arange(tile) < live, tokens, tokens[0])
+    assert moe_pallas._block(tile) == 8
+    got = jax.jit(moe_pallas.combine_rows)(acc, y, tokens, live)
+    want = acc.at[tokens[:live]].add(y[:live].reshape(live, s, 128))
+    assert np.array_equal(np.asarray(got), np.asarray(want))
+
+
+def test_the_combine_kernel_runs_where_the_codecs_do_on_token_major_rows(monkeypatch):
+    f32 = lambda *shape: jax.ShapeDtypeStruct(shape, jnp.float32)
+    assert not moe_pallas.takes(f32(64, 2, 128), f32(16, 256))  # a CPU backend: XLA's
+    monkeypatch.setenv("ST_CODEC", "pallas")
+    assert moe_pallas.takes(f32(64, 2, 128), f32(16, 256))
+    assert not moe_pallas.takes(f32(64, 256), f32(16, 256))  # two dimensions
+    assert not moe_pallas.takes(f32(64, 2, 128), f32(12, 256))  # no whole blocks of rows
+    assert not moe_pallas.takes(f32(64, 2, 128), jax.ShapeDtypeStruct((16, 256), jnp.bfloat16))
+
+
+@pytest.mark.parametrize("hidden,carried", [(64, "f32[64,64]"), (128, "f32[64,1,128]"),
+                                            (256, "f32[64,2,128]"), (192, "f32[64,192]")])
+def test_the_accumulators_shape_is_chosen_by_the_operands(hidden, carried):
+    """Whole lanes of 128: token-major; else (the rehearsals' 64) the
+    two-dimensional carry stays. Forward and backward loop alike."""
+    cfg, *args = routed_case("mixed", hidden, 8)
+    text = str(jax.make_jaxpr(partial(routed_and_cotangents, cfg, "silu"))(*args))
+    loops = [line for line in text.splitlines() if " while[" in line or "= while" in line]
+    scatters = [line for line in text.splitlines() if "scatter-add" in line or "scatter_add" in line]
+    assert loops and scatters
+    other = "f32[64,%d]" % hidden if "128]" in carried else "f32[64,%d,128]" % (hidden // 128)
+    assert carried in text
+    rows = [line for line in scatters if carried in line.split("=")[0]]
+    assert len(rows) == 2  # out's and du's
+    assert not [line for line in scatters if other in line.split("=")[0]]
 
 
 # 5 -----------------------------------------------------------------------------

@@ -208,6 +208,7 @@ def test_schema_lint_every_emitted_st_name_is_documented():
         "st_apply_rows_batch": "Pallas kernel name (ops/codec_pallas.py), shown in device traces",
         "st_attn_fwd": "Pallas kernel name (ops/attention_pallas.py), shown in device traces",
         "st_attn_bwd": "Pallas kernel name (ops/attention_pallas.py), shown in device traces",
+        "st_moe_combine": "Pallas kernel name (ops/moe_pallas.py), shown in device traces",
     }
     emitted: dict[str, set[str]] = {}
     sources = list((repo / "shared_tensor_tpu").rglob("*.py")) + [

@@ -296,7 +296,7 @@ def test_pod_counters_are_in_the_schema_and_the_exposition():
         "st_pod_steps_total", "st_pod_compiles_total", "st_pod_compile_seconds_total",
         "st_pod_cache_load_seconds_total", "st_pod_last_compile_step",
         "st_attn_traces_total", "st_codec_kernel_traces_total",
-        "st_codec_leaves_per_block_max",
+        "st_codec_leaves_per_block_max", "st_moe_combine_traces_total",
     )
     text = pod_registry().prometheus_text()
     for name in names:
@@ -308,6 +308,8 @@ def test_pod_counters_are_in_the_schema_and_the_exposition():
     assert 'st_attn_traces_total{path="scan"}' in text
     assert 'st_codec_kernel_traces_total{kernel="quantize_rows"}' in text
     assert 'st_codec_kernel_traces_total{kernel="apply_rows_batch"}' in text
+    assert 'st_moe_combine_traces_total{path="pallas"}' in text
+    assert 'st_moe_combine_traces_total{path="xla"}' in text
     assert pod_registry() is pod_registry()
 
 

@@ -162,6 +162,41 @@ def test_the_shares_add_up_to_the_uncut_layer():
     np.testing.assert_allclose(h + routed, want, rtol=2e-5, atol=2e-6)
 
 
+@pytest.mark.parametrize("hidden,tile", [(128, 8), (256, 16)])
+def test_reglu_experts_on_the_token_major_accumulator_equal_the_two_dimensional_to_the_bit(
+        monkeypatch, hidden, tile):
+    """``mla_moe``'s expert loop as this decoder calls it (ReGLU, top-3 of 16,
+    experts 4-8 held, the leaves' own names) carries its sums ``[T, hidden /
+    128, 128]``: forward and the five cotangents equal the ``[T, hidden]``
+    carry's element for element. Choices at random, so experts' last tiles
+    are part padding; token 0 has all its pairs held, expert 8 has none."""
+    t, k, f = 64, 3, 24
+    cfg = M.Config(hidden_size=hidden, moe_ffn_hidden_size=f, moe_num_primary_experts=16,
+                   moe_num_active_primary_experts=k, experts_held=(4, 5), expert_tile=tile,
+                   compute_dtype="float32")
+    keys = jax.random.split(jax.random.key(hidden), 5)
+    idx = jnp.argsort(jax.random.uniform(keys[0], (t, 16)), axis=1)[:, :k]
+    idx = jnp.where(idx == 8, 9, idx).at[0].set(jnp.arange(4, 4 + k)).astype(jnp.int32)
+    p = {f"experts.{e}.{name}.weight": 0.3 * jax.random.normal(
+        jax.random.fold_in(keys[1], 3 * e + i), (hidden, f) if name == "down" else (f, hidden))
+        for e in range(4, 9) for i, name in enumerate(("gate", "up", "down"))}
+    u, g = (jax.random.normal(key, (t, hidden)) for key in keys[2:4])
+    w = jax.nn.softmax(jax.random.normal(keys[4], (t, k)), axis=-1)
+
+    def both(p, u, w):
+        out, vjp = jax.vjp(lambda p, u, w: mla_moe.held_experts(
+            p, u, idx, w, cfg, "relu", names=("gate", "up", "down"))[0], p, u, w)
+        return out, vjp(g)
+
+    assert mla_moe._token_major(u.shape) == (t, hidden // 128, 128)
+    got = jax.jit(both)(p, u, w)
+    monkeypatch.setattr(mla_moe, "_token_major", lambda shape: tuple(shape))
+    want = jax.jit(lambda *a: both(*a))(p, u, w)  # a function of its own: traced again
+    assert float(jnp.max(jnp.abs(want[0]))) > 0
+    for a, b in zip(jax.tree.leaves(got), jax.tree.leaves(want), strict=True):
+        assert a.shape == b.shape and np.array_equal(np.asarray(a), np.asarray(b))
+
+
 def test_router_weights_are_a_softmax_over_the_chosen_logits_of_the_raw_input():
     cfg = config()
     w_r = jax.random.normal(jax.random.key(2), (16, 64))

@@ -247,3 +247,89 @@ def test_swa_block_grad_compiles_for_v5e_with_the_attention_kernels(v5e_devices,
         assert f"st.attn.{kind}" in re.search(r'op_name="([^"]*)"', line).group(1)
     assert f"f32[28,{t},128]" in kernels["st_attn_bwd"]  # dk, dv a query head
     assert not [l for l in text.splitlines() if " while(" in l and f"st.attn.{kind}" in l]
+
+
+def _computations(text: str) -> dict[str, list[str]]:
+    """The compiled text's computations by name, each its instructions' lines."""
+    found, name = {}, None
+    for line in text.splitlines():
+        head = re.match(r"(?:ENTRY )?%(\S+) \(.*\{$", line)
+        if head:
+            name = head.group(1)
+            found[name] = []
+        elif name is not None and line.startswith("  "):
+            found[name].append(line)
+    return found
+
+
+def _reached(comps: dict[str, list[str]], root: str) -> list[str]:
+    """Every instruction of ``root`` and of the computations it calls."""
+    seen, todo, lines = set(), [root], []
+    while todo:
+        name = todo.pop()
+        if name in seen or name not in comps:
+            continue
+        seen.add(name)
+        lines += comps[name]
+        for line in comps[name]:
+            todo += re.findall(r"(?:calls|to_apply|body|condition)=%([\w.\-]+)", line)
+    return lines
+
+
+@pytest.mark.parametrize("decoder,tokens,tile", [("swa", 16384, 512), ("mla", 8192, 128)])
+def test_expert_layer_grad_compiles_for_v5e_with_token_major_accumulators(
+        v5e_devices, decoder, tokens, tile):
+    """``value_and_grad`` of the held experts' part of one layer at both
+    decoders' cells' shapes (hidden 2 560 = 20 sublanes in tiles of 512 rows;
+    2 048 = 16 in tiles of 128), mapped over a peer axis of one: both loops
+    (``out``'s and ``du``'s) carry their accumulator ``[T, hidden / 128,
+    128]`` in the layout ``{2,1,0:T(8,128)}`` (a token's row is tiles of its
+    own), each adds a tile's rows to it by one ``st_moe_combine`` Mosaic call
+    an iteration (row copies of one token from and to HBM: what Mosaic
+    refuses on the two-dimensional layout), nothing else in a loop's body
+    makes or copies an array of the accumulator's size, and it is brought
+    back to ``[T, hidden]`` after the loop."""
+    if decoder == "swa":
+        cfg = swa_moe.Config(num_hidden_layers=1, experts_held=(0, 16), expert_tile=tile)
+        width, names, act = cfg.moe_ffn_hidden_size, ("gate", "up", "down"), "relu"
+    else:
+        cfg = mla_moe.Config(experts_held=(0, 8), expert_tile=tile)
+        width, names, act = cfg.moe_intermediate_size, ("gate_proj", "up_proj", "down_proj"), "silu"
+    d, k = cfg.hidden_size, cfg.num_experts_per_tok
+    mesh = make_mesh(1, 1, devices=v5e_devices)
+    arg = lambda shape, dtype=jnp.float32: jax.ShapeDtypeStruct(
+        (1, *shape), dtype, sharding=NamedSharding(mesh, P())
+    )
+    params = {
+        f"experts.{e}.{name}.weight": arg((d, width) if name == names[2] else (width, d))
+        for e in range(cfg.experts_held[1]) for name in names
+    }
+
+    def loss(p, u, w, idx):
+        routed, _ = mla_moe.held_experts(p, u, idx, w, cfg, act, names)
+        return jnp.sum(jnp.square(routed))
+
+    text = jax.jit(jax.vmap(jax.value_and_grad(loss, argnums=(0, 1, 2)))).lower(
+        params, arg((tokens, d)), arg((tokens, k)), arg((tokens, k), jnp.int32)
+    ).compile().as_text()
+    carried, flat = f"f32[{tokens},{d // 128},128]", f"f32[{tokens},{d}]"
+    result = lambda line: line.split(" = ", 1)[-1]  # "<shape>{layout} <operation>(..."
+    operation = lambda line: re.search(r" ([\w\-]+)\(", result(line)).group(1)
+    comps = _computations(text)
+    loops = [line for line in text.splitlines() if " while(" in line and carried in line]
+    assert len(loops) == 2
+    for loop in loops:
+        assert carried + "{2,1,0:T(8,128)" in loop
+        body = _reached(comps, re.search(r"body=%([\w.\-]+)", loop).group(1))
+        made = [line for line in body if " = " in line and result(line).startswith((carried, flat))
+                and operation(line) not in ("parameter", "get-tuple-element")]
+        assert len(made) == 1 and "tpu_custom_call" in made[0], made
+        assert re.match(r"\s*%st_moe_combine(\.\d+)? = ", made[0])
+        assert "st.moe.combine" in re.search(r'op_name="([^"]*)"', made[0]).group(1)
+    # after each loop one transposition, eight tokens' [8, S, 128] to [S, 8, 128]:
+    # [T, hidden]'s own tiles, so what follows is a bitcast
+    tiles = f"f32[{tokens // 8},{d // 128},8,128]"
+    relayouts = [line for line in text.splitlines() if " = " in line
+                 and result(line).startswith(tiles) and operation(line) in ("fusion", "copy", "transpose")
+                 and "st.moe.combine" in line]
+    assert len(relayouts) == 2, relayouts

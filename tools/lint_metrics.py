@@ -43,6 +43,7 @@ ALLOWED_NON_METRICS: dict[str, str] = {
     "st_apply_rows_batch": "Pallas kernel name (ops/codec_pallas.py), shown in device traces",
     "st_attn_fwd": "Pallas kernel name (ops/attention_pallas.py), shown in device traces",
     "st_attn_bwd": "Pallas kernel name (ops/attention_pallas.py), shown in device traces",
+    "st_moe_combine": "Pallas kernel name (ops/moe_pallas.py), shown in device traces",
 }
 
 #: Dynamic-construction sites that are NOT metric names, keyed by the
