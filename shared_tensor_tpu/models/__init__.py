@@ -7,9 +7,12 @@ table is of deployment size (its ``Config``, ``init_params``, ``forward`` and
 ``loss_fn`` stay under ``mla_moe.``: char-rnn's are the package's);
 ``swa_moe`` the second, a grouped-query decoder with window and full
 attention layers, an early router and ReGLU experts, which takes what the two
-share from ``mla_moe``."""
+share from ``mla_moe``; ``gated_swa_moe`` the third, whose window and full
+layers differ in their head counts and their RoPE, with a per-head output
+gate, a dense first layer and a softmax-over-all router beside a shared
+expert, made of the other two's functions."""
 
-from . import char_rnn, mla_moe, resnet, swa_moe
+from . import char_rnn, gated_swa_moe, mla_moe, resnet, swa_moe
 from .char_rnn import (
     CharRNNConfig,
     encode_corpus,
@@ -19,6 +22,7 @@ from .char_rnn import (
     make_batches,
     sample,
 )
+from .gated_swa_moe import Config as GatedSwaMoeConfig
 from .mla_moe import Config as MlaMoeConfig
 from .resnet import ResNetConfig
 from .swa_moe import Config as SwaMoeConfig
@@ -28,7 +32,9 @@ __all__ = [
     "resnet",
     "mla_moe",
     "swa_moe",
+    "gated_swa_moe",
     "MlaMoeConfig",
+    "GatedSwaMoeConfig",
     "SwaMoeConfig",
     "CharRNNConfig",
     "ResNetConfig",

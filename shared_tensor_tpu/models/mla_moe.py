@@ -214,10 +214,19 @@ def rms_norm(x: jax.Array, w: jax.Array, eps: float) -> jax.Array:
     return x * lax.rsqrt(jnp.mean(x * x, axis=-1, keepdims=True) + eps) * w
 
 
-def rope_tables(n: int, dim: int, theta: float) -> tuple[jax.Array, jax.Array]:
-    """cos, sin ``[n, dim // 2]`` of position x theta^(-2i/dim)."""
-    inv = theta ** (-jnp.arange(0, dim, 2, dtype=jnp.float32) / dim)
+def rope_tables(n: int, dim: int, theta: float, inv_freq=None,
+                scale: float = 1.0) -> tuple[jax.Array, jax.Array]:
+    """cos, sin ``[n, dim // 2]`` of position x theta^(-2i/dim), or of
+    position x ``inv_freq [dim // 2]`` where a model brings its own
+    frequencies (stretched ones, say); both times ``scale`` where it is not 1
+    (a stretched context's attention factor)."""
+    if inv_freq is None:
+        inv = theta ** (-jnp.arange(0, dim, 2, dtype=jnp.float32) / dim)
+    else:
+        inv = jnp.asarray(inv_freq, jnp.float32)
     ang = jnp.arange(n, dtype=jnp.float32)[:, None] * inv[None, :]
+    if scale != 1.0:
+        return jnp.cos(ang) * scale, jnp.sin(ang) * scale
     return jnp.cos(ang), jnp.sin(ang)
 
 
@@ -395,7 +404,8 @@ def causal_attention(q, k, v, block: int, window: int | None = None,
 
     Which one was traced is counted (``st_attn_traces_total{path}`` and
     ``{kind}``), and the tiles its forward pass lists
-    (``st_attn_tiles_listed{kind}``)."""
+    (``st_attn_tiles_listed{kind}``) for how many query heads
+    (``st_attn_heads{kind}``)."""
     n = q.shape[0]
     window = window if window is not None and window < n else None
     q, k, v = (jnp.swapaxes(a, 0, 1) for a in (q, k, v))  # heads first
@@ -405,7 +415,7 @@ def causal_attention(q, k, v, block: int, window: int | None = None,
     bq, bk = (block, block) if block else attention_pallas._fwd_tiles(n)
     pod_tier().count_attention_trace(
         "scan" if block else "pallas", "full" if window is None else "window",
-        len(attention_pallas.tile_list(n, bq, bk, False, window)))
+        len(attention_pallas.tile_list(n, bq, bk, False, window)), heads=q.shape[0])
     return jnp.swapaxes(_attention_tiles(q, k, v, block, window, scope), 0, 1)
 
 
@@ -705,15 +715,17 @@ def expert_counters(held, cfg) -> dict:
     }
 
 
-def moe(p: dict, u: jax.Array, cfg: Config):
+def moe(p: dict, u: jax.Array, cfg, router=route, shared_name: str = "shared_experts."):
     """The expert layer of ``u [T, hidden]``: shared expert + the held
-    experts' weighted part; ``p`` holds the ``mlp.*`` leaves. Returns the
-    output and this layer's counters."""
+    experts' weighted part; ``p`` holds the ``mlp.*`` leaves, the shared
+    expert's under the prefix ``shared_name``, and ``router(p, u, cfg)`` gives
+    every token's experts and weights. Returns the output and this layer's
+    counters."""
     with jax.named_scope("st.moe.router"):
-        idx, w = route(p, u, cfg)
+        idx, w = router(p, u, cfg)
     routed, held = held_experts(p, u, idx, w, cfg, "silu")
     with jax.named_scope("st.moe.shared"):
-        shared = swiglu({n: p["shared_experts." + n] for n in
+        shared = swiglu({n: p[shared_name + n] for n in
                          ("gate_proj.weight", "up_proj.weight", "down_proj.weight")},
                         u, cfg.dtype)
     aux = expert_counters(held, cfg)

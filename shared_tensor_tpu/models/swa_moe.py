@@ -136,9 +136,10 @@ def param_shapes(cfg: Config) -> dict[str, tuple[int, ...]]:
     return shapes
 
 
-def init_params(key: jax.Array, cfg: Config) -> dict[str, jax.Array]:
-    """Matrices normal(0, ``init_std``), the embedding normal(0, 1), norms 1."""
-    shapes = param_shapes(cfg)
+def init_params(key: jax.Array, cfg, shapes=param_shapes) -> dict[str, jax.Array]:
+    """Matrices normal(0, ``init_std``), the embedding normal(0, 1), norms 1,
+    for the leaves ``shapes(cfg)`` names."""
+    shapes = shapes(cfg)
     params = {}
     for k, (name, shape) in zip(jax.random.split(key, len(shapes)), sorted(shapes.items())):
         if len(shape) == 1:
@@ -150,22 +151,32 @@ def init_params(key: jax.Array, cfg: Config) -> dict[str, jax.Array]:
 
 
 def rope_half(x: jax.Array, cos: jax.Array, sin: jax.Array) -> jax.Array:
-    """Rotate the pairs ``(i, i + dim/2)`` of the last axis of ``x [T, H,
-    dim]`` by position t's angles (the ``rotate_half`` layout)."""
+    """Rotate the pairs ``(i, i + r/2)`` of the first ``r`` = twice the
+    tables' width dimensions of the last axis of ``x [T, H, dim]`` by
+    position t's angles (the ``rotate_half`` layout); dimensions from ``r``
+    on pass (a partial rotary factor)."""
     x = x.astype(jnp.float32)
-    a, b = jnp.split(x, 2, axis=-1)
+    r = 2 * cos.shape[-1]
+    a, b, *rest = jnp.split(x, 2 if r == x.shape[-1] else (r // 2, r), axis=-1)
     c, s = cos[:, None, :], sin[:, None, :]
-    return jnp.concatenate([a * c - b * s, b * c + a * s], axis=-1)
+    return jnp.concatenate([a * c - b * s, b * c + a * s, *rest], axis=-1)
 
 
-def attention(p: dict, x: jax.Array, rope, window: int | None, cfg: Config) -> jax.Array:
+def attention(p: dict, x: jax.Array, rope, window: int | None, cfg,
+              heads: int | None = None) -> jax.Array:
     """Grouped-query attention of ``x [T, hidden]`` (already normed) over the
     whole causal prefix (``window`` None) or each query's ``window`` newest
-    keys, with RoPE on q and k where ``rope`` is given; ``p`` holds the
-    ``self_attn.*`` leaves."""
+    keys, in ``heads`` query heads (``cfg.num_attention_heads`` by default:
+    a model may give a layer its own count), with RoPE on q and k where
+    ``rope`` is given (tables narrower than half a head turn the head's first
+    dimensions only); ``p`` holds the ``self_attn.*`` leaves. Where they hold
+    a ``g_proj.weight [heads, hidden]``, every head's output is scaled by the
+    sigmoid of its row's product with ``x`` before ``o_proj`` (a headwise
+    output gate, arXiv:2505.06708), in float32."""
     n, hd, dt = x.shape[0], cfg.head_dim, cfg.dtype
+    heads = heads or cfg.num_attention_heads
     with jax.named_scope("st.attn.proj"):
-        q = _mm(x, p["q_proj.weight"], dt).reshape(n, cfg.num_attention_heads, hd)
+        q = _mm(x, p["q_proj.weight"], dt).reshape(n, heads, hd)
         k = _mm(x, p["k_proj.weight"], dt).reshape(n, cfg.num_key_value_heads, hd)
         v = _mm(x, p["v_proj.weight"], dt).reshape(n, cfg.num_key_value_heads, hd)
         if rope is not None:
@@ -174,6 +185,9 @@ def attention(p: dict, x: jax.Array, rope, window: int | None, cfg: Config) -> j
     scope = "st.attn.full" if window is None else "st.attn.window"
     with jax.named_scope(scope):
         o = causal_attention(q, k, v, cfg.attn_block, window, scope=scope)
+    if "g_proj.weight" in p:
+        with jax.named_scope("st.attn.gate"):
+            o = o * jax.nn.sigmoid(_mm(x, p["g_proj.weight"], dt))[:, :, None]
     with jax.named_scope("st.attn.proj"):
         return _mm(o.reshape(n, -1), p["o_proj.weight"], dt)
 
@@ -227,7 +241,7 @@ def forward(params: dict, tokens: jax.Array, cfg: Config) -> jax.Array:
     return head_logits(y, params["model.norm.weight"], params["lm_head.weight"], cfg)
 
 
-def _sequence_loss(params: dict, tokens: jax.Array, cfg: Config):
+def _sequence_loss(params: dict, tokens: jax.Array, cfg, trunk):
     n = tokens.shape[0]
     y, auxes = trunk(params, tokens, cfg)
     with jax.named_scope("st.head_loss"):
@@ -239,17 +253,19 @@ def _sequence_loss(params: dict, tokens: jax.Array, cfg: Config):
     return ce, aux, y
 
 
-def loss_fn(params: dict, batch: jax.Array, cfg: Config,
-            positions: jax.Array | None = None) -> tuple[jax.Array, Any]:
+def loss_fn(params: dict, batch: jax.Array, cfg, positions: jax.Array | None = None,
+            trunk=trunk) -> tuple[jax.Array, Any]:
     """``(loss, aux)`` of ``batch [B, T]`` token ids (documents packed, no
     mask between them): the next-token cross-entropy over the held slice of
     the vocabulary, the mean over the sequences. ``aux`` is ``mla_moe``'s
-    without the prediction module's entries: ``ce_main`` and, one entry a
-    layer, ``moe_pairs_held``, ``moe_load_max_over_mean``,
+    without the prediction module's entries: ``ce_main`` and, one entry an
+    expert layer, ``moe_pairs_held``, ``moe_load_max_over_mean``,
     ``moe_tokens_unrouted_share``, ``moe_rows_executed``; with ``positions``
     also ``ce_main_of [B]``, ``logits [B, len(positions), vocab_held]`` and
-    ``choices [B, layers, T, k]``."""
-    outs = [_sequence_loss(params, batch[b], cfg) for b in range(batch.shape[0])]
+    ``choices [B, expert layers, T, k]``. ``trunk(params, tokens, cfg)`` is
+    the decoder under the head: this module's, or another's that ends in the
+    same norm, head and loss."""
+    outs = [_sequence_loss(params, batch[b], cfg, trunk) for b in range(batch.shape[0])]
     n = len(outs)
     aux = {name: sum(a[name] for _, a, _ in outs) / n
            for name in outs[0][1] if name != "choices"}

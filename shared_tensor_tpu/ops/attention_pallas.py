@@ -27,6 +27,13 @@ kernel writes ``dk``, ``dv`` a query head in float32, which XLA adds over
 each group (a group's queries cannot share one ``dk`` in scratch: the kernel
 holds one head's whole ``dq`` in VMEM, 16.8 MB at 16 384 x 128, and seven do
 not fit).
+A window narrower than a tile is a band like any other (a tile may straddle
+both of its edges, and a late query of a far tile may see nothing of it),
+and whole tiles then visit several times the scores the band holds; the chip
+still runs the widest tiles fastest (:func:`_fwd_tiles` has the readings), so
+a window does not narrow them. The one thing the forward kernel asks is that
+a query tile lie inside one key tile, so that its walk starts on its queries'
+own keys.
 With no window and as many K/V heads the tile lists, the masks and the
 compiled bodies are what they were before either existed.
 
@@ -82,7 +89,16 @@ def _fwd_tiles(t: int) -> tuple[int, int]:
     numerator are rescaled once a key tile, so wider key tiles spare work:
     the compiled kernel's bundles a score fall by a quarter from 512 x 512 to
     512 x 1 024 and no further at 2 048 (chip-free compile for a v5e, PR 30);
-    the backward kernel rescales nothing and reads the same at every tile."""
+    the backward kernel rescales nothing and reads the same at every tile.
+
+    A window does not narrow them, though whole tiles visit several times the
+    scores a narrow band holds (3.0 times at 512 x 1 024 under a window of
+    512, 2.0 at 512 x 512, 1.5 at 256 x 256): a tile's time is far from
+    proportional to its scores. 72 heads over 8 192 positions under a window
+    of 512, forward: 5.04 ms at 512 x 1 024, 5.63 at 512 x 512, 6.33 at 256 x
+    512, 8.69 at 256 x 256, 15.8 at 128 x 128; backward: 7.50 at 512 x 512,
+    8.38 at 256 x 512, 9.01 at 256 x 256, 17.5 at 128 x 128 (my chip run, PR
+    35; the chip-free price of the bundles orders them alike)."""
     b = _tile(t)
     return b, (2 * b if b and t % (2 * b) == 0 else b)
 
@@ -238,6 +254,9 @@ def attention_fwd(q, k, v, *, window: int | None = None,
     kv = _kv_head(h, k.shape[0])
     bq, bk = _fwd_tiles(t)
     bq, bk = block_q or bq, block_k or bk
+    if window is not None and bk % bq:
+        # the walk back from the diagonal starts on the tile of the queries' own keys
+        raise ValueError(f"with a window a query tile ({bq}) lies inside one key tile ({bk})")
     qi, kj = _tiles(t, bq, bk, False, window)
     need = (2 * 2 * (bq * _lanes(d) + bk * _lanes(d) + bk * _lanes(dv) + bq * _lanes(dv))
             + bq * _lanes(dv) * 4 + bq * bk * (3 * 4 + 2))

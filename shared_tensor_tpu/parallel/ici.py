@@ -106,7 +106,6 @@ def init_state(
         # default device first would stage the whole (n_peer, total) state
         # there.
         zeros = jax.jit(lambda: jnp.zeros(shape, jnp.float32), out_shardings=sh)
-        residual = zeros()
         if template is None:
             values = zeros()
         else:
@@ -116,6 +115,11 @@ def init_state(
             values = jax.jit(
                 lambda f: jnp.broadcast_to(f, shape), out_shardings=sh
             )(seed)
+            del seed
+        # last: seeding holds the template, its flat copy and the broadcast
+        # at once, five tables with the residual beside them (16.25 GB of a
+        # v5e's 16.91 for a 3.24 GB table; my chip run, PR 35)
+        residual = zeros()
     return PeerSyncState(values, residual)
 
 

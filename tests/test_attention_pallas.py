@@ -116,6 +116,19 @@ BAND = {
     "grouped_no_window": (4, 2, 256, None, 128, 128),
     # a window the sequence never fills: the triangle under the band's code
     "window_longer_than_the_sequence": (2, 2, 256, 1000, 128, 128),
+    # the third model's layouts in small. Groups of 6 (its full layers' 48 on
+    # 8) under a window narrower than the query tile and than the key tile and
+    # a multiple of neither: a query tile's band lies inside two key tiles
+    "group_of_6_window_under_both_tile_sides": (6, 1, 512, 100, 128, 256),
+    # groups of 9 (its window layers' 72 on 8), two K/V heads, the window
+    # between the query tile and the key tile
+    "group_of_9_window_between_the_tile_sides": (18, 2, 512, 200, 128, 256),
+    # square tiles of 256 and a window far under them: every tile but the
+    # first of a row straddles both of the band's edges
+    "window_far_under_square_tiles": (6, 1, 512, 72, 256, 256),
+    # the kernels' own tiles (512 x 1 024 forward, 512 backward) at a group of
+    # 9 under a window narrower than either side
+    "own_tiles_group_of_9": (9, 1, 1024, 300, None, None),
 }
 
 
@@ -149,11 +162,15 @@ def test_band_and_groups_equal_the_scan_and_the_dense_mask(case):
         assert worst(a, c) <= 1.5 * room, name
 
 
-def test_a_window_one_key_short_is_told_apart():
+@pytest.mark.parametrize("case", [
+    "grouped_7_window_of_no_whole_tile", "group_of_6_window_under_both_tile_sides",
+    "own_tiles_group_of_9"])
+def test_a_window_one_key_short_is_told_apart(case):
     """The far edge is exact: the band of ``window - 1`` keys differs from
     the band of ``window`` in the kernels, the scan and the dense mask alike,
-    by far more than their rounding."""
-    h, h_kv, t, window, bq, bk = BAND["grouped_7_window_of_no_whole_tile"]
+    by far more than their rounding; also where the window is narrower than a
+    tile."""
+    h, h_kv, t, window, bq, bk = BAND[case]
     q, k, v, _ = operands(h, t, 128, 128, h_kv)
     right = plain(q, k, v, window)[0]
     for short in (A.attention_fwd(q, k, v, window=window - 1, block_q=bq, block_k=bk)[0],
@@ -183,6 +200,48 @@ def test_without_a_window_the_tile_lists_are_the_triangles():
     assert all(0 <= i * 512 + 511 - j * 512 and i * 512 - (j * 512 + 511) < 4096 for i, j in band)
     fwd = A.tile_list(16384, 512, 1024, False, 4096)
     assert fwd[:5] == [(0, 0), (1, 0), (2, 1), (2, 0), (3, 1)]  # the diagonal back
+
+
+def test_a_window_wants_a_query_tile_inside_one_key_tile():
+    """The forward kernel walks a window's key tiles from the diagonal back
+    and counts on the first of them holding every query's own key: a query
+    tile wider than its key tiles would start some rows on a tile they see
+    nothing of. It says so; the tiles it chooses itself never are, and the
+    backward kernel, which keeps no running maximum, takes any."""
+    q, k, v, g = operands(2, 512, 128, 128, 1)
+    with pytest.raises(ValueError, match="inside one key tile"):
+        A.attention_fwd(q, k, v, window=72, block_q=256, block_k=128)
+    o, lse = plain(q, k, v, 72)
+    got = A.attention_bwd(q, k, v, o.astype(q.dtype), lse, g, window=72, block_q=256, block_k=128)
+    want = A.attention_bwd(q, k, v, o.astype(q.dtype), lse, g, window=72, block_q=128, block_k=128)
+    for a, b in zip(got, want):
+        assert worst(a, b) <= 0.01 * float(jnp.max(jnp.abs(b.astype(jnp.float32))))
+    for t in (8192, 16384, 1024, 384, 128):
+        bq, bk = A._fwd_tiles(t)
+        assert bk % bq == 0
+
+
+def test_a_window_does_not_narrow_the_tiles():
+    """With no window, with a window of eight tiles (4 096 at 16 384 tokens)
+    and with one of a single tile (512 at 8 192) the tiles are the length's
+    own, 512 queries by 1 024 keys forward and 512 by 512 backward, and the
+    lists what they were: 272 forward tiles of the triangle and 140 of the
+    band at 16 384. Under the window of 512 whole tiles visit 3.0 times the
+    band's scores forward (an odd query tile's band lies inside one key tile,
+    an even one's in two) and 2.0 backward, where tiles of 256 would visit
+    1.5: the chip runs the wide ones faster all the same (``_fwd_tiles``)."""
+    assert A._fwd_tiles(8192) == A._fwd_tiles(16384) == (512, 1024)
+    assert A._tile(8192) == A._tile(16384) == 512
+    assert len(A.tile_list(16384, 512, 1024, False)) == 272
+    assert len(A.tile_list(16384, 512, 1024, False, 4096)) == 140
+    assert len(A.tile_list(16384, 512, 512, True, 4096)) == 252
+    band = 512 * 513 // 2 + (8192 - 512) * 512  # pairs a head
+    visited = lambda bq, bk: len(A.tile_list(8192, bq, bk, False, 512)) * bq * bk / band
+    assert len(A.tile_list(8192, 512, 1024, False, 512)) == 23
+    assert visited(512, 1024) == pytest.approx(3.0, abs=0.05)
+    assert visited(512, 512) == pytest.approx(2.0, abs=0.05)
+    assert visited(256, 256) == pytest.approx(1.5, abs=0.03)
+    assert round(1000 * band / (8192 * 8193 // 2)) == 121  # 12.1 % of the triangle
 
 
 def _trace_counts():

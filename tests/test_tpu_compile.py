@@ -28,7 +28,7 @@ import pytest
 from jax.sharding import NamedSharding, PartitionSpec as P
 
 from shared_tensor_tpu.models import char_rnn as m
-from shared_tensor_tpu.models import mla_moe, swa_moe
+from shared_tensor_tpu.models import gated_swa_moe, mla_moe, swa_moe
 from shared_tensor_tpu.ops import codec_pallas, table
 from shared_tensor_tpu.parallel import (
     PeerSyncState,
@@ -247,6 +247,49 @@ def test_swa_block_grad_compiles_for_v5e_with_the_attention_kernels(v5e_devices,
         assert f"st.attn.{kind}" in re.search(r'op_name="([^"]*)"', line).group(1)
     assert f"f32[28,{t},128]" in kernels["st_attn_bwd"]  # dk, dv a query head
     assert not [l for l in text.splitlines() if " while(" in l and f"st.attn.{kind}" in l]
+
+
+@pytest.mark.parametrize("layer", [0, 1])
+def test_gated_swa_block_grad_compiles_for_v5e_with_the_attention_kernels(v5e_devices, layer):
+    """``value_and_grad`` of one layer of models/gated_swa_moe.py at the
+    published widths and 8 192 tokens, mapped over a peer axis of one: layer
+    0 (full attention, 48 query heads on 8 K/V heads, YaRN on half a head,
+    the dense MLP) and layer 1 (a window of 512 keys, narrower than the
+    kernels' widest tile, 72 query heads, 8 of 256 experts held beside the
+    shared one). Mosaic takes the two attention kernels at groups of 6 and 9
+    with float32 ``dk``, ``dv`` a query head, in the layer kind's scope, and
+    the gate's operations sit in ``st.attn.gate``."""
+    cfg = gated_swa_moe.Config(num_hidden_layers=2, experts_held=(0, 8))
+    t, heads = 8192, cfg.heads(layer)
+    kind = "window" if cfg.window(layer) else "full"
+    mesh = make_mesh(1, 1, devices=v5e_devices)
+    arg = lambda *shape: jax.ShapeDtypeStruct(
+        (1, *shape), jnp.float32, sharding=NamedSharding(mesh, P())
+    )
+    prefix = f"model.layers.{layer}."
+    params = {
+        name[len(prefix):]: arg(*shape)
+        for name, shape in gated_swa_moe.param_shapes(cfg).items() if name.startswith(prefix)
+    }
+
+    def loss(p, x):
+        rope = gated_swa_moe.layer_rope(cfg, cfg.layer_types[layer], t)
+        return jnp.sum(gated_swa_moe.block(p, x, rope, cfg, layer)[0])
+
+    text = jax.jit(jax.vmap(jax.value_and_grad(loss))).lower(
+        params, arg(t, cfg.hidden_size)
+    ).compile().as_text()
+    kernels = {
+        name: line for line in text.splitlines() if "tpu_custom_call" in line
+        for name in re.findall(r"%(st_attn_\w+?)(?:\.\d+)? = ", line)
+    }
+    assert sorted(kernels) == ["st_attn_bwd", "st_attn_fwd"]
+    for line in kernels.values():
+        assert f"st.attn.{kind}" in re.search(r'op_name="([^"]*)"', line).group(1)
+    assert f"f32[{heads},{t},128]" in kernels["st_attn_bwd"]  # dk, dv a query head
+    assert not [l for l in text.splitlines() if " while(" in l and f"st.attn.{kind}" in l]
+    assert "st.attn.gate" in text
+    assert ("st.moe.shared" in text) == (layer > 0) and ("st.ffn" in text) == (layer == 0)
 
 
 def _computations(text: str) -> dict[str, list[str]]:
