@@ -217,6 +217,7 @@ SCHEMA: dict[str, tuple[str, str]] = {
     # calls them is traced
     "st_codec_kernel_traces_total": ("counter", "traced calls of a codec kernel (per-kernel label: quantize_rows | apply_rows_batch)"),
     "st_codec_leaves_per_block_max": ("gauge", "most leaves one grid block of the newest traced codec kernel meets (the worst trip count of its loop over leaves)"),
+    "st_codec_words_rows_per_block": ("gauge", "128-lane rows of packed words a grid step of the newest traced codec kernel takes (per-kernel label: quantize_rows | apply_rows_batch): 32 at a block of 1 024 table rows"),
     # per-link series (rendered via link_key)
     "st_link_bytes_out_total": ("counter", "wire bytes sent on the link (incl. framing/keepalives)"),
     "st_link_bytes_in_total": ("counter", "wire bytes received on the link"),

@@ -3,7 +3,7 @@
 The reference's hot path is 4-6 sequential CPU passes of n float ops per frame
 (quantize src/sharedtensor.c:153-174, apply :106-111 — measured codec-CPU-bound
 at 202 M elem/s, BASELINE.md). These two kernels move that work onto the TPU
-VPU with the minimum number of HBM passes. The table codec (ops/table.py) runs
+with the minimum number of HBM passes. The table codec (ops/table.py) runs
 the sign/error-feedback rule with a scale per leaf, and per-leaf padding is
 row-aligned, so a (1, 128) row has one scale and so many live lanes:
 
@@ -24,7 +24,10 @@ live lanes from them (:func:`_on_block_leaves`). No ``(rows, 1)`` operand is
 built, stored or streamed: XLA pads such an array to 128 lanes, 1.68 GB for
 13 MB of numbers at 3.28 M rows, and its block costs a grid step half the DMA
 of a full float32 block (PERF.md section 6, PR 32). The streamed operands are
-the float32 rows and the packed words.
+the float32 rows and the packed words, and nothing is built for the words
+either: ``quantize_rows`` writes them in the layout below, an all-gather
+stacks K peers' arrays as they are, and ``apply_rows_batch`` takes the stack
+as it arrives (PERF.md section 6, PR 37).
 
 Each kernel has exactly one caller, ops/table.py (``quantize_rows`` /
 ``apply_rows``), which holds their XLA twins and builds ``LeafTables`` from
@@ -32,7 +35,12 @@ the table's static leaf ranges (``LeafRows.tables``); both are deliberately
 UN-jitted, since the table functions wrap them in their own jit and
 parallel/ici.py embeds them inside a shard_map'd step. A traced call is
 counted (``st_codec_kernel_traces_total{kernel}``,
-``st_codec_leaves_per_block_max`` in ``utils.profiling.pod_registry()``).
+``st_codec_leaves_per_block_max``, ``st_codec_words_rows_per_block{kernel}``
+in ``utils.profiling.pod_registry()``). A kernel's traced size does not grow
+with K or with the block: whole-block vector operations and loops with one
+body, nothing unrolled in Python (tests/test_codec_pallas.py guards it: a
+body unrolled over frames and sublane groups cost a sync step 5.5 s of
+set-up, PERF.md section 6, PR 36).
 
 Bit layout is identical to ops/codec.py (flat bit i -> word[i//32] bit i%32),
 so frames from either implementation interoperate; tests/test_codec_pallas.py
@@ -42,9 +50,17 @@ codec, a plain NumPy statement of the rule, and the XLA twins.
 Kernels run compiled on TPU and fall back to the interpreter on CPU (tests).
 
 Layout: a flat padded buffer (a multiple of 1024) viewed as (rows, 128)
-float32 rows; packed words viewed as (rows, 4) uint32 rows. Row r, word k
-covers flat bits 128*r + 32*k .. +31, so ``words2d.reshape(-1)`` is the flat
-word vector used by the wire layer.
+float32 rows; the packed words are the wire layer's flat word vector viewed
+128 words a row, ``u32[ceil(rows / 32), 128]`` (ops/packing.py
+``dense_words``: a bitcast). Table row r's word k, which covers flat bits
+128 r + 32 k .. +31, is flat word 4 r + k: words row r // 32, lane
+4 (r % 32) + k. One words row holds the words of 32 consecutive table rows,
+so the array is dense in HBM (52 MB at 3.28 M rows; a ``u32[rows, 4]`` is
+lane-padded 32x there, 1.68 GB), a block of 1 024 table rows is a (32, 128)
+tile of words, and ``words.reshape(-1)[: rows * 4]`` is the flat word
+vector. ``rows`` is whole 8-row tiles, not whole words rows: the pad words
+behind the last table row are 0 (no lane past a leaf's live end sets a bit)
+and never read as live.
 """
 
 from __future__ import annotations
@@ -59,14 +75,20 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 from .codec import SAT
-from .packing import LANES, BITS_PER_WORD
+from .packing import BITS_PER_WORD, LANES, WORDS_PER_ROW, words_rows
 
-WORDS_PER_ROW = LANES // BITS_PER_WORD  # 4
+#: Table rows whose packed words fill one 128-lane row of the words array.
+ROWS_PER_WORDS_ROW = LANES // WORDS_PER_ROW  # 32
 #: Most rows per grid step: 1024 rows x 128 lanes x 4 B = 512 KiB per buffer in
-#: VMEM. A kernel that waits on its DMAs (quantize_rows; apply_rows_batch at
-#: K = 1) takes them all: fewer, longer transfers (8.13 -> 7.86 ms and 8.50 ->
-#: 8.09 ms at 3.28 M rows on a v5e, 2048 no better; PERF.md section 6, PR 32).
+#: VMEM, 32 words rows. Both kernels wait on their DMAs and take them all:
+#: fewer, longer transfers (PERF.md section 6, PR 32 and PR 37).
 BLOCK_ROWS = 1024
+#: Most frames the apply kernel unpacks back to back with no loop between
+#: them: the scheduler overlaps one frame's lane gathers with the next's
+#: arithmetic, a loop trip a frame does not (11.0 ms against 8.2 at K = 4,
+#: 39.9 against 33.1 at K = 16 in groups of 8; PERF.md section 6, PR 37).
+#: Past it a loop runs over groups of this many.
+_FRAMES_UNROLLED = 8
 
 
 def _interpret() -> bool:
@@ -104,51 +126,103 @@ def _exact_pow2(e_i32):
     return jax.lax.bitcast_convert_type((e_i32 + 127) << 23, jnp.float32)
 
 
-def _pack_rows(bits_i32):
-    """(rows, 128) 0/1 int32 -> (rows, 4) uint32, LSB-first per 32 lanes.
+def _iota(shape, axis):
+    return jax.lax.broadcasted_iota(jnp.int32, shape, axis)
 
-    Mosaic supports neither unsigned reductions nor lane-splitting reshapes
-    ((rows,128)->(rows,4,32) fails "unsupported shape cast"), so the
-    lane-group reduction runs on the MXU instead: two (rows,128)x(128,4) dots
-    with constant weight matrices W_half[l, k] = [l//32 == k] * 2^(l%16),
-    one for the low 16 bits of each word and one for the high 16. Every value
+
+def _split(x, n: int):
+    """``(x // n, x % n)`` of non-negative int32 ``x`` by a power of two: a
+    shift and a mask (``//`` and ``%`` each trace, lower and run a sign
+    fix-up: 3 ms of lowering apiece, once a frame)."""
+    return x >> (n.bit_length() - 1), x & (n - 1)
+
+
+def _gather(x, index, axis: int):
+    """``x`` with every element replaced by the one ``index`` names along
+    ``axis`` in its own row (axis 1) or column (axis 0): what
+    ``jnp.take_along_axis`` lowers to, without its pass over the indices
+    (they are in bounds) — one XLU gather a vreg in Mosaic."""
+    other = 1 - axis
+    dnums = jax.lax.GatherDimensionNumbers(
+        offset_dims=(),
+        collapsed_slice_dims=(axis,),
+        start_index_map=(axis,),
+        operand_batching_dims=(other,),
+        start_indices_batching_dims=(other,),
+    )
+    return jax.lax.gather(
+        x, index[..., None], dnums, slice_sizes=(1, 1),
+        mode=jax.lax.GatherScatterMode.PROMISE_IN_BOUNDS,
+    )
+
+
+def _spread_words(bits, spread_ref) -> None:
+    """First half of the pack: (rows, 128) bool -> ``spread_ref`` i32[rows,
+    128] with row r's word ``L % 4`` (LSB-first over its 32 lanes) at EVERY
+    lane L, so that the word is already at the lane the words array wants it
+    at, ``4 (r % 32) + L % 4``, whatever r is.
+
+    The lane-group reduction runs on the MXU (Mosaic has no unsigned
+    reduction, and a lane-splitting reshape, (rows, 128) -> (rows, 4, 32),
+    fails "unsupported shape cast"): two (rows, 128) x (128, 128) dots with
+    the constant weights W_half[l, L] = [l // 32 == L % 4] * 2^(l % 16), one
+    for the low 16 bits of each word and one for the high 16. Every value
     stays <= 65535, so the f32 dot is exact; the halves are recombined with
-    integer shifts.
+    integer shifts. A weight tile is 128 columns wide whether 4 or 128 of
+    them are used, so the dots cost what the (128, 4) weights cost.
     """
-    rows = bits_i32.shape[0]
-    lane = jax.lax.broadcasted_iota(jnp.int32, (LANES, WORDS_PER_ROW), 0)
-    word = jax.lax.broadcasted_iota(jnp.int32, (LANES, WORDS_PER_ROW), 1)
-    in_word = lane // BITS_PER_WORD == word
-    e = lane % BITS_PER_WORD  # bit position within the word, 0..31
-    w_lo = jnp.where(in_word & (e < 16), _exact_pow2(e % 16), 0.0)
-    w_hi = jnp.where(in_word & (e >= 16), _exact_pow2(e % 16), 0.0)
-    bits_f = bits_i32.astype(jnp.float32)
+    # the bit's lane: its word, its position in the word (0..31)
+    bit_word, e = _split(_iota((LANES, LANES), 0), BITS_PER_WORD)
+    _, word = _split(_iota((LANES, LANES), 1), WORDS_PER_ROW)  # the output lane's
+    in_word = bit_word == word
+    w_lo = jnp.where(in_word & (e < 16), _exact_pow2(e & 15), 0.0)
+    w_hi = jnp.where(in_word & (e >= 16), _exact_pow2(e & 15), 0.0)
+    bits_f = bits.astype(jnp.float32)
     lo = jnp.dot(bits_f, w_lo, preferred_element_type=jnp.float32)
     hi = jnp.dot(bits_f, w_hi, preferred_element_type=jnp.float32)
-    words_i32 = lo.astype(jnp.int32) | (hi.astype(jnp.int32) << 16)
-    return jax.lax.bitcast_convert_type(words_i32, jnp.uint32)
+    spread_ref[...] = lo.astype(jnp.int32) | (hi.astype(jnp.int32) << 16)
 
 
-def _unpack_rows(words_u32):
-    """(rows, 4) uint32 -> (rows, 128) 0/1 int32 (inverse of _pack_rows).
+def _pack_rows(spread_ref, words_ref) -> None:
+    """Second half of the pack: ``spread_ref`` i32[rows, 128] (see
+    :func:`_spread_words`) -> ``words_ref`` u32[rows / 32, 128], words row R
+    lane L = the word of table row ``32 R + L // 4`` that ``spread_ref``
+    holds at that row's lane L: the diagonal of each 32-row group.
 
-    The lane replication (lane l <- word[l//32]) must stay in integer domain:
-    an MXU dot would round its f32 inputs to bf16 and corrupt word values
-    above 2^8. Each word column is lane-broadcast to its 32 lanes and the
-    four spans concatenated; bit extraction is then shift+mask in int32
-    (`& 1` discards arithmetic-shift sign extension).
-    """
-    rows = words_u32.shape[0]
-    words = jax.lax.bitcast_convert_type(words_u32, jnp.int32)
-    wrep = jnp.concatenate(
-        [
-            jnp.broadcast_to(words[:, k : k + 1], (rows, BITS_PER_WORD))
-            for k in range(WORDS_PER_ROW)
-        ],
-        axis=1,
-    )
-    shift = jax.lax.broadcasted_iota(jnp.int32, (rows, LANES), 1) % BITS_PER_WORD
-    return (wrep >> shift) & jnp.int32(1)
+    Lane L's row lies in the group's vreg ``L // 32`` at sublane ``(L % 32)
+    // 4``: three selects by lane pick the vreg, one sublane gather (XLU)
+    picks the row; integer moves only, no arithmetic touches a word. One
+    loop trip a words row, one traced body."""
+    vreg, in_vreg = _split(_iota((8, LANES), 1), 8 * WORDS_PER_ROW)
+    sublane, _ = _split(in_vreg, WORDS_PER_ROW)
+
+    def one(r, carry):
+        base = pl.multiple_of(r * ROWS_PER_WORDS_ROW, ROWS_PER_WORDS_ROW)
+        v = spread_ref[pl.ds(base, 8), :]
+        for j in range(1, ROWS_PER_WORDS_ROW // 8):
+            v = jnp.where(vreg == j, spread_ref[pl.ds(base + 8 * j, 8), :], v)
+        row = _gather(v, sublane, 0)[0:1]
+        words_ref[pl.ds(r, 1), :] = jax.lax.bitcast_convert_type(row, jnp.uint32)
+        return carry
+
+    jax.lax.fori_loop(0, words_ref.shape[0], one, 0)
+
+
+def _unpack_rows(words_i32, rows: int):
+    """(rows / 32, 128) int32 words rows -> (rows, 128) 0/1 int32: table row
+    ``32 R + q`` lane l = bit ``l % 32`` of words row R's lane ``4 q + l //
+    32`` (inverse of :func:`_pack_rows`).
+
+    A words row reaches its 32 table rows by a sublane broadcast and each
+    word its 32 lanes by one lane gather a vreg (XLU; the (rows, 4) layout
+    took four lane broadcasts a vreg), then shift and mask: all in the
+    integer domain (an MXU dot would round its f32 inputs to bf16 and
+    corrupt word values above 2^8; `& 1` discards the arithmetic shift's
+    sign extension)."""
+    _, q = _split(_iota((rows, LANES), 0), ROWS_PER_WORDS_ROW)
+    k, bit = _split(_iota((rows, LANES), 1), BITS_PER_WORD)
+    wrep = jnp.repeat(words_i32, ROWS_PER_WORDS_ROW, axis=0)
+    return (_gather(wrep, WORDS_PER_ROW * q + k, 1) >> bit) & jnp.int32(1)
 
 
 class LeafTables(NamedTuple):
@@ -174,7 +248,9 @@ class LeafTables(NamedTuple):
 def _count_trace(kernel: str, tables: LeafTables) -> None:
     from ..utils.profiling import pod_tier
 
-    pod_tier().count_codec_kernel_trace(kernel, tables.leaves_max)
+    pod_tier().count_codec_kernel_trace(
+        kernel, tables.leaves_max, tables.block // ROWS_PER_WORDS_ROW
+    )
 
 
 def _on_block_leaves(lo_ref, first_ref, last_ref, rows: int, body) -> None:
@@ -209,15 +285,17 @@ def _on_block_leaves(lo_ref, first_ref, last_ref, rows: int, body) -> None:
 
 
 def _quantize_rows_kernel(
-    s_ref, lo_ref, end_ref, first_ref, last_ref, resid_ref, words_ref, new_resid_ref
+    s_ref, lo_ref, end_ref, first_ref, last_ref,
+    resid_ref, words_ref, new_resid_ref, spread_ref,
 ):
     def body(flat, off, at):
         s = at(lambda j: s_ref[j])
+        # rows past the window's end (the last block's tail) lie past every
+        # leaf's live end too, so their bits are 0 like any padding's
         live = flat < at(lambda j: end_ref[j] - off)
         r = resid_ref[...]  # (block, LANES)
         neg = r <= 0.0  # bit set => send -scale (zero counts as negative, Q3)
-        bits = jnp.logical_and(live, neg)
-        words_ref[...] = _pack_rows(bits.astype(jnp.int32))
+        _spread_words(jnp.logical_and(live, neg), spread_ref)
         sent = jnp.where(neg, -s, s)
         # rows whose leaf idles at scale 0 keep their residual; padding lanes
         # are forced back to 0 (the ops/table.py invariant, bit-for-bit)
@@ -226,16 +304,18 @@ def _quantize_rows_kernel(
         )
 
     _on_block_leaves(lo_ref, first_ref, last_ref, resid_ref.shape[0], body)
+    _pack_rows(spread_ref, words_ref)
 
 
-def _row_spec(block: int, width: int) -> pl.BlockSpec:
+def _row_spec(block: int) -> pl.BlockSpec:
     # index maps of a scalar-prefetch grid also receive the prefetched refs
-    return pl.BlockSpec((block, width), lambda i, *_: (i, 0), memory_space=pltpu.VMEM)
+    return pl.BlockSpec((block, LANES), lambda i, *_: (i, 0), memory_space=pltpu.VMEM)
 
 
 def quantize_block_rows(rows: int) -> int:
-    """Rows per grid step of quantize_rows."""
-    return min(BLOCK_ROWS, rows)
+    """Rows per grid step of quantize_rows: whole words rows, so a multiple
+    of 32 (a block may reach past a short table's end)."""
+    return min(BLOCK_ROWS, words_rows(rows) * ROWS_PER_WORDS_ROW)
 
 
 def quantize_rows(
@@ -246,43 +326,55 @@ def quantize_rows(
 
     ``scales`` f32[k] (one a leaf) and ``tables`` (cut for
     :func:`quantize_block_rows`) go to scalar memory; ``residual``
-    f32[rows*128] flat is the only streamed input. Returns (words u32[rows*4]
-    flat, new_residual flat). Traceable — callers jit. Bit-for-bit equal to
-    its XLA twin in ops/table.py.
+    f32[rows*128] flat is the only streamed input. Returns (words
+    u32[words_rows(rows), 128], new_residual flat). Traceable — callers jit.
+    Bit-for-bit equal to its XLA twin in ops/table.py.
     """
     rows = residual.shape[0] // LANES
     block = tables.block
     _count_trace("quantize_rows", tables)
-    words2d, new_resid = pl.pallas_call(
+    words, new_resid = pl.pallas_call(
         _quantize_rows_kernel,
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=5,
             grid=(pl.cdiv(rows, block),),
-            in_specs=[_row_spec(block, LANES)],
-            out_specs=[_row_spec(block, WORDS_PER_ROW), _row_spec(block, LANES)],
+            in_specs=[_row_spec(block)],
+            out_specs=[_row_spec(block // ROWS_PER_WORDS_ROW), _row_spec(block)],
+            scratch_shapes=[pltpu.VMEM((block, LANES), jnp.int32)],
         ),
         out_shape=[
-            jax.ShapeDtypeStruct((rows, WORDS_PER_ROW), jnp.uint32),
+            jax.ShapeDtypeStruct((words_rows(rows), LANES), jnp.uint32),
             jax.ShapeDtypeStruct((rows, LANES), jnp.float32),
         ],
         input_output_aliases={5: 1},
         interpret=_interpret(),
         name="st_quantize_rows",
     )(scales, *tables.scalars(), residual.reshape(rows, LANES))
-    return words2d.reshape(-1), new_resid.reshape(-1)
+    return words, new_resid.reshape(-1)
 
 
 def _apply_rows_kernel(
     s_ref, lo_ref, end_ref, first_ref, last_ref, words_ref, *refs, k_frames, n_arrays
 ):
+    unrolled = max(u for u in range(1, _FRAMES_UNROLLED + 1) if k_frames % u == 0)
+
     def body(flat, off, at):
         live = flat < at(lambda j: end_ref[j] - off)
-        delta = jnp.zeros(flat.shape, jnp.float32)
-        for kf in range(k_frames):
-            w = words_ref[:, kf * WORDS_PER_ROW : (kf + 1) * WORDS_PER_ROW]
-            bits = _unpack_rows(w)
+
+        def frame(kf, delta):
+            w = jax.lax.bitcast_convert_type(words_ref[kf], jnp.int32)
             s = at(lambda j: s_ref[kf, j])
-            delta = delta + s * (1.0 - 2.0 * bits.astype(jnp.float32))
+            bits = _unpack_rows(w, flat.shape[0])
+            return delta + s * (1.0 - 2.0 * bits.astype(jnp.float32))
+
+        def group(g, delta):
+            return jax.lax.fori_loop(
+                0, unrolled, lambda u, d: frame(g * unrolled + u, d), delta, unroll=True
+            )
+
+        delta = jax.lax.fori_loop(
+            0, k_frames // unrolled, group, jnp.zeros(flat.shape, jnp.float32)
+        )
         delta = jnp.where(live, delta, 0.0)
         in_refs, out_refs = refs[:n_arrays], refs[n_arrays:]
         for i_ref, o_ref in zip(in_refs, out_refs):
@@ -290,41 +382,35 @@ def _apply_rows_kernel(
                 live, jnp.clip(i_ref[...] + delta, -SAT, SAT), 0.0
             )
 
-    _on_block_leaves(lo_ref, first_ref, last_ref, words_ref.shape[0], body)
+    _on_block_leaves(lo_ref, first_ref, last_ref, refs[0].shape[0], body)
 
 
 #: VMEM the apply kernel may plan for: 14 of the 16 MiB a Mosaic kernel gets
 #: by default on a v5e ("Scoped allocation with size ... and limit 16.00M").
 _APPLY_VMEM_BUDGET = 14 << 20
-#: One (1, 128) row of any 32-bit operand or temporary in VMEM. A narrower
-#: block — the (block, 4K) words — is padded to whole 128-lane rows there,
-#: so it costs the same.
+#: One (1, 128) row of any 32-bit operand or temporary in VMEM.
 _ROW_BYTES = LANES * 4
 
 
 def apply_block_rows(rows: int, k_frames: int, n_arrays: int) -> int:
-    """Rows per grid step of apply_rows_batch: BLOCK_ROWS at K = 1; half as
-    many from K = 2 on, where the unrolled unpack and not the DMAs sets the
-    pace and a larger block's temporaries spill (K = 4 on a v5e: 14.07 ms at
-    512 rows, 14.68 at 1024); fewer when K frames would not fit VMEM. Counted
-    per block row, in lane-padded 128-lane rows: every streamed operand twice
-    (the pipeline double-buffers) — ceil(4K/128) rows of words, N arrays in
-    and N out — plus 4 rows per frame for what the unrolled frame loop keeps
-    live (word broadcast, bits, the frame's scale over the block, running
-    delta; Mosaic does not reuse them across iterations) plus 4 for the
-    epilogue (tests/test_tpu_compile.py compiles K = 1, 8, 16, 64 without a
-    chip)."""
-    words_rows = -(-k_frames * WORDS_PER_ROW // LANES)
-    per_row = _ROW_BYTES * (2 * (words_rows + 2 * n_arrays) + 4 * k_frames + 4)
-    fit = max(8, _APPLY_VMEM_BUDGET // per_row // 8 * 8)
-    most = BLOCK_ROWS if k_frames == 1 else BLOCK_ROWS // 2
-    return min(most, rows, fit)
+    """Rows per grid step of apply_rows_batch: :func:`quantize_block_rows`'
+    unless K frames and N arrays would not fit VMEM. Counted per block row,
+    in 128-lane rows: every streamed operand twice (the pipeline
+    double-buffers) — K / 32 rows of words, N arrays in and N out — plus 12
+    for the temporaries (element index, live lanes, the running delta, one
+    group of frames' gather index, words, bits and scale over the block).
+    The frame loop keeps them from growing with K. A smaller block is whole
+    8-row tiles of words, 256 rows (tests/test_tpu_compile.py compiles K =
+    1, 4, 8, 16, 64 without a chip)."""
+    per_row = _ROW_BYTES * (2 * 2 * n_arrays + 12) + 2 * 4 * WORDS_PER_ROW * k_frames
+    fit = max(256, _APPLY_VMEM_BUDGET // per_row // 256 * 256)
+    return min(quantize_block_rows(rows), fit)
 
 
 def apply_rows_batch(
     scales: jnp.ndarray,
     tables: LeafTables,
-    words2d: jnp.ndarray,
+    words: jnp.ndarray,
     arrays: tuple[jnp.ndarray, ...],
 ) -> tuple[jnp.ndarray, ...]:
     """Fused receive pass for K frames x N target arrays: the frames are
@@ -335,26 +421,33 @@ def apply_rows_batch(
     ``scales`` f32[K, k] — per frame, per leaf (a frame's entry is 0 where it
     contributes nothing: idle leaves, split-horizon self-masking in
     parallel/ici.py) — and ``tables`` (cut for :func:`apply_block_rows`) go
-    to scalar memory; ``words2d`` u32[rows, K*4] — frame k's packed bits for
-    row r at [r, 4k:4k+4]; ``arrays`` flat f32[rows*128] each.
+    to scalar memory; ``words`` u32[K, words_rows(rows), 128] — the frames'
+    words arrays as :func:`quantize_rows` writes them and an all-gather
+    stacks them, a grid step taking ``(K, block / 32, 128)``; ``arrays``
+    flat f32[rows*128] each.
     """
     rows = arrays[0].shape[0] // LANES
     k = scales.shape[0]
     n_arr = len(arrays)
     block = tables.block
     _count_trace("apply_rows_batch", tables)
-    vspec = _row_spec(block, LANES)
+    vspec = _row_spec(block)
+    words_spec = pl.BlockSpec(
+        (k, block // ROWS_PER_WORDS_ROW, LANES),
+        lambda i, *_: (0, i, 0),
+        memory_space=pltpu.VMEM,
+    )
     outs = pl.pallas_call(
         partial(_apply_rows_kernel, k_frames=k, n_arrays=n_arr),
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=5,
             grid=(pl.cdiv(rows, block),),
-            in_specs=[_row_spec(block, k * WORDS_PER_ROW)] + [vspec] * n_arr,
+            in_specs=[words_spec] + [vspec] * n_arr,
             out_specs=[vspec] * n_arr,
         ),
         out_shape=[jax.ShapeDtypeStruct((rows, LANES), jnp.float32)] * n_arr,
         input_output_aliases={6 + i: i for i in range(n_arr)},
         interpret=_interpret(),
         name="st_apply_rows_batch",
-    )(scales, *tables.scalars(), words2d, *[a.reshape(rows, LANES) for a in arrays])
+    )(scales, *tables.scalars(), words, *[a.reshape(rows, LANES) for a in arrays])
     return tuple(o.reshape(-1) for o in outs)

@@ -22,6 +22,8 @@ LANES = 128
 SUBLANES = 8
 TILE = LANES * SUBLANES  # 1024
 BITS_PER_WORD = 32
+#: uint32 words that pack one 128-lane row of sign bits.
+WORDS_PER_ROW = LANES // BITS_PER_WORD  # 4
 
 
 def padded_len(n: int, multiple: int = TILE) -> int:
@@ -46,6 +48,32 @@ def unpack_bits(words: jnp.ndarray) -> jnp.ndarray:
     shifts = jnp.arange(BITS_PER_WORD, dtype=jnp.uint32)
     bits = (words[..., :, None] >> shifts) & jnp.uint32(1)
     return bits.reshape(*words.shape[:-1], -1).astype(jnp.int32)
+
+
+def words_rows(rows: int) -> int:
+    """Rows of the packed words of ``rows`` 128-lane table rows in the form
+    the row codec carries them on the device, ``u32[words_rows, 128]``: the
+    flat word vector 128 words a row, so the four words of 32 consecutive
+    table rows fill one row and the array is dense in HBM (a ``u32[rows, 4]``
+    is lane-padded 32x there)."""
+    return -(-rows * WORDS_PER_ROW // LANES)
+
+
+def dense_words(flat: jnp.ndarray, rows: int) -> jnp.ndarray:
+    """The flat word vector ``u32[..., rows * 4]`` -> ``u32[...,
+    words_rows(rows), 128]``: a bitcast where ``rows`` is a multiple of 32,
+    zero pad words behind the last row's else."""
+    pad = words_rows(rows) * LANES - flat.shape[-1]
+    if pad:
+        flat = jnp.pad(flat, [(0, 0)] * (flat.ndim - 1) + [(0, pad)])
+    return flat.reshape(*flat.shape[:-1], words_rows(rows), LANES)
+
+
+def flat_words(words: jnp.ndarray, rows: int) -> jnp.ndarray:
+    """Inverse of :func:`dense_words`: ``u32[..., words_rows(rows), 128]`` ->
+    the flat word vector ``u32[..., rows * 4]`` of the wire layer."""
+    flat = words.reshape(*words.shape[:-2], -1)
+    return flat[..., : rows * WORDS_PER_ROW]
 
 
 def words_to_wire(words: np.ndarray, n: int) -> bytes:

@@ -32,7 +32,15 @@ import numpy as np
 
 from ..config import ScalePolicy
 from .codec import SAT, pad_flat, pow2_floor
-from .packing import LANES, TILE, pack_bits, padded_len, unpack_bits
+from .packing import (
+    LANES,
+    TILE,
+    dense_words,
+    flat_words,
+    pack_bits,
+    padded_len,
+    unpack_bits,
+)
 
 
 # (start, stop) rows of each leaf, in leaf order
@@ -245,14 +253,16 @@ class LeafRows:
     def _rowcounts(self) -> np.ndarray:
         return _live_rowcount(self.ranges, self.ns).reshape(self.n_windows, self.rows)
 
-    def tables(self, block: int, window=None):
-        """The window's :class:`~.codec_pallas.LeafTables` for kernels that
-        step through it ``block`` rows at a time: each leaf's first element
-        and live end, flat within the window and clipped to it, and per block
-        the first and the last leaf it meets; k + k + 2 x blocks scalars,
-        picked from static ``[n_windows, ...]`` tables by ``window``."""
-        from .codec_pallas import LeafTables
+    @functools.cached_property
+    def _block_tables(self) -> dict[int, tuple]:
+        """``block`` -> :meth:`_cut_tables` of it: cut once a block size for
+        the life of this object (a built sync step's), whichever kernel or
+        trace asks."""
+        return {}
 
+    def _cut_tables(self, block: int) -> tuple:
+        """(leaves_max, lo, end, first, last) of :meth:`tables`, for every
+        window: static ``[n_windows, ...]`` int32 tables."""
         starts, stops = (np.asarray(x, np.int64) for x in zip(*self.ranges))
         edges = np.arange(0, self.rows, block)
         lo, end, first, last = [], [], [], []
@@ -266,11 +276,20 @@ class LeafRows:
                 np.searchsorted(ca, np.minimum(edges + block, self.rows), side="left") - 1
             )
         leaves_max = int((np.asarray(last) - np.asarray(first)).max()) + 1
-        return LeafTables(
-            block,
-            leaves_max,
-            *(self._pick(np.asarray(t, np.int32), window) for t in (lo, end, first, last)),
-        )
+        return leaves_max, *(np.asarray(t, np.int32) for t in (lo, end, first, last))
+
+    def tables(self, block: int, window=None):
+        """The window's :class:`~.codec_pallas.LeafTables` for kernels that
+        step through it ``block`` rows at a time: each leaf's first element
+        and live end, flat within the window and clipped to it, and per block
+        the first and the last leaf it meets; k + k + 2 x blocks scalars,
+        picked from static ``[n_windows, ...]`` tables by ``window``."""
+        from .codec_pallas import LeafTables
+
+        if block not in self._block_tables:
+            self._block_tables[block] = self._cut_tables(block)
+        leaves_max, *tabs = self._block_tables[block]
+        return LeafTables(block, leaves_max, *(self._pick(t, window) for t in tabs))
 
     def one_a_leaf(self, scales: jnp.ndarray) -> jnp.ndarray:
         """``scales`` [..., k] as they are, or a single global scale [..., 1]
@@ -343,8 +362,12 @@ def resolve_impl(impl: str) -> str:
 # (``LeafRows.expand`` / ``rowcount``), and the kernels of
 # ops/codec_pallas.py get ``LeafRows.tables`` in scalar memory and derive a
 # block's per-row scale and live lanes themselves, so no per-row operand is
-# built, stored or streamed for them. ``impl`` is a resolved tier ("pallas"
-# or "xla", :func:`resolve_impl`).
+# built, stored or streamed for them. The packed words pass between the two
+# passes (and through an all-gather) 128 words a row, ``u32[words_rows,
+# 128]`` (ops/packing.py ``dense_words``: the wire's flat word vector,
+# bitcast): the sender pass writes that array, the receiver pass takes K of
+# them stacked, and nothing relays them out in between. ``impl`` is a
+# resolved tier ("pallas" or "xla", :func:`resolve_impl`).
 
 
 def live_lanes(rowcount: jnp.ndarray) -> jnp.ndarray:
@@ -396,9 +419,11 @@ def quantize_rows(
 ) -> tuple[jnp.ndarray, jnp.ndarray]:
     """Sender pass: sign-quantize + LSB-first pack + error feedback with a
     scale per leaf. ``scales`` f32[k] (or the one global scale, f32[1]),
-    ``residual`` f32[rows*128] flat (the
-    rows of ``window``) -> (words u32[rows*4], residual'). Bit set iff
-    r <= 0; a leaf at scale 0 idles; padding lanes are forced to 0."""
+    ``residual`` f32[rows*128] flat (the rows of ``window``) -> (words
+    u32[words_rows(rows), 128], residual'): the flat word vector 128 words a
+    row (``packing.flat_words`` gives the wire's ``u32[rows*4]`` back). Bit
+    set iff r <= 0; a leaf at scale 0 idles; padding lanes are forced to 0,
+    pad words too."""
     with jax.named_scope("st.quantize"):
         scales = leaves.one_a_leaf(scales)
         if impl == "pallas":
@@ -414,7 +439,8 @@ def quantize_rows(
         neg = r <= 0.0
         sent = jnp.where(neg, -s, s)
         r2 = jnp.where(live & (s > 0), r - sent, jnp.where(live, r, 0.0))
-        return pack_bits(jnp.logical_and(live, neg).reshape(-1)), r2.reshape(-1)
+        words = pack_bits(jnp.logical_and(live, neg).reshape(-1))
+        return dense_words(words, leaves.rows), r2.reshape(-1)
 
 
 def apply_rows(
@@ -429,34 +455,29 @@ def apply_rows(
     pure adds, so they commute) added to every array in one pass, clamped to
     +/-codec.SAT, padding lanes forced to 0. ``scales`` f32[K, k] or, one
     global scale a frame, f32[K, 1] (a frame's entry is 0 where it
-    contributes nothing), ``words`` u32[K, rows*4],
-    ``arrays`` flat f32[rows*128] each (the rows of ``window``). Owns the
-    kernel's operand layout."""
+    contributes nothing), ``words`` u32[K, words_rows(rows), 128] (K frames'
+    words as :func:`quantize_rows` returns them, stacked: what an all-gather
+    gives, and the kernel's operand as it arrives; ``packing.dense_words``
+    makes it of the wire's ``u32[K, rows*4]``), ``arrays`` flat
+    f32[rows*128] each (the rows of ``window``)."""
     k, rows = scales.shape[0], leaves.rows
     scales = leaves.one_a_leaf(scales)
-    if impl == "pallas":
-        from . import codec_pallas
+    with jax.named_scope("st.apply"):
+        if impl == "pallas":
+            from . import codec_pallas
 
-        with jax.named_scope("st.words_layout"):
-            # frame k's words for row r at [r, 4k:4k+4]
-            words2d = (
-                words.reshape(k, rows, LANES // 32)
-                .transpose(1, 0, 2)
-                .reshape(rows, k * (LANES // 32))
-            )
-        with jax.named_scope("st.apply"):
             block = codec_pallas.apply_block_rows(rows, k, len(arrays))
             return codec_pallas.apply_rows_batch(
-                scales, leaves.tables(block, window), words2d, arrays
+                scales, leaves.tables(block, window), words, arrays
             )
-    with jax.named_scope("st.words_layout"):
-        bits = unpack_bits(words).reshape(k, rows, LANES).astype(jnp.float32)
-    with jax.named_scope("st.apply"):
+        bits = unpack_bits(flat_words(words, rows)).reshape(k, rows, LANES)
         live = live_lanes(leaves.rowcount(window))
         s_rows = leaves.expand(scales, window)  # (K, rows)
         # elementwise + sum on the VPU: under the RMS policy a scale is
         # arbitrary, so the arithmetic stays exact f32, no MXU
-        delta = jnp.sum(s_rows[:, :, None] * (1.0 - 2.0 * bits), axis=0)
+        delta = jnp.sum(
+            s_rows[:, :, None] * (1.0 - 2.0 * bits.astype(jnp.float32)), axis=0
+        )
         return tuple(
             jnp.where(
                 live, jnp.clip(a.reshape(rows, LANES) + delta, -SAT, SAT), 0.0
@@ -498,7 +519,7 @@ def _quantize_table(
     leaves = LeafRows.of(spec)
     scales = _table_scales(residual, leaves, policy, per_leaf)
     words, new_flat = quantize_rows(scales, leaves, None, residual, impl)
-    return TableFrame(scales, words), new_flat
+    return TableFrame(scales, flat_words(words, leaves.rows)), new_flat
 
 
 def quantize_table(
@@ -562,11 +583,12 @@ def _apply_table_batch(
     arrays: tuple[jnp.ndarray, ...], frames: TableFrame, spec: TableSpec, impl: str
 ) -> tuple[jnp.ndarray, ...]:
     # one frame (scales [L], words [W]) is the K = 1 stack
+    leaves = LeafRows.of(spec)
     return apply_rows(
         jnp.atleast_2d(frames.scales),
-        LeafRows.of(spec),
+        leaves,
         None,
-        jnp.atleast_2d(frames.words),
+        dense_words(jnp.atleast_2d(frames.words), leaves.rows),
         arrays,
         impl,
     )

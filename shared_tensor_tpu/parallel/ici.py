@@ -245,14 +245,17 @@ def _codec_send(ctx: _StepCtx, policy: ScalePolicy, impl: str, residual):
     source of truth for both the fused step (build_sync_step) and the
     overlap phases (build_sync_phases).
 
-    Returns (new_residual [flat], words_all [n_peer, W_local],
-    scales_all [n_peer, k], scales_local [k])."""
+    Returns (new_residual [flat], words_all [n_peer, words_rows, 128] (each
+    peer's packed words as ops.table.quantize_rows returns them: the flat
+    word vector 128 words a row, dense in HBM; gathered in that shape, which
+    ops.table.apply_rows takes as it arrives), scales_all [n_peer, k],
+    scales_local [k])."""
     with jax.named_scope("st.codec_send"):
         r = residual.reshape(ctx.rows_local, LANES)
         scales = _leaf_scales(ctx, r, ctx.live(), policy)
         words, r2 = quantize_rows(scales, ctx.leaves, ctx.window(), residual, impl)
         with jax.named_scope("st.allgather"):
-            words_all = jax.lax.all_gather(words, ctx.peer_ax)  # (n_peer, W_local)
+            words_all = jax.lax.all_gather(words, ctx.peer_ax)  # (n_peer, words_rows, 128)
             scales_all = jax.lax.all_gather(scales, ctx.peer_ax)  # (n_peer, k)
     return r2, words_all, scales_all, scales
 
@@ -381,7 +384,9 @@ def build_sync_phases(
     Composing ``apply_gathered(values, *send(residual)[1:])`` immediately is
     bit-for-bit ``build_sync_step`` (tests pin this).
 
-    Shapes: ``words_all`` u32[n_peer, total//32] sharded over the shard axis;
+    Shapes: ``words_all`` u32[n_peer, n_shard * words_rows, 128] (a shard's
+    packed words 128 a row, ops.packing.words_rows of its rows) sharded over
+    the shard axis;
     ``scales_all`` f32[n_peer, num_leaves] replicated (row p = the scales
     peer p transmitted — the same observability surface as build_sync_step).
     """

@@ -142,6 +142,14 @@ class PodTier:
         self._leaves_per_block = instrument(
             self.registry.gauge, "st_codec_leaves_per_block_max"
         )
+        self._words_rows = {kernel: 0 for kernel in self._codec_traces}
+        words_keys = {
+            kernel: label_key("st_codec_words_rows_per_block", "kernel", kernel)
+            for kernel in self._words_rows
+        }
+        self.registry.register_collector(
+            lambda: {words_keys[k]: n for k, n in self._words_rows.items()}
+        )
 
     def watch(self, trainer) -> None:
         """The trainer whose ``aux`` the expert layers' gauges read (the
@@ -192,13 +200,18 @@ class PodTier:
         with self._mu:
             self._combine_traces[path] += 1
 
-    def count_codec_kernel_trace(self, kernel: str, leaves_per_block: int) -> None:
+    def count_codec_kernel_trace(
+        self, kernel: str, leaves_per_block: int, words_rows_per_block: int
+    ) -> None:
         """One traced call of a codec kernel of ``ops/codec_pallas.py``
-        (``quantize_rows`` or ``apply_rows_batch``) and the most leaves one
-        of its grid blocks meets: the worst trip count of the kernel's loop
-        over leaves. Traces, not steps."""
+        (``quantize_rows`` or ``apply_rows_batch``), the most leaves one
+        of its grid blocks meets (the worst trip count of the kernel's loop
+        over leaves) and the 128-lane rows of packed words a grid step
+        takes (32 at a block of 1 024 table rows: the dense words layout
+        at that block). Traces, not steps."""
         with self._mu:
             self._codec_traces[kernel] += 1
+            self._words_rows[kernel] = words_rows_per_block
         self._leaves_per_block.set(leaves_per_block)
 
     def _on_duration(self, event: str, seconds: float, **_kw) -> None:

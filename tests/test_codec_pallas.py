@@ -48,8 +48,17 @@ def _apply_many(arrays, frame, n):
     return apply_table_many(arrays, tframe, _spec(n), impl="pallas")
 
 
-@pytest.mark.parametrize("n", [17, 240, 1024, 4096, 40000])
+#: 1048 rows, 24 (mod 256) and (mod 32): a last grid block of less than one
+#: words row; 1288 rows, 8 (mod 32): a last words row of 8 table rows and 96
+#: lanes of pad bits, in a last block of 264 rows
+_ROWS_24_MOD_256 = 1048 * 128 - 7
+_ROWS_8_MOD_32 = 1288 * 128 - 3
+
+
+@pytest.mark.parametrize("n", [17, 240, 1024, 4096, 40000, _ROWS_24_MOD_256, _ROWS_8_MOD_32])
 def test_quantize_parity(n):
+    """The wire's flat word vector is the golden codec's, bit for bit,
+    whatever the kernel's own layout of the words is."""
     r = _rand_resid(n, n)
     frame_g, resid_g = codec.quantize(jnp.asarray(r), n)
     frame_p, resid_p = _quantize(r, n)
@@ -90,7 +99,7 @@ def test_quantize_zero_residual_parity():
     np.testing.assert_array_equal(np.asarray(resid_p), 0.0)
 
 
-@pytest.mark.parametrize("n", [17, 1024, 40000])
+@pytest.mark.parametrize("n", [17, 1024, 40000, _ROWS_24_MOD_256, _ROWS_8_MOD_32])
 def test_apply_parity(n):
     r = _rand_resid(n, n + 1)
     v = _rand_resid(n, n + 2)
@@ -126,3 +135,36 @@ def test_link_convergence_with_pallas():
         (v,) = apply_table_many((v,), frame, spec, impl="pallas")
     assert float(jnp.max(jnp.abs(r))) == 0.0
     np.testing.assert_allclose(np.asarray(v), target, rtol=0, atol=1.5e-7)
+
+
+def _equations(jaxpr):
+    """Equations of a jaxpr and of every jaxpr inside it."""
+    from jax import core
+
+    return sum(
+        1 + sum(_equations(sub) for sub in core.jaxprs_in_params(eqn.params))
+        for eqn in jaxpr.eqns
+    )
+
+
+def test_apply_kernel_trace_does_not_grow_with_frames():
+    """The set-up guard (PERF.md section 6, PR 36 and PR 37): the apply
+    kernel's traced size is the frame loop's one body, not K copies of it.
+    At K = 16 (two groups of eight frames) the traced call holds no more
+    than 2 times the equations it holds at K = 1."""
+    import jax
+
+    from shared_tensor_tpu.ops.table import _apply_table_batch
+
+    spec = _spec(3000 * 128)
+    counts = {}
+    for k in (1, 16):
+        frames = TableFrame(
+            jnp.ones((k, 1), jnp.float32), jnp.zeros((k, spec.total // 32), jnp.uint32)
+        )
+        jaxpr = jax.make_jaxpr(
+            lambda a, f: _apply_table_batch.__wrapped__((a,), f, spec=spec, impl="pallas")
+        )(jnp.zeros(spec.total, jnp.float32), frames)
+        counts[k] = _equations(jaxpr.jaxpr)
+    assert counts[1] > 50  # the kernel's body is counted, not the call alone
+    assert counts[16] <= 2 * counts[1], counts

@@ -21,6 +21,7 @@ from shared_tensor_tpu.ops.table import (
     live_lanes,
     quantize_table,
 )
+from shared_tensor_tpu.ops.packing import words_rows
 from shared_tensor_tpu.parallel import (
     add_updates,
     build_sync_step,
@@ -57,11 +58,17 @@ def test_mesh_shapes():
     assert mesh.shape == {"peer": 4, "shard": 2}
 
 
-def test_parity_with_golden_codec():
+@pytest.mark.parametrize(
+    "impl,shape", [("xla", (40, 64)), ("pallas", (40, 64)), ("pallas", (1290, 128))]
+)
+def test_parity_with_golden_codec(impl, shape):
     """One pod step == per-peer golden quantize + cross-apply of every other
-    peer's frame, bit-for-bit (n_shard=1)."""
+    peer's frame, bit-for-bit (n_shard=1), on either tier of the row codec:
+    the words a peer gathers (128 a row, as the kernels write and read them)
+    are the golden codec's flat vector. (1290, 128) is 1304 rows, 24 (mod
+    32) and (mod 256): two grid blocks, the last words row 24 table rows."""
     mesh = make_mesh(2, 1)
-    tpl = template()
+    tpl = template(shape=shape)
     spec = make_spec(tpl)
     state = init_state(mesh, spec, tpl)
     # give each peer a distinct pending update
@@ -73,7 +80,7 @@ def test_parity_with_golden_codec():
     v0 = np.asarray(state.values)
     r0 = np.asarray(state.residual)
 
-    step = build_sync_step(mesh, spec)
+    step = build_sync_step(mesh, spec, impl=impl)
     state2, scales = jax.block_until_ready(step(state))
     # golden: quantize each peer's residual, apply to the *other* peer
     frames, resids = [], []
@@ -244,8 +251,11 @@ def test_global_scale_mode():
         np.testing.assert_array_equal(np.asarray(scales[p]), np.asarray(f.scales)[:1])
 
 
-@pytest.mark.parametrize("n_peer,n_shard", [(1, 1), (4, 1), (4, 2)])
-def test_sync_phases_compose_to_sync_step(n_peer, n_shard):
+@pytest.mark.parametrize(
+    "n_peer,n_shard,impl",
+    [(1, 1, "auto"), (4, 1, "auto"), (4, 2, "auto"), (4, 1, "pallas"), (2, 2, "pallas")],
+)
+def test_sync_phases_compose_to_sync_step(n_peer, n_shard, impl):
     """build_sync_phases is the fused step split in two: composing
     apply_gathered(values, *send(residual)[1:]) immediately must be
     bit-for-bit build_sync_step (the overlap training mode's correctness
@@ -264,14 +274,17 @@ def test_sync_phases_compose_to_sync_step(n_peer, n_shard):
         ]
     )
     state = add_updates(init_state(mesh, spec, tpl), ups)
-    fused, scales_f = jax.block_until_ready(build_sync_step(mesh, spec)(state))
+    fused, scales_f = jax.block_until_ready(build_sync_step(mesh, spec, impl=impl)(state))
 
     state2 = add_updates(init_state(make_mesh(n_peer, n_shard), spec, tpl), ups)
-    send, apply_gathered = build_sync_phases(mesh, spec)
+    send, apply_gathered = build_sync_phases(mesh, spec, impl=impl)
+    rows_local = spec.total // 128 // n_shard
 
     @jax.jit
     def composed(st):
         r2, words_all, scales_all = send(st.residual)
+        # every peer's packed words, 128 a row, a shard's rows after another's
+        assert words_all.shape == (n_peer, n_shard * words_rows(rows_local), 128)
         v2 = apply_gathered(st.values, words_all, scales_all)
         return v2, r2, scales_all
 

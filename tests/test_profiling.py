@@ -296,7 +296,8 @@ def test_pod_counters_are_in_the_schema_and_the_exposition():
         "st_pod_steps_total", "st_pod_compiles_total", "st_pod_compile_seconds_total",
         "st_pod_cache_load_seconds_total", "st_pod_last_compile_step",
         "st_attn_traces_total", "st_codec_kernel_traces_total",
-        "st_codec_leaves_per_block_max", "st_moe_combine_traces_total",
+        "st_codec_leaves_per_block_max", "st_codec_words_rows_per_block",
+        "st_moe_combine_traces_total",
     )
     text = pod_registry().prometheus_text()
     for name in names:
@@ -308,6 +309,8 @@ def test_pod_counters_are_in_the_schema_and_the_exposition():
     assert 'st_attn_traces_total{path="scan"}' in text
     assert 'st_codec_kernel_traces_total{kernel="quantize_rows"}' in text
     assert 'st_codec_kernel_traces_total{kernel="apply_rows_batch"}' in text
+    assert 'st_codec_words_rows_per_block{kernel="quantize_rows"}' in text
+    assert 'st_codec_words_rows_per_block{kernel="apply_rows_batch"}' in text
     assert 'st_moe_combine_traces_total{path="pallas"}' in text
     assert 'st_moe_combine_traces_total{path="xla"}' in text
     assert pod_registry() is pod_registry()
@@ -315,8 +318,9 @@ def test_pod_counters_are_in_the_schema_and_the_exposition():
 
 def test_traced_sync_step_counts_its_codec_kernels():
     """Tracing ``build_sync_step`` on the kernel tier counts one call of each
-    codec kernel and leaves the most leaves one of their grid blocks meets in
-    the gauge; the XLA tier traces no kernel."""
+    codec kernel and leaves the most leaves one of their grid blocks meets,
+    and the rows of packed words a grid step takes (32 at a block of 1 024
+    table rows), in the gauges; the XLA tier traces no kernel."""
     import jax
 
     from shared_tensor_tpu.obs.schema import label_key
@@ -329,6 +333,8 @@ def test_traced_sync_step_counts_its_codec_kernels():
             [snap[label_key("st_codec_kernel_traces_total", "kernel", k)]
              for k in ("quantize_rows", "apply_rows_batch")],
             snap["st_codec_leaves_per_block_max"],
+            [snap[label_key("st_codec_words_rows_per_block", "kernel", k)]
+             for k in ("quantize_rows", "apply_rows_batch")],
         )
 
     mesh = make_mesh(2, 1)
@@ -336,14 +342,15 @@ def test_traced_sync_step_counts_its_codec_kernels():
     tree = {f"l{i}": jnp.zeros(n) for i, n in enumerate([3, 1000, 70, 1024, 5, 1100 * 128])}
     spec = make_spec(tree)
     state = init_state(mesh, spec)
-    before, _ = counts()
+    before, _, _ = counts()
     build_sync_step(mesh, spec, impl="xla").lower(state)
     assert counts()[0] == before
     build_sync_step(mesh, spec, impl="pallas").lower(state)
-    after, leaves = counts()
+    after, leaves, words_rows = counts()
     assert [a - b for a, b in zip(after, before)] == [1, 1]
     assert leaves == 6
+    assert words_rows == [32, 32]  # 1140 rows in blocks of 1024
     build_sync_step(mesh, make_spec({"w": jnp.zeros(4096)}), impl="pallas").lower(
         jax.tree.map(lambda x: x[:, :4096], state)
     )
-    assert counts()[1] == 1  # the newest traced table's
+    assert counts()[1:] == (1, [1, 1])  # the newest traced table's: 32 rows, one block

@@ -20,15 +20,12 @@ from shared_tensor_tpu.train import PodTrainer
 from shared_tensor_tpu.utils import profiling
 
 #: The innermost scopes that partition a sync step (ISSUE 27's table).
-SYNC_SCOPES = {
-    "st.leaf_scales", "st.quantize", "st.allgather",
-    "st.words_layout", "st.apply",
-}
+SYNC_SCOPES = {"st.leaf_scales", "st.quantize", "st.allgather", "st.apply"}
 #: Every path a sync step's operations may sit under.
 SYNC_PATHS = {
     "st.codec_send", "st.codec_send/st.leaf_scales",
     "st.codec_send/st.quantize", "st.codec_send/st.allgather",
-    "st.codec_apply", "st.codec_apply/st.words_layout", "st.codec_apply/st.apply",
+    "st.codec_apply", "st.codec_apply/st.apply",
 }
 TRAIN_SCOPES = {"st.grads", "st.unflatten", "st.flatten", "st.update", "st.add_updates"}
 
@@ -117,7 +114,9 @@ def _pallas_names(fn, *args):
 
 _ROWS = 16
 _FLAT = jnp.ones((_ROWS * 128,), jnp.float32)
-_TABLES = LeafRows.of(make_spec({"a": jnp.ones(1000), "b": jnp.ones(1024)})).tables(_ROWS)
+_TABLES = LeafRows.of(make_spec({"a": jnp.ones(1000), "b": jnp.ones(1024)})).tables(
+    codec_pallas.quantize_block_rows(_ROWS)
+)
 
 
 @pytest.mark.parametrize(
@@ -128,7 +127,7 @@ _TABLES = LeafRows.of(make_spec({"a": jnp.ones(1000), "b": jnp.ones(1024)})).tab
          (jnp.ones((2,)), _FLAT)),
         ("st_apply_rows_batch",
          lambda s, w, a: codec_pallas.apply_rows_batch(s, _TABLES, w, (a,)),
-         (jnp.ones((2, 2)), jnp.zeros((_ROWS, 8), jnp.uint32), _FLAT)),
+         (jnp.ones((2, 2)), jnp.zeros((2, 1, 128), jnp.uint32), _FLAT)),
     ],
     ids=lambda v: v if isinstance(v, str) else "",
 )
@@ -172,8 +171,6 @@ def test_scope_times_of_a_sync_step(tmp_path):
     smap = profiling.scope_map(compiled)
     t = profiling.scope_times(str(tmp_path), smap, steps=3)
     assert t["devices"] == 4 and t["steps"] == 3
-    # words_layout is a reshape XLA fuses into its consumer at this size: the
-    # program has it, a trace need not
     _check_table(
         t, {"st.leaf_scales", "st.quantize", "st.allgather", "st.apply"},
         set(smap.values()),

@@ -17,6 +17,7 @@ import jax.numpy as jnp
 
 from shared_tensor_tpu.config import ScalePolicy
 from shared_tensor_tpu.ops import table as T
+from shared_tensor_tpu.ops.packing import dense_words, flat_words, words_rows
 
 
 def _table(seed, shapes=((40, 70), (256,), (3, 5, 7)), scale_per_leaf=None):
@@ -126,8 +127,8 @@ def test_pallas_roundtrip_convergence():
 # tier (interpreter here) is held bit for bit to a plain NumPy statement of
 # the rule at the same scales, and to its XLA twin.
 
-#: name -> leaf sizes (elements). A grid block is 1024 rows (quantize, apply at
-#: K = 1) or 512 (apply from K = 2 on); a leaf of n elements takes
+#: name -> leaf sizes (elements). A grid block is 1024 rows, 32 rows of packed
+#: words (32 table rows' words fill one); a leaf of n elements takes
 #: ceil(n / 1024) * 8 rows.
 _LEAF_TABLES = {
     # 1536 rows in one leaf: two blocks, each inside it
@@ -135,9 +136,15 @@ _LEAF_TABLES = {
     # 704 + 904 rows: the first block meets both leaves, the second one only
     "two_leaves_a_block": [704 * 128 - 77, 904 * 128],
     # 64 leaves of 8 rows (ResNet's BatchNorm leaves), one of them of a single
-    # live element, then 600 rows: a 512-row block meets 64 leaves, a
-    # 1024-row block 65
+    # live element and one idling at scale 0, then 600 rows: every words row
+    # of the run spans four leaves, and a 1024-row block meets 65 leaves
     "64_leaves_a_block": [1 + (37 * i) % 1024 for i in range(63)] + [1, 600 * 128 - 1],
+    # 1288 rows, 8 (mod 32): the last words row holds 8 table rows and 96
+    # lanes of pad bits; the last block is 264 rows of 1024
+    "rows_8_mod_32": [1288 * 128 - 3],
+    # 520 + 528 = 1048 rows, 24 (mod 256) and (mod 32): the last block is 24
+    # rows, less than one words row, inside the second leaf
+    "rows_24_mod_256": [520 * 128 - 9, 528 * 128],
 }
 
 
@@ -187,11 +194,14 @@ def test_quantize_rows_from_leaf_scalars(name):
     want_w, want_r = _np_quantize(scales, spec, live, r)
     for impl in ("pallas", "xla"):
         w, r2 = T.quantize_rows(jnp.asarray(scales), leaves, None, jnp.asarray(r), impl)
-        np.testing.assert_array_equal(np.asarray(w), want_w, err_msg=impl)
+        assert w.shape == (words_rows(leaves.rows), 128)
+        # the wire's flat word vector, then nothing but zero pad bits
+        np.testing.assert_array_equal(np.asarray(w).reshape(-1)[: want_w.size], want_w, err_msg=impl)
+        assert not np.asarray(w).reshape(-1)[want_w.size:].any(), impl
         np.testing.assert_array_equal(np.asarray(r2), want_r, err_msg=impl)
 
 
-@pytest.mark.parametrize("k", [1, 4, 5])
+@pytest.mark.parametrize("k", [1, 2, 4, 5, 16])
 @pytest.mark.parametrize("name", list(_LEAF_TABLES))
 def test_apply_rows_from_leaf_scalars(name, k):
     spec, rng, live = _leaf_table(name, 12)
@@ -201,7 +211,7 @@ def test_apply_rows_from_leaf_scalars(name, k):
     arrays = tuple(rng.normal(size=spec.total).astype(np.float32) for _ in range(2))
     outs = {
         impl: T.apply_rows(
-            jnp.asarray(scales), leaves, None, jnp.asarray(words),
+            jnp.asarray(scales), leaves, None, dense_words(jnp.asarray(words), leaves.rows),
             tuple(jnp.asarray(a) for a in arrays), impl,
         )
         for impl in ("pallas", "xla")
@@ -214,7 +224,9 @@ def test_apply_rows_from_leaf_scalars(name, k):
             np.testing.assert_allclose(np.asarray(got_x), np.asarray(got_p), rtol=1e-6, atol=1e-6)
 
 
-@pytest.mark.parametrize("name", ["two_leaves_a_block", "64_leaves_a_block"])
+@pytest.mark.parametrize(
+    "name", ["two_leaves_a_block", "64_leaves_a_block", "rows_24_mod_256"]
+)
 def test_row_codec_on_a_window_that_cuts_a_leaf(name):
     """Two windows of the table's rows, as two shards hold them: the cut falls
     inside a leaf, and each window's pass equals the whole table's on its
@@ -236,11 +248,11 @@ def test_row_codec_on_a_window_that_cuts_a_leaf(name):
             got_w, got_r = T.quantize_rows(
                 jnp.asarray(scales[0]), halves, jnp.int32(w), jnp.asarray(r[el]), impl
             )
-            np.testing.assert_array_equal(np.asarray(got_w), want_w[wd])
+            np.testing.assert_array_equal(np.asarray(flat_words(got_w, cut)), want_w[wd])
             np.testing.assert_array_equal(np.asarray(got_r), want_r[el])
         (got_v,) = T.apply_rows(
-            jnp.asarray(scales), halves, jnp.int32(w), jnp.asarray(words[:, wd]),
-            (jnp.asarray(v[el]),), "pallas",
+            jnp.asarray(scales), halves, jnp.int32(w),
+            dense_words(jnp.asarray(words[:, wd]), cut), (jnp.asarray(v[el]),), "pallas",
         )
         np.testing.assert_array_equal(np.asarray(got_v), want_v[el])
 

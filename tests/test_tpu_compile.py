@@ -113,21 +113,26 @@ def _per_row_arrays(text: str, rows: int) -> list[str]:
     """Arrays of the compiled text that hold a number or a few for each of
     ``rows`` table rows: ``f32[rows,1]``, ``s32[rows,1]``, ``f32[rows,K]``.
     XLA pads such an array to 128 lanes (1.68 GB for 13 MB of numbers at
-    this size). The packed words ``u32[rows,4K]`` are no such copy of
-    per-leaf numbers and stay (ROADMAP S2)."""
-    return sorted(set(re.findall(r"\b[fs]32\[%d,\d{1,2}\]" % rows, text)))
+    this size), and it pads the packed words as much where they are laid
+    out a table row a row, ``u32[rows,4]`` or ``u32[K,rows,4]``: they travel
+    128 words a row, ``u32[rows/32,128]`` (ROADMAP S2)."""
+    return sorted(set(re.findall(r"\b[fsu]32\[(?:\d+,)?%d,\d{1,2}\]" % rows, text)))
 
 
-@pytest.mark.parametrize("k", [1, 8, 16, 64])
+@pytest.mark.parametrize("k", [1, 4, 8, 16, 64])
 def test_apply_table_batch_compiles_for_v5e(v5e_devices, olmoe_spec, k):
     """K = 16 is the device tier's default burst (comm/peer.py) and what
     SharedTensor.receive_frames pads up to (at the seed it asked for more
     VMEM than a kernel gets); K = 64 is its largest: 64 x 201 scales in
-    scalar memory beside the leaf tables of 34 145 grid blocks of 96 rows.
-    At the table cells' real size but for K = 1, whose kernel at that size
-    the (1,1) sync step below compiles: alone, XLA spends 7 minutes lowering
-    the reshape of one frame's words to (rows, 4) (ROADMAP S2)."""
-    spec = olmoe_spec if k > 1 else _olmoe_spec(rehearsal=True)
+    scalar memory beside the leaf tables of 3 202 grid blocks of 1 024 rows,
+    a block of K x 32 rows of words. At the table cells' real size, K = 1
+    too: the wire's flat words become the kernel's operand by a bitcast
+    (XLA spent 7 minutes lowering one frame's reshape to (rows, 4)). But
+    for K = 4, whose kernel at that size the (4, 1) sync step below
+    compiles: here XLA would spend 38 s a compile on the copy that turns
+    ``u32[4, W]``, tiled four frames a tile, into four words arrays
+    (ROADMAP S2 (d))."""
+    spec = olmoe_spec if k != 4 else _olmoe_spec(rehearsal=True)
     mesh = make_mesh(1, 1, devices=v5e_devices)
     arg = lambda shape, dtype: jax.ShapeDtypeStruct(
         shape, dtype, sharding=NamedSharding(mesh, P())
