@@ -175,9 +175,13 @@ class Event:
 
 def py_event(
     name: str, node: int = 0, link: int = 0, arg: int = 0, detail: str = "",
-    extra: int = 0,
+    extra: int = 0, t_ns: Optional[int] = None,
 ) -> Event:
-    return Event(time.monotonic_ns(), "py", name, node, link, arg, detail, extra)
+    """A Python-tier event stamped now, or at ``t_ns`` (CLOCK_MONOTONIC) when
+    what it reports ended earlier than it is logged."""
+    if t_ns is None:
+        t_ns = time.monotonic_ns()
+    return Event(t_ns, "py", name, node, link, arg, detail, extra)
 
 
 def _lib():

@@ -100,15 +100,19 @@ class ObsHub:
 
     def emit(
         self, name: str, node: int = 0, link: int = 0, arg: int = 0,
-        detail: str = "", extra: int = 0,
+        detail: str = "", extra: int = 0, t_ns: Optional[int] = None,
     ) -> None:
         """Record one Python-tier event (no-op when obs is disabled — the
-        callers gate on their own cached flag; this is the backstop)."""
+        callers gate on their own cached flag; this is the backstop).
+        ``t_ns`` stamps it at a CLOCK_MONOTONIC time already read (the pod
+        tier's spans: the end of the span; a GC pause logged after it)."""
         from . import obs_enabled
 
         if not obs_enabled():
             return
-        self.recorder.record([ev.py_event(name, node, link, arg, detail, extra)])
+        self.recorder.record(
+            [ev.py_event(name, node, link, arg, detail, extra, t_ns)]
+        )
 
     def poll_native(self, min_interval_sec: float = 0.0, lib=None) -> int:
         """Drain the native ring into the recorder (rate-limited when

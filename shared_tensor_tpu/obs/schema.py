@@ -198,6 +198,18 @@ SCHEMA: dict[str, tuple[str, str]] = {
     "st_pod_compile_seconds_total": ("counter", "seconds in those compilations"),
     "st_pod_cache_load_seconds_total": ("counter", "seconds retrieving executables from the persistent compilation cache"),
     "st_pod_last_compile_step": ("gauge", "PodTrainer.steps when the latest compilation happened"),
+    # r38 the pod tier's host spans (utils/profiling.py PodTier.span): each
+    # span is one event in the process's flight recorder (obs.hub()) and one
+    # addition to these two series, which count with ST_OBS=0 too. A program
+    # build's three phases are spans as well (st:build.trace | .lower |
+    # .compile, by program name); a phase nested inside another (a trace
+    # inside a trace or a lowering) is the outer one's and counts once.
+    "st_pod_span_seconds_total": ("counter", "host seconds inside the pod tier's spans (per-span label: trainer_init | init_state | build_sync_step | build_train_step | train.step | shard_batch | build.trace | ...; a child's seconds are in its parents' too)"),
+    "st_pod_span_calls_total": ("counter", "completed spans of the pod tier (per-span label, as st_pod_span_seconds_total)"),
+    "st_pod_trace_seconds_total": ("counter", "seconds JAX reported tracing programs to jaxprs in this process (a trace inside another trace or a lowering is not counted apart)"),
+    "st_pod_lower_seconds_total": ("counter", "seconds JAX reported lowering jaxprs to MLIR modules in this process"),
+    "st_pod_gc_seconds_total": ("counter", "seconds inside Python's cyclic collector since the pod tier was made (every generation, every collection)"),
+    "st_pod_gc_pause_seconds_max": ("gauge", "the longest single collection since the pod tier was made"),
     # expert layers (models/mla_moe.py), read from the newest PodTrainer's
     # aux when the registry is read: the newest step's, over all peers and
     # expert layers

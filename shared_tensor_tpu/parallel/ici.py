@@ -65,6 +65,7 @@ from ..ops.table import (
     resolve_impl,
     unflatten,
 )
+from ..utils.profiling import pod_tier
 from .mesh import rows_per_shard
 
 
@@ -95,12 +96,23 @@ def init_state(
     reference instead has one master seed its state and stream it to joiners
     (src/sharedtensor.c:379-381); in-pod peers are born simultaneously so the
     seed is just replicated — the streaming join path lives in the DCN tier
-    (comm/peer.py)."""
+    (comm/peer.py).
+
+    Host spans: ``st:init_state`` around ``st:init_state.seed`` (the
+    template flattened, leaf by leaf, and put under the shard axis),
+    ``st:init_state.broadcast`` (one program: the seed to every peer) and
+    ``st:init_state.residual`` (one program: zeros). Each child ends by
+    waiting for its array, which the next one or the caller's first step
+    would wait for anyway, so a child's seconds are its own work, programs
+    built and run, and not its successor's. Without a template the values
+    are zeros too, dispatched in ``st:init_state`` itself (the program is
+    built there) and waited for with the residual."""
     sh = state_sharding(mesh, config)
     peer_ax, shard_ax = sh.spec
     shape = (mesh.shape[peer_ax], spec.total)
     rows_per_shard(spec.total, mesh.shape[shard_ax])  # validate divisibility
-    with jax.profiler.TraceAnnotation("st:init_state"):
+    pod = pod_tier()
+    with pod.span("init_state"):
         # Both arrays are built under their sharding, so each device only
         # ever holds its own (1, total / n_shard) block: broadcasting on the
         # default device first would stage the whole (n_peer, total) state
@@ -109,24 +121,27 @@ def init_state(
         if template is None:
             values = zeros()
         else:
-            seed = jax.device_put(
-                flatten(template, spec), NamedSharding(mesh, P(shard_ax))
-            )
-            values = jax.jit(
-                lambda f: jnp.broadcast_to(f, shape), out_shardings=sh
-            )(seed)
+            with pod.span("init_state.seed"):
+                seed = jax.block_until_ready(jax.device_put(
+                    flatten(template, spec), NamedSharding(mesh, P(shard_ax))
+                ))
+            with pod.span("init_state.broadcast"):
+                values = jax.block_until_ready(jax.jit(
+                    lambda f: jnp.broadcast_to(f, shape), out_shardings=sh
+                )(seed))
             del seed
         # last: seeding holds the template, its flat copy and the broadcast
         # at once, five tables with the residual beside them (16.25 GB of a
         # v5e's 16.91 for a 3.24 GB table; my chip run, PR 35)
-        residual = zeros()
+        with pod.span("init_state.residual"):
+            residual = jax.block_until_ready(zeros())
     return PeerSyncState(values, residual)
 
 
 def read_peer(state: PeerSyncState, spec: TableSpec, peer: int):
     """Peer ``peer``'s current replica as the caller's pytree (reference
     copyToTensor)."""
-    with jax.profiler.TraceAnnotation("st:read_peer"):
+    with pod_tier().span("read_peer"):
         return unflatten(state.values[peer], spec)
 
 
@@ -298,58 +313,63 @@ def build_sync_step(
     ``impl`` is the row codec's tier (ops.table.resolve_impl: "auto" is the
     Pallas kernels exactly where they compile, a TPU; "pallas"/"xla" pin one
     for parity tests).
+
+    Host span ``st:build_sync_step``: the layout's tables and, the first
+    time in a process, the import of Pallas; the program itself is built
+    (``st:build.*``) when it is first called or lowered.
     """
-    cfg = config or MeshConfig()
-    ctx = _make_ctx(mesh, spec, per_leaf, cfg)
-    peer_ax, shard_ax = ctx.peer_ax, ctx.shard_ax
-    impl = resolve_impl(impl)
+    with pod_tier().span("build_sync_step"):
+        cfg = config or MeshConfig()
+        ctx = _make_ctx(mesh, spec, per_leaf, cfg)
+        peer_ax, shard_ax = ctx.peer_ax, ctx.shard_ax
+        impl = resolve_impl(impl)
 
-    def _compressed_body(values, residual):
-        """Compose the shared codec halves (same blocks as
-        build_sync_phases — the compose-parity test pins the equivalence)."""
-        r2, words_all, scales_all, scales = _codec_send(ctx, policy, impl, residual)
-        v2 = _codec_apply(ctx, impl, values, words_all, scales_all)
-        return v2, r2, scales
+        def _compressed_body(values, residual):
+            """Compose the shared codec halves (same blocks as
+            build_sync_phases — the compose-parity test pins the equivalence)."""
+            r2, words_all, scales_all, scales = _codec_send(ctx, policy, impl, residual)
+            v2 = _codec_apply(ctx, impl, values, words_all, scales_all)
+            return v2, r2, scales
 
-    def _exact(values, residual):
-        r = residual.reshape(ctx.rows_local, LANES)
-        live = ctx.live()
-        # report the would-have-been scales so both arms expose the same
-        # observability surface (the shard-axis reduction inside also lets
-        # shard_map infer the scales output is shard-replicated)
-        scales = _leaf_scales(ctx, r, live, policy)
-        delta_others = jax.lax.psum(residual, peer_ax) - residual
-        v2 = jnp.clip(values + delta_others, -SAT, SAT)
-        v2 = jnp.where(live.reshape(-1), v2, 0.0)
-        return v2, jnp.zeros_like(residual), scales
+        def _exact(values, residual):
+            r = residual.reshape(ctx.rows_local, LANES)
+            live = ctx.live()
+            # report the would-have-been scales so both arms expose the same
+            # observability surface (the shard-axis reduction inside also lets
+            # shard_map infer the scales output is shard-replicated)
+            scales = _leaf_scales(ctx, r, live, policy)
+            delta_others = jax.lax.psum(residual, peer_ax) - residual
+            v2 = jnp.clip(values + delta_others, -SAT, SAT)
+            v2 = jnp.where(live.reshape(-1), v2, 0.0)
+            return v2, jnp.zeros_like(residual), scales
 
-    body = _compressed_body if compressed else _exact
+        body = _compressed_body if compressed else _exact
 
-    def _step(values, residual):
-        # local blocks: (1, spec.total // n_shard)
-        v2, r2, scales = body(values[0], residual[0])
-        return v2[None], r2[None], scales[None]
+        def _step(values, residual):
+            # local blocks: (1, spec.total // n_shard)
+            v2, r2, scales = body(values[0], residual[0])
+            return v2[None], r2[None], scales[None]
 
-    spec_vr = P(peer_ax, shard_ax)
-    sharded = shard_map(
-        _step,
-        mesh=mesh,
-        in_specs=(spec_vr, spec_vr),
-        out_specs=(spec_vr, spec_vr, P(peer_ax, None)),
-        # pallas_call outputs carry no varying-mesh-axes annotation; disable
-        # the vma checker for the kernel body (the XLA body keeps it)
-        check_vma=not (compressed and impl == "pallas"),
-    )
+        spec_vr = P(peer_ax, shard_ax)
+        sharded = shard_map(
+            _step,
+            mesh=mesh,
+            in_specs=(spec_vr, spec_vr),
+            out_specs=(spec_vr, spec_vr, P(peer_ax, None)),
+            # pallas_call outputs carry no varying-mesh-axes annotation; disable
+            # the vma checker for the kernel body (the XLA body keeps it)
+            check_vma=not (compressed and impl == "pallas"),
+        )
 
-    def sync_step(state: PeerSyncState) -> Tuple[PeerSyncState, jax.Array]:
-        v, r, scales = sharded(state.values, state.residual)
-        return PeerSyncState(v, r), scales
+        def sync_step(state: PeerSyncState) -> Tuple[PeerSyncState, jax.Array]:
+            v, r, scales = sharded(state.values, state.residual)
+            return PeerSyncState(v, r), scales
 
-    if jit_compile:
-        return jax.jit(sync_step, donate_argnums=(0,))
-    # Raw (traceable) form for embedding into a larger jitted step
-    # (train/async_sgd.py fuses grads + add_updates + sync into one program).
-    return sync_step
+        if jit_compile:
+            return jax.jit(sync_step, donate_argnums=(0,))
+        # Raw (traceable) form for embedding into a larger jitted step
+        # (train/async_sgd.py fuses grads + add_updates + sync into one program).
+        return sync_step
 
 
 def build_sync_phases(

@@ -90,79 +90,84 @@ def build_train_step(
     for sync", README.md:24; SURVEY.md §7.4 hard part 1). The local update
     is delivered one step later; eventual consistency is unchanged.
     ``apply_gathered(values, *send(residual)[1:])`` composed immediately is
-    bit-for-bit the non-overlap sync (tests pin this)."""
-    cfg = config or MeshConfig()
-    if overlap and (not sync or not compressed):
-        raise ValueError("overlap=True requires sync=True and compressed=True")
-    sync_raw = (
-        build_sync_step(
-            mesh,
-            spec,
-            policy=policy,
-            per_leaf=per_leaf,
-            compressed=compressed,
-            config=cfg,
-            jit_compile=False,
+    bit-for-bit the non-overlap sync (tests pin this).
+
+    Host span ``st:build_train_step`` (around ``st:build_sync_step``); the
+    program is built, ``st:build.*`` of program ``_step``, inside the
+    ``st:train.step`` that first calls it."""
+    with profiling.pod_tier().span("build_train_step"):
+        cfg = config or MeshConfig()
+        if overlap and (not sync or not compressed):
+            raise ValueError("overlap=True requires sync=True and compressed=True")
+        sync_raw = (
+            build_sync_step(
+                mesh,
+                spec,
+                policy=policy,
+                per_leaf=per_leaf,
+                compressed=compressed,
+                config=cfg,
+                jit_compile=False,
+            )
+            if sync and not overlap
+            else None
         )
-        if sync and not overlap
-        else None
-    )
-    phases = (
-        build_sync_phases(
-            mesh, spec, policy=policy, per_leaf=per_leaf, config=cfg
+        phases = (
+            build_sync_phases(
+                mesh, spec, policy=policy, per_leaf=per_leaf, config=cfg
+            )
+            if sync and overlap
+            else None
         )
-        if sync and overlap
-        else None
-    )
-    k = spec.num_leaves if per_leaf else 1
+        k = spec.num_leaves if per_leaf else 1
 
-    def loss_and_aux(params, batch_item):
-        out = loss_fn(params, batch_item)
-        return out if isinstance(out, tuple) else (out, None)
+        def loss_and_aux(params, batch_item):
+            out = loss_fn(params, batch_item)
+            return out if isinstance(out, tuple) else (out, None)
 
-    grad_fn = jax.value_and_grad(loss_and_aux, has_aux=True)
+        grad_fn = jax.value_and_grad(loss_and_aux, has_aux=True)
 
-    def per_peer(values_row: jnp.ndarray, batch_item):
-        with jax.named_scope("st.grads"):
-            params = unflatten(values_row, spec)
-            (loss, aux), grads = grad_fn(params, batch_item)
-            return loss, flatten(grads, spec), aux
+        def per_peer(values_row: jnp.ndarray, batch_item):
+            with jax.named_scope("st.grads"):
+                params = unflatten(values_row, spec)
+                (loss, aux), grads = grad_fn(params, batch_item)
+                return loss, flatten(grads, spec), aux
 
-    def with_aux(state, opt_state, losses, scales, aux):
-        out = (state, opt_state, losses, scales)
-        return out if aux is None else (*out, aux)
+        def with_aux(state, opt_state, losses, scales, aux):
+            out = (state, opt_state, losses, scales)
+            return out if aux is None else (*out, aux)
 
-    def update_of(g, opt_state, values, lr):
-        with jax.named_scope("st.update"):
-            if optimizer is None:
-                return -lr * g, opt_state
-            return jax.vmap(optimizer.update)(g, opt_state, values)
+        def update_of(g, opt_state, values, lr):
+            with jax.named_scope("st.update"):
+                if optimizer is None:
+                    return -lr * g, opt_state
+                return jax.vmap(optimizer.update)(g, opt_state, values)
 
-    def _step(state: PeerSyncState, opt_state, batch, lr):
-        if phases is not None:
-            # OVERLAP mode: quantize + all-gather the residual as it stands —
-            # no data dependency on this step's grads, so XLA's latency-
-            # hiding scheduler runs the collective under the backward pass.
-            # The local update below rides the NEXT step's frame (async
-            # semantics unchanged: a frame carries whatever residual mass
-            # exists at frame time, exactly like the reference's streams).
-            send, apply_gathered = phases
-            r2, words_all, scales_all = send(state.residual)
+        def _step(state: PeerSyncState, opt_state, batch, lr):
+            if phases is not None:
+                # OVERLAP mode: quantize + all-gather the residual as it stands —
+                # no data dependency on this step's grads, so XLA's latency-
+                # hiding scheduler runs the collective under the backward pass.
+                # The local update below rides the NEXT step's frame (async
+                # semantics unchanged: a frame carries whatever residual mass
+                # exists at frame time, exactly like the reference's streams).
+                send, apply_gathered = phases
+                r2, words_all, scales_all = send(state.residual)
+                losses, g, aux = jax.vmap(per_peer)(state.values, batch)
+                updates, opt_state = update_of(g, opt_state, state.values, lr)
+                v2 = apply_gathered(state.values, words_all, scales_all)
+                state = add_updates_raw(PeerSyncState(v2, r2), updates)
+                return with_aux(state, opt_state, losses, scales_all, aux)
             losses, g, aux = jax.vmap(per_peer)(state.values, batch)
             updates, opt_state = update_of(g, opt_state, state.values, lr)
-            v2 = apply_gathered(state.values, words_all, scales_all)
-            state = add_updates_raw(PeerSyncState(v2, r2), updates)
-            return with_aux(state, opt_state, losses, scales_all, aux)
-        losses, g, aux = jax.vmap(per_peer)(state.values, batch)
-        updates, opt_state = update_of(g, opt_state, state.values, lr)
-        state = add_updates_raw(state, updates)
-        if sync_raw is not None:
-            state, scales = sync_raw(state)
-        else:
-            scales = jnp.zeros((state.values.shape[0], k), jnp.float32)
-        return with_aux(state, opt_state, losses, scales, aux)
+            state = add_updates_raw(state, updates)
+            if sync_raw is not None:
+                state, scales = sync_raw(state)
+            else:
+                scales = jnp.zeros((state.values.shape[0], k), jnp.float32)
+            return with_aux(state, opt_state, losses, scales, aux)
 
-    return jax.jit(_step, donate_argnums=(0,) if optimizer is None else (0, 1))
+        return jax.jit(_step, donate_argnums=(0,) if optimizer is None else (0, 1))
 
 
 @dataclasses.dataclass
@@ -191,40 +196,49 @@ class PodTrainer:
     sync_every: int = 1
 
     def __post_init__(self):
-        self.spec: TableSpec = make_spec(self.template)
-        self.state: PeerSyncState = init_state(
-            self.mesh, self.spec, self.template, self.mesh_config
-        )
-        self.n_peer: int = self.mesh.shape[self.mesh_config.peer_axis]
-        self.opt_state = (
-            None
-            if self.optimizer is None
-            else jax.vmap(self.optimizer.init)(self.state.values)
-        )
-        self.sync_every = max(1, int(self.sync_every))
-        kw = dict(
-            policy=self.codec.scale_policy,
-            per_leaf=self.codec.per_leaf_scale,
-            compressed=self.compressed,
-            config=self.mesh_config,
-            optimizer=self.optimizer,
-        )
-        self._step = build_train_step(
-            self.mesh, self.spec, self.loss_fn,
-            sync=self.sync, overlap=self.overlap, **kw,
-        )
-        # the off-beat program for sync_every > 1: identical step, no
-        # exchange — updates pile into the residual until the sync beat
-        self._step_local = (
-            build_train_step(self.mesh, self.spec, self.loss_fn, sync=False, **kw)
-            if self.sync and self.sync_every > 1
-            else None
-        )
+        """Host spans: ``st:trainer_init`` around ``st:make_spec``,
+        ``st:init_state`` (and its children, which wait for their arrays:
+        see :func:`~shared_tensor_tpu.parallel.ici.init_state`),
+        ``st:opt_init`` (dispatches only) and one ``st:build_train_step`` a
+        program (builds nothing yet: the first ``st:train.step`` does)."""
+        pod = profiling.pod_tier()
+        with pod.span("trainer_init"):
+            with pod.span("make_spec"):
+                self.spec: TableSpec = make_spec(self.template)
+            self.state: PeerSyncState = init_state(
+                self.mesh, self.spec, self.template, self.mesh_config
+            )
+            self.n_peer: int = self.mesh.shape[self.mesh_config.peer_axis]
+            with pod.span("opt_init"):
+                self.opt_state = (
+                    None
+                    if self.optimizer is None
+                    else jax.vmap(self.optimizer.init)(self.state.values)
+                )
+            self.sync_every = max(1, int(self.sync_every))
+            kw = dict(
+                policy=self.codec.scale_policy,
+                per_leaf=self.codec.per_leaf_scale,
+                compressed=self.compressed,
+                config=self.mesh_config,
+                optimizer=self.optimizer,
+            )
+            self._step = build_train_step(
+                self.mesh, self.spec, self.loss_fn,
+                sync=self.sync, overlap=self.overlap, **kw,
+            )
+            # the off-beat program for sync_every > 1: identical step, no
+            # exchange — updates pile into the residual until the sync beat
+            self._step_local = (
+                build_train_step(self.mesh, self.spec, self.loss_fn, sync=False, **kw)
+                if self.sync and self.sync_every > 1
+                else None
+            )
         self.steps = 0
         #: the newest step's ``aux`` (leading peer axis, on the device) where
         #: ``loss_fn`` returns ``(loss, aux)``; None before the first step
         self.aux: Any = None
-        profiling.pod_tier().watch(self)
+        pod.watch(self)
 
     def shard_batch(self, batch: Any) -> Any:
         """Pin a [n_peer, ...] batch pytree to the peer axis so each peer's
@@ -235,7 +249,7 @@ class PodTrainer:
             sh = NamedSharding(self.mesh, P(ax, *([None] * (x.ndim - 1))))
             return jax.device_put(x, sh)
 
-        with jax.profiler.TraceAnnotation("st:shard_batch"):
+        with profiling.pod_tier().span("shard_batch"):
             return jax.tree.map(put, batch)
 
     def step(self, batch: Any, lr: float = 1e-2):
@@ -245,19 +259,22 @@ class PodTrainer:
         (the transform owns the step size)."""
         pod = profiling.pod_tier()
         pod.step_now = self.steps  # a compilation in here is this step's
-        with jax.profiler.StepTraceAnnotation(
-            "st:train.step", step_num=self.steps
+        fn = self._step
+        if self._step_local is not None and (self.steps + 1) % self.sync_every:
+            fn = self._step_local
+        synced = self.sync and fn is self._step
+        # the step log: the span is the host's time inside this call, and from
+        # its end to the next one's start is what the caller did
+        with pod.span(
+            "train.step", step_num=self.steps, program="sync" if synced else "local"
         ):
-            fn = self._step
-            if self._step_local is not None and (self.steps + 1) % self.sync_every:
-                fn = self._step_local
             self.state, self.opt_state, losses, scales, *aux = fn(
                 self.state, self.opt_state, batch, jnp.float32(lr)
             )
         if aux:
             self.aux = aux[0]
         self.steps += 1
-        pod.count_step(self.sync and fn is self._step)
+        pod.count_step(synced)
         return losses, scales
 
     def lower(self, batch: Any, lr: float = 1e-2):
@@ -277,7 +294,7 @@ class PodTrainer:
     def add(self, updates: jax.Array) -> None:
         """Out-of-band additive update, [n_peer, spec.total] flat (reference
         addFromTensor outside the training loop)."""
-        with jax.profiler.TraceAnnotation("st:add"):
+        with profiling.pod_tier().span("add"):
             self.state = add_updates(self.state, updates)
 
     def replica_spread(self) -> float:

@@ -1,11 +1,22 @@
-"""Where a step's time goes, and the pod tier's counters.
+"""Where a step's time goes, and the pod tier's counters and host spans.
 
 The chip path names its own work: ``jax.named_scope("st.<name>")`` in
 parallel/ici.py, ops/table.py and train/async_sgd.py, ``name="st_<kernel>"``
-on the Pallas calls of ops/codec_pallas.py, ``st:<name>`` host spans
-(``jax.profiler`` annotations) in ``PodTrainer``. This module captures a
-trace and reads those names back; it knows the ``st.`` / ``st:`` prefixes
-and no single name.
+on the Pallas calls of ops/codec_pallas.py, ``st:<name>`` host spans in
+``PodTrainer`` and parallel/ici.py. This module captures a trace and reads
+those names back; it knows the ``st.`` / ``st:`` prefixes and no single name.
+
+A host span is one call of :meth:`PodTier.span`: a name, a start and an end
+on CLOCK_MONOTONIC, the span that was open around it on its thread, the
+trainer's step and a few attributes. It is kept as one event of the
+process's flight recorder (``obs.hub()``, the timeline the host tiers
+share), whether a profiler runs or not; a program's trace, lowering and
+compilation (from ``jax.monitoring``) and a collection of Python's cyclic
+collector are such events too. ``pod_tier().spans()`` gives them back,
+``pod_tier().span_table()`` sums them by name with self times, and
+``hub().export_timeline(path)`` at the end of a run followed by
+``python -m shared_tensor_tpu.utils.profiling --timeline path`` prints the
+same table in another process: where a set-up's seconds went.
 
 - :func:`trace` — the capture: a ``jax.profiler`` session around whatever
   runs inside. ``chipbench/run.py --keep-trace DIR`` writes the same files.
@@ -18,6 +29,9 @@ and no single name.
   put down to the host span that covers it.
   ``python -m shared_tensor_tpu.utils.profiling <dir> [--hlo PATH]`` prints
   it (:func:`main`).
+- :func:`span_rows`, :func:`span_table` — the host spans of a timeline
+  (the hub's, or an exported file's) as rows, and per name their calls,
+  total and self seconds.
 - :func:`pod_registry` — the pod tier's counters (``st_pod_*`` in
   obs/schema.py) in one :class:`~shared_tensor_tpu.obs.registry.Registry`:
   steps by program, and compilations stamped with the step they fell in.
@@ -30,6 +44,7 @@ from __future__ import annotations
 
 import bisect
 import contextlib
+import gc
 import glob
 import math
 import os
@@ -38,19 +53,29 @@ import threading
 import time
 import weakref
 from collections import deque
-from typing import Iterable, Iterator
+from typing import Iterable, Iterator, NamedTuple
 
 import jax
 
 SCOPE_PREFIX = "st."
-SPAN_PREFIXES = ("st:", "chipbench:")
+SPAN_PREFIX = "st:"
+SPAN_PREFIXES = (SPAN_PREFIX, "chipbench:")
 UNSCOPED = "unscoped"
 #: An idle gap shorter than this is the device's own turn-around between
 #: two operations, not something the host did.
 IDLE_GAP_NS = 50_000
 
+TRACE_EVENT = "/jax/core/compile/jaxpr_trace_duration"
+LOWER_EVENT = "/jax/core/compile/jaxpr_to_mlir_module_duration"
 COMPILE_EVENT = "/jax/core/compile/backend_compile_duration"
 CACHE_LOAD_EVENT = "/jax/compilation_cache/cache_retrieval_time_sec"
+#: the three phases of a program's build, as child spans
+BUILD_SPANS = {
+    TRACE_EVENT: "build.trace", LOWER_EVENT: "build.lower", COMPILE_EVENT: "build.compile",
+}
+GC_SPAN = SPAN_PREFIX + "gc"
+#: a collection shorter than this is counted and not logged
+GC_EVENT_NS = 1_000_000
 
 
 @contextlib.contextmanager
@@ -64,17 +89,76 @@ def trace(log_dir: str) -> Iterator[None]:
         jax.profiler.stop_trace()
 
 
-# --- the pod tier's counters ------------------------------------------------
+# --- the pod tier's counters and host spans -----------------------------------
+
+
+class _Span:
+    """One open span of :meth:`PodTier.span`."""
+
+    __slots__ = ("_tier", "_name", "_attrs", "_ann", "_t0", "_parent")
+
+    def __init__(self, tier: "PodTier", name: str, step_num, attrs: dict):
+        self._tier, self._name, self._attrs = tier, name, attrs
+        self._ann = (
+            jax.profiler.TraceAnnotation(SPAN_PREFIX + name)
+            if step_num is None
+            else jax.profiler.StepTraceAnnotation(SPAN_PREFIX + name, step_num=step_num)
+        )
+
+    def __enter__(self):
+        open_spans = self._tier._open_spans()
+        self._parent = open_spans[-1] if open_spans else ""
+        open_spans.append(SPAN_PREFIX + self._name)
+        self._ann.__enter__()
+        self._t0 = time.monotonic_ns()
+        return self
+
+    def __exit__(self, *exc):
+        t1 = time.monotonic_ns()
+        self._ann.__exit__(*exc)
+        self._tier._open_spans().pop()
+        self._tier._close(self._name, self._t0, t1, self._parent, self._attrs)
+        return False
 
 
 class PodTier:
-    """The pod tier's registry and what feeds it: ``PodTrainer.step`` stamps
-    the step it is in and counts it when it returns; one ``jax.monitoring``
-    listener counts backend compilations (a load from the persistent cache
-    is one too, and its seconds are kept apart) and stamps each with that
-    step, so "which step recompiled" is a gauge an operator reads."""
+    """The pod tier's registry, its host spans, and what feeds them.
+
+    *Counters.* ``PodTrainer.step`` stamps the step it is in and counts it
+    when it returns; one ``jax.monitoring`` listener counts backend
+    compilations (a load from the persistent cache is one too, and its
+    seconds are kept apart) and stamps each with that step, so "which step
+    recompiled" is a gauge an operator reads.
+
+    *Spans.* ``with pod_tier().span("train.step", step_num=n, program="sync"):``
+    is a host span ``st:train.step``: the same ``jax.profiler`` annotation
+    as ever (on the profiler's clock beside the device's operations when a
+    trace is being taken), two reads of CLOCK_MONOTONIC, and at its end one
+    event in the process's flight recorder, ``obs.hub()``: ``name`` the
+    span's, ``t_ns`` its end, ``arg`` its duration in ns (start = ``t_ns -
+    arg``), ``extra`` :attr:`step_now` (what every span of one step
+    shares), ``detail`` ``parent=<the span open around it on this thread>``
+    and the attributes as ``key=value``. With ``ST_OBS=0`` the event is
+    left out; the annotation and the two series ``st_pod_span_seconds_total{span=}``
+    / ``st_pod_span_calls_total{span=}`` stay. A program's build is three
+    such events made from JAX's own (``st:build.trace``, ``.lower``,
+    ``.compile``, ``program=<the jitted function's name>``), children of
+    whatever span is open on the thread that builds; a phase inside another
+    (``multiply`` traced inside ``sync_step``'s trace) is the outer one's. A
+    collection of Python's cyclic collector that lasts 1 ms or more is an
+    event ``st:gc`` (``generation=``), every one adds to
+    ``st_pod_gc_seconds_total``, and while it runs a ``st:gc`` annotation is
+    open for a trace to show.
+
+    *Getting them out.* :meth:`spans` (rows), :meth:`span_table` (per name:
+    calls, total and self seconds); ``hub().export_timeline(path)`` and this
+    module's ``--timeline path`` for another process; ``hub().dump(reason)``
+    carries the registry too. The recorder keeps the newest events
+    (``ST_OBS_RECORDER_EVENTS``, 4 096 unless set): a long run's set-up
+    spans roll out of it, the two series do not."""
 
     def __init__(self):
+        from .. import obs
         from ..obs.registry import Registry
         from ..obs.schema import SCHEMA, label_key
 
@@ -100,6 +184,40 @@ class PodTier:
         self._last_compile_step = instrument(
             self.registry.gauge, "st_pod_last_compile_step"
         )
+        self._trace_s = instrument(self.registry.counter, "st_pod_trace_seconds_total")
+        self._lower_s = instrument(self.registry.counter, "st_pod_lower_seconds_total")
+        # spans: the recorder they go to, this thread's open ones, the series
+        self._obs = obs
+        self._hub = obs.hub()
+        self._hub.register_registry("pod", self.registry)
+        self._thread = threading.local()
+        self._span_ns: dict[str, int] = {}
+        self._span_calls: dict[str, int] = {}
+
+        def span_series() -> dict:
+            with self._mu:
+                ns, calls = dict(self._span_ns), dict(self._span_calls)
+            return {
+                **{label_key("st_pod_span_seconds_total", "span", k): v / 1e9
+                   for k, v in ns.items()},
+                **{label_key("st_pod_span_calls_total", "span", k): v
+                   for k, v in calls.items()},
+            }
+
+        self.registry.register_collector(span_series)
+        jax.monitoring.register_event_time_span_listener(self._on_time_span)
+        # the collector's callback runs wherever an allocation lands, inside
+        # the recorder's lock too: it touches plain attributes only, and a
+        # pause's event waits in _gc_pending for the next span to log it
+        self._gc_ns = 0
+        self._gc_max_ns = 0
+        self._gc_open = None
+        self._gc_pending: list = []
+        self.registry.register_collector(lambda: {
+            "st_pod_gc_seconds_total": self._gc_ns / 1e9,
+            "st_pod_gc_pause_seconds_max": self._gc_max_ns / 1e9,
+        })
+        gc.callbacks.append(self._on_gc)
         jax.monitoring.register_event_duration_secs_listener(self._on_duration)
         self._trainer = lambda: None
         self.registry.register_collector(self._moe)
@@ -221,6 +339,162 @@ class PodTier:
             self._last_compile_step.set(self.step_now)
         elif event == CACHE_LOAD_EVENT:
             self._cache_load_s.inc(max(0.0, seconds))
+
+    # --- host spans -----------------------------------------------------------
+
+    def span(self, name: str, step_num: int | None = None, **attrs) -> _Span:
+        """``with pod_tier().span(name, **attrs):`` is the host span
+        ``st:<name>`` (the class docstring says what it keeps and where).
+        ``step_num`` makes its annotation a ``StepTraceAnnotation``: the
+        profiler's step marker."""
+        return _Span(self, name, step_num, attrs)
+
+    def _open_spans(self) -> list:
+        """This thread's open spans by name, outermost first."""
+        try:
+            return self._thread.spans
+        except AttributeError:
+            self._thread.spans = []
+            return self._thread.spans
+
+    def _close(self, name: str, t0_ns: int, t1_ns: int, parent: str, attrs: dict) -> None:
+        """One finished span: into the two series, and into the recorder."""
+        with self._mu:
+            self._span_ns[name] = self._span_ns.get(name, 0) + t1_ns - t0_ns
+            self._span_calls[name] = self._span_calls.get(name, 0) + 1
+        if not self._obs.obs_enabled():
+            return
+        self._flush_gc()
+        self._log(SPAN_PREFIX + name, t0_ns, t1_ns, parent, self.step_now, attrs)
+
+    def _log(self, name: str, t0_ns: int, t1_ns: int, parent: str, step: int, attrs: dict) -> None:
+        detail = [f"parent={parent}"] if parent else []
+        detail += [f"{k}={str(v).replace(' ', '_')}" for k, v in attrs.items()]
+        self._hub.emit(
+            name, arg=t1_ns - t0_ns, detail=" ".join(detail), extra=step, t_ns=t1_ns
+        )
+
+    def _on_time_span(self, event: str, start: float, end: float, fun_name: str = "", **_kw) -> None:
+        """A program's trace, lowering or compilation as a child span of
+        whatever is open on this thread (JAX's two times are the wall
+        clock's: their difference is laid back from now). A phase that ends
+        while its thread is still tracing (``multiply`` traced inside
+        ``sync_step``'s trace; what Pallas's interpreter traces inside a
+        lowering) is the outer one's: not logged, not counted."""
+        name = BUILD_SPANS.get(event)
+        if name is None or not jax.core.trace_ctx.is_top_level():
+            return
+        seconds = max(0.0, end - start)
+        if event == TRACE_EVENT:
+            self._trace_s.inc(seconds)
+        elif event == LOWER_EVENT:
+            self._lower_s.inc(seconds)
+        t1 = time.monotonic_ns()
+        program = fun_name[4:-1] if fun_name.startswith("jit(") else fun_name
+        open_spans = self._open_spans()
+        self._close(name, t1 - int(1e9 * seconds), t1,
+                    open_spans[-1] if open_spans else "", {"program": program})
+
+    def _on_gc(self, phase: str, info: dict) -> None:
+        if phase == "start":
+            ann = jax.profiler.TraceAnnotation(GC_SPAN)
+            ann.__enter__()
+            self._gc_open = (time.monotonic_ns(), ann)
+        elif self._gc_open is not None:
+            (t0, ann), self._gc_open = self._gc_open, None
+            t1 = time.monotonic_ns()
+            ann.__exit__(None, None, None)
+            self._gc_ns += t1 - t0
+            self._gc_max_ns = max(self._gc_max_ns, t1 - t0)
+            if t1 - t0 >= GC_EVENT_NS and self._obs.obs_enabled():
+                open_spans = self._open_spans()
+                self._gc_pending.append((
+                    t0, t1, open_spans[-1] if open_spans else "", self.step_now,
+                    {"generation": info.get("generation", -1)},
+                ))
+
+    def _flush_gc(self) -> None:
+        if self._gc_pending:
+            pending, self._gc_pending = self._gc_pending, []
+            for t0, t1, parent, step, attrs in pending:
+                self._log(GC_SPAN, t0, t1, parent, step, attrs)
+
+    def spans(self, since_ns: int | None = None) -> list["Span"]:
+        """The host spans the recorder holds, by start (:func:`span_rows`);
+        ``since_ns`` keeps those that ended at or after that CLOCK_MONOTONIC
+        time. Empty with ``ST_OBS=0``."""
+        self._flush_gc()
+        return span_rows((e.as_dict() for e in self._hub.recorder.timeline()), since_ns)
+
+    def span_table(self) -> dict[str, dict]:
+        """:func:`span_table` of :meth:`spans`."""
+        return span_table(self.spans())
+
+
+class Span(NamedTuple):
+    """One host span, as :func:`span_rows` reads it back."""
+
+    name: str  # "st:train.step"
+    t0_ns: int  # CLOCK_MONOTONIC
+    t1_ns: int
+    parent: str  # the span open around it on its thread, "" for none
+    step: int  # PodTier.step_now when it ended
+    attrs: dict  # program=, generation=
+
+
+def span_rows(timeline: Iterable[dict], since_ns: int | None = None) -> list[Span]:
+    """The ``st:*`` events of a timeline (``Event.as_dict()`` entries: the
+    hub's, or the ``timeline`` list of a file ``hub().export_timeline()``
+    wrote) as :class:`Span` rows, by start and the longer first."""
+    rows = []
+    for e in timeline:
+        if not e["name"].startswith(SPAN_PREFIX) or e.get("tier") != "py":
+            continue
+        if since_ns is not None and e["t_ns"] < since_ns:
+            continue
+        attrs = dict(kv.split("=", 1) for kv in e.get("detail", "").split() if "=" in kv)
+        rows.append(Span(e["name"], e["t_ns"] - e.get("arg", 0), e["t_ns"],
+                         attrs.pop("parent", ""), e.get("extra", 0), attrs))
+    rows.sort(key=lambda r: (r.t0_ns, -r.t1_ns))
+    return rows
+
+
+def span_table(rows: list[Span]) -> dict[str, dict]:
+    """``{name: {"calls", "total_s", "self_s"}}`` of :func:`span_rows`' rows,
+    the largest total first; a span that names a program is listed under
+    ``<name> program=<program>``. Self time is a span's duration minus what
+    its children cover: a row is the child of the nearest row of its
+    ``parent``'s name that was open when it started."""
+    covered: dict[int, list] = {}  # row -> the intervals its children cover
+    open_rows: list[int] = []
+    for i, r in enumerate(rows):
+        open_rows = [j for j in open_rows if rows[j].t1_ns > r.t0_ns]
+        for j in reversed(open_rows):
+            if rows[j].name == r.parent:
+                covered.setdefault(j, []).append((r.t0_ns, min(r.t1_ns, rows[j].t1_ns)))
+                break
+        open_rows.append(i)
+    table: dict[str, dict] = {}
+    for i, r in enumerate(rows):
+        key = f"{r.name} program={r.attrs['program']}" if "program" in r.attrs else r.name
+        t = table.setdefault(key, {"calls": 0, "total_s": 0.0, "self_s": 0.0})
+        t["calls"] += 1
+        t["total_s"] += (r.t1_ns - r.t0_ns) / 1e9
+        # a collection inside a build is a child of the same span as the
+        # build: what two children both cover counts once
+        children = sum(b - a for a, b in _union(covered.get(i, ())))
+        t["self_s"] += (r.t1_ns - r.t0_ns - children) / 1e9
+    return dict(sorted(table.items(), key=lambda kv: -kv[1]["total_s"]))
+
+
+def format_span_table(table: dict[str, dict]) -> str:
+    """:func:`span_table`'s result as the table PERF.md prints."""
+    out = [f"{'calls':>7}  {'total s':>10}  {'self s':>10}  span"]
+    out += [
+        f"{t['calls']:>7}  {t['total_s']:10.3f}  {t['self_s']:10.3f}  {name}"
+        for name, t in table.items()
+    ]
+    return "\n".join(out)
 
 
 _pod_tier: PodTier | None = None
@@ -744,9 +1018,14 @@ def main(argv=None) -> int:
 
     ap = argparse.ArgumentParser(
         prog="python -m shared_tensor_tpu.utils.profiling",
-        description="Device time by st.* scope from a kept profiler trace.",
+        description="Device time by st.* scope from a kept profiler trace, or host "
+        "time by st:* span from an exported timeline.",
     )
-    ap.add_argument("trace_dir", help="what profiling.trace() or run.py --keep-trace wrote")
+    ap.add_argument("trace_dir", nargs="?",
+                    help="what profiling.trace() or run.py --keep-trace wrote")
+    ap.add_argument("--timeline", metavar="FILE",
+                    help="what obs.hub().export_timeline(FILE) wrote: print its host "
+                    "spans by name (calls, total and self seconds) and stop")
     ap.add_argument("--hlo", action="append", default=[], metavar="PATH",
                     help="compiled program text, or an --xla_dump_to directory, for "
                     "the join by instruction name (repeatable)")
@@ -754,6 +1033,13 @@ def main(argv=None) -> int:
     ap.add_argument("--ops", type=int, default=24, help="operations to list")
     ap.add_argument("--json", action="store_true", help="print the whole result as JSON")
     args = ap.parse_args(argv)
+    if args.timeline:
+        with open(args.timeline) as f:
+            table = span_table(span_rows(json.load(f)["timeline"]))
+        print(json.dumps(table) if args.json else format_span_table(table))
+        return 0
+    if not args.trace_dir:
+        ap.error("give a trace directory, or --timeline FILE")
     maps = [scope_map(t) for path in args.hlo for t in hlo_texts(path)]
     table = scope_times(args.trace_dir, maps, steps=args.steps)
     if args.json:

@@ -354,3 +354,237 @@ def test_traced_sync_step_counts_its_codec_kernels():
         jax.tree.map(lambda x: x[:, :4096], state)
     )
     assert counts()[1:] == (1, [1, 1])  # the newest traced table's: 32 rows, one block
+
+
+# --- PR 38: the pod tier's host spans -----------------------------------------------
+
+
+def _spans_since(t_ns, name=None):
+    from shared_tensor_tpu.utils.profiling import pod_tier
+
+    return [r for r in pod_tier().spans(since_ns=t_ns) if name is None or r.name == name]
+
+
+def _series(name, span):
+    from shared_tensor_tpu.obs.schema import label_key
+
+    snap, _ = _pod_counts()
+    return snap.get(label_key(name, "span", span), 0)
+
+
+def test_span_logs_one_event_and_nests():
+    """One event a span: its duration, the step, the span open around it;
+    a parent's self time is its duration minus what its children cover."""
+    from shared_tensor_tpu.utils.profiling import pod_tier, span_table
+
+    pod = pod_tier()
+    t = time.monotonic_ns()
+    pod.step_now = 41
+    with pod.span("t38.outer", program="sync"):
+        time.sleep(0.02)
+        with pod.span("t38.inner"):
+            time.sleep(0.03)
+    pod.step_now = 0
+    rows = [r for r in _spans_since(t) if r.name.startswith("st:t38.")]
+    assert [r.name for r in rows] == ["st:t38.outer", "st:t38.inner"]  # by start
+    outer, inner = rows
+    assert (outer.parent, inner.parent) == ("", "st:t38.outer")
+    assert outer.step == inner.step == 41
+    assert outer.attrs == {"program": "sync"} and inner.attrs == {}
+    assert outer.t0_ns <= inner.t0_ns and inner.t1_ns <= outer.t1_ns
+    assert 0.03e9 <= inner.t1_ns - inner.t0_ns < 3e9
+    assert 0.05e9 <= outer.t1_ns - outer.t0_ns < 6e9
+    table = span_table(rows)
+    assert list(table) == ["st:t38.outer program=sync", "st:t38.inner"]  # largest first
+    o, i = table.values()
+    assert o["calls"] == i["calls"] == 1 and i["self_s"] == i["total_s"]
+    assert o["self_s"] == pytest.approx(o["total_s"] - i["total_s"], abs=1e-9)
+    assert 0.02 <= o["self_s"] < 3
+
+
+def test_span_series_are_in_the_schema_and_the_exposition():
+    from shared_tensor_tpu.obs.schema import SCHEMA
+    from shared_tensor_tpu.utils.profiling import pod_registry, pod_tier
+
+    with pod_tier().span("t38.series"):
+        pass
+    text = pod_registry().prometheus_text()
+    for name in ("st_pod_span_seconds_total", "st_pod_span_calls_total",
+                 "st_pod_trace_seconds_total", "st_pod_lower_seconds_total",
+                 "st_pod_gc_seconds_total", "st_pod_gc_pause_seconds_max"):
+        assert name in SCHEMA and name in text, name
+    assert 'st_pod_span_calls_total{span="t38.series"} 1' in text
+    assert 'st_pod_span_seconds_total{span="t38.series"}' in text
+
+
+def test_span_with_obs_off_logs_nothing_and_still_counts():
+    from shared_tensor_tpu import obs
+    from shared_tensor_tpu.utils.profiling import pod_tier
+
+    pod = pod_tier()
+    t = time.monotonic_ns()
+    calls = _series("st_pod_span_calls_total", "t38.off")
+    was = obs.obs_enabled()
+    obs.set_enabled(False)
+    try:
+        with pod.span("t38.off"):
+            time.sleep(0.01)
+        assert _spans_since(t) == []
+    finally:
+        obs.set_enabled(was)
+    assert _spans_since(t, "st:t38.off") == []  # nor afterwards
+    assert _series("st_pod_span_calls_total", "t38.off") == calls + 1
+    assert _series("st_pod_span_seconds_total", "t38.off") >= 0.01
+
+
+def test_pod_trainer_leaves_its_set_up_and_its_builds_as_spans():
+    t = time.monotonic_ns()
+    tr, batch = _pod_trainer()
+    parent = {r.name: r.parent for r in _spans_since(t) if not r.name.startswith("st:build.")}
+    assert parent["st:trainer_init"] == ""
+    for child in ("st:make_spec", "st:init_state", "st:opt_init", "st:build_train_step"):
+        assert parent[child] == "st:trainer_init", child
+    for child in ("seed", "broadcast", "residual"):
+        assert parent[f"st:init_state.{child}"] == "st:init_state", child
+    assert parent["st:build_sync_step"] == "st:build_train_step"
+    init = _spans_since(t, "st:trainer_init")[0]
+    assert all(init.t0_ns <= r.t0_ns and r.t1_ns <= init.t1_ns
+               for r in _spans_since(t) if r.parent in parent and r.parent)
+    b = batch(4)
+    seen = []
+    for _ in range(3):
+        t = time.monotonic_ns()
+        tr.step(b)
+        seen.append(_spans_since(t))
+    first = {r.name: r for r in seen[0]}
+    step = first["st:train.step"]
+    assert step.attrs == {"program": "sync"} and step.step == 0
+    for phase in ("trace", "lower", "compile"):
+        build = first[f"st:build.{phase}"]
+        assert build.attrs == {"program": "_step"}, build
+        assert build.parent == "st:train.step" and build.step == 0
+        assert step.t0_ns <= build.t0_ns and build.t1_ns <= step.t1_ns
+    third = seen[2]
+    assert [(r.name, r.step) for r in third if r.name != "st:gc"] == [("st:train.step", 2)]
+
+
+def test_a_nested_trace_counts_once():
+    """``inner`` is traced inside ``outer``'s trace: one ``st:build.trace``
+    event, ``outer``'s, and its seconds once in the counter."""
+    import jax
+
+    from shared_tensor_tpu.utils.profiling import pod_tier
+
+    pod_tier()  # the listeners exist from here
+
+    @jax.jit
+    def inner(x):
+        time.sleep(0.05)
+        return x + 1
+
+    @jax.jit
+    def outer(x):
+        return inner(x) * 2
+
+    x = jnp.ones(8)  # built before the reading starts
+    snap, _ = _pod_counts()
+    t = time.monotonic_ns()
+    outer(x).block_until_ready()
+    traces = [r for r in _spans_since(t, "st:build.trace")]
+    assert [r.attrs["program"] for r in traces] == ["outer"]
+    traced_s = (traces[0].t1_ns - traces[0].t0_ns) / 1e9
+    assert traced_s >= 0.05
+    after, _ = _pod_counts()
+    counted = after["st_pod_trace_seconds_total"] - snap["st_pod_trace_seconds_total"]
+    assert counted == pytest.approx(traced_s, abs=0.01)  # twice would be 0.05 more
+    assert after["st_pod_lower_seconds_total"] > snap["st_pod_lower_seconds_total"]
+
+
+def test_a_new_batch_shape_logs_its_build_at_that_step():
+    tr, batch = _pod_trainer()
+    small, large = batch(4), batch(6)
+    for _ in range(3):
+        tr.step(small)
+    t = time.monotonic_ns()
+    tr.step(large)  # step number 3 builds the program again
+    builds = [r for r in _spans_since(t) if r.name.startswith("st:build.")]
+    assert {r.name for r in builds} == {"st:build.trace", "st:build.lower", "st:build.compile"}
+    assert all(r.step == 3 and r.parent == "st:train.step" for r in builds)
+    snap, _ = _pod_counts()
+    assert snap["st_pod_last_compile_step"] == 3
+
+
+def test_a_pause_between_two_steps_is_the_gap_between_their_spans():
+    tr, batch = _pod_trainer()
+    b = batch(4)
+    tr.step(b)  # builds
+    t = time.monotonic_ns()
+    tr.step(b)
+    time.sleep(0.05)
+    tr.step(b)
+    one, two = _spans_since(t, "st:train.step")
+    assert (one.step, two.step) == (1, 2)
+    assert 0.05e9 <= two.t0_ns - one.t1_ns < 3e9
+
+
+def test_a_collection_is_an_event_and_raises_the_gauge():
+    import gc
+
+    from shared_tensor_tpu.utils.profiling import pod_tier
+
+    pod = pod_tier()
+    snap, _ = _pod_counts()
+    cycle = [[] for _ in range(1_000_000)]
+    for a, b in zip(cycle, cycle[1:]):
+        a.append(b)
+    cycle[-1].append(cycle[0])
+    t = time.monotonic_ns()
+    with pod.span("t38.collect"):
+        del cycle, a, b
+        gc.collect()
+    pauses = _spans_since(t, "st:gc")
+    assert pauses and all(r.parent == "st:t38.collect" for r in pauses)
+    longest = max(r.t1_ns - r.t0_ns for r in pauses)
+    assert longest >= 1_000_000
+    assert any(r.attrs == {"generation": "2"} for r in pauses)
+    after, _ = _pod_counts()
+    assert after["st_pod_gc_pause_seconds_max"] >= longest / 1e9
+    assert after["st_pod_gc_seconds_total"] >= snap["st_pod_gc_seconds_total"] + longest / 1e9
+
+
+def test_build_sync_step_logs_its_span():
+    from shared_tensor_tpu.ops.table import make_spec
+    from shared_tensor_tpu.parallel import build_sync_step, init_state, make_mesh
+
+    mesh = make_mesh(2, 1)
+    spec = make_spec({"w": jnp.zeros(4096)})
+    t = time.monotonic_ns()
+    step = build_sync_step(mesh, spec)
+    state = init_state(mesh, spec)
+    names = [r.name for r in _spans_since(t)]
+    assert names[0] == "st:build_sync_step" and "st:init_state" in names
+    assert "st:init_state.residual" in names and "st:init_state.seed" not in names
+    t = time.monotonic_ns()
+    step(state)
+    builds = {r.name: r.attrs for r in _spans_since(t) if r.name.startswith("st:build.")}
+    assert builds == {f"st:build.{p}": {"program": "sync_step"} for p in ("trace", "lower", "compile")}
+
+
+def test_timeline_option_prints_the_span_table(tmp_path, capsys):
+    from shared_tensor_tpu import obs
+    from shared_tensor_tpu.utils.profiling import main, pod_tier
+
+    pod = pod_tier()
+    with pod.span("t38.exported"):
+        with pod.span("t38.exported.child"):
+            time.sleep(0.01)
+    path = obs.hub().export_timeline(str(tmp_path / "timeline.json"))
+    assert main(["--timeline", path]) == 0
+    out = capsys.readouterr().out.splitlines()
+    assert out[0].split() == ["calls", "total", "s", "self", "s", "span"]
+    rows = {l.split()[-1]: l.split() for l in out[1:]}
+    assert rows["st:t38.exported"][0] == "1"
+    assert float(rows["st:t38.exported.child"][1]) >= 0.01
+    assert float(rows["st:t38.exported"][2]) < float(rows["st:t38.exported"][1])
+    with pytest.raises(SystemExit):
+        main([])
