@@ -27,7 +27,7 @@ head, loss and the sequence loop ``swa_moe.loss_fn``'s (given this trunk).
 What is new is the frequency law, the router and the layer plan. Precision is
 theirs: bfloat16 operands with float32 accumulation; router, norms, RoPE,
 softmax, gate, loss and the residual stream float32. Each layer runs under
-``jax.checkpoint`` and keeps its attention's output.
+``mla_moe.layer_checkpoint`` and keeps its attention's q, k, v and output.
 """
 
 from __future__ import annotations
@@ -44,7 +44,7 @@ from jax import lax
 
 from . import swa_moe
 from .mla_moe import (
-    ATTN_OUT, _layer, _router_logits, _sub, moe, rms_norm, rope_tables, swiglu,
+    _layer, _router_logits, _sub, layer_checkpoint, moe, rms_norm, rope_tables, swiglu,
 )
 
 _PERIOD = ("full_attention", "sliding_attention", "sliding_attention", "sliding_attention")
@@ -253,9 +253,7 @@ def trunk(params: dict, tokens: jax.Array, cfg: Config):
         x = params["model.embed_tokens.weight"][tokens]
     auxes = []
     for i, kind in enumerate(kinds):
-        # recomputed in the backward pass but for its attention's output
-        fn = jax.checkpoint(partial(block, cfg=cfg, layer=i),
-                            policy=jax.checkpoint_policies.save_only_these_names(ATTN_OUT))
+        fn = layer_checkpoint(partial(block, cfg=cfg, layer=i))
         x, aux = fn(_sub(params, _layer(i)), x, ropes[kind])
         auxes += [aux] if aux is not None else []
     return x, auxes

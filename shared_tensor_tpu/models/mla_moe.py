@@ -52,7 +52,19 @@ from jax.custom_batching import custom_vmap
 from ..ops import attention_pallas, moe_pallas
 from ..utils.profiling import pod_tier
 
-ATTN_OUT = "attn_out"  # the residual a layer's checkpoint keeps
+#: what a layer's checkpoint keeps, written once for every decoder: its
+#: attention's ``o`` and ``lse`` and, heads first as the backward rule takes
+#: them, its ``q``, ``k`` and ``v`` (:func:`_attention_tiles_fwd` names them)
+ATTN_OUT, ATTN_QKV = "attn_out", "attn_qkv"
+
+
+def layer_checkpoint(fn):
+    """``fn`` (one decoder layer) under ``jax.checkpoint``: recomputed in the
+    backward pass but for its attention's residuals, so neither the attention
+    nor what makes q, k and v (their products, RoPE, the head-first layout)
+    runs a second time."""
+    return jax.checkpoint(
+        fn, policy=jax.checkpoint_policies.save_only_these_names(ATTN_OUT, ATTN_QKV))
 
 
 @dataclasses.dataclass(frozen=True)
@@ -365,8 +377,10 @@ def _attention_tiles(q, k, v, block: int | None, window: int | None, scope: str)
 
 
 def _attention_tiles_fwd(q, k, v, block, window, scope):
-    # what a layer's checkpoint keeps (64 MB + 1 MB a layer at 8 192 tokens),
-    # so that recomputing the layer does not run the attention a third time
+    # the residuals are what a layer's checkpoint keeps (:func:`layer_checkpoint`;
+    # :func:`_saved_bytes` of them a layer), so that recomputing the layer runs
+    # neither the attention a third time nor q's, k's and v's making a second
+    q, k, v = (checkpoint_name(a, ATTN_QKV) for a in (q, k, v))
     o, lse = (checkpoint_name(a, ATTN_OUT) for a in _attention_o_lse(q, k, v, block, window))
     return o, (q, k, v, o, lse)
 
@@ -379,6 +393,14 @@ def _attention_tiles_bwd(block, window, scope, res, g):
 
 
 _attention_tiles.defvjp(_attention_tiles_fwd, _attention_tiles_bwd)
+
+
+def _saved_bytes(q, k, v) -> int:
+    """Bytes of :func:`_attention_tiles_fwd`'s residuals ``(q, k, v, o, lse)``
+    by shape and dtype (the device pads a last dimension to whole lanes)."""
+    o = jax.ShapeDtypeStruct((*q.shape[:2], v.shape[-1]), v.dtype)
+    lse = jax.ShapeDtypeStruct(q.shape[:2], jnp.float32)
+    return sum(a.size * a.dtype.itemsize for a in (q, k, v, o, lse))
 
 
 def causal_attention(q, k, v, block: int, window: int | None = None,
@@ -405,7 +427,8 @@ def causal_attention(q, k, v, block: int, window: int | None = None,
     Which one was traced is counted (``st_attn_traces_total{path}`` and
     ``{kind}``), and the tiles its forward pass lists
     (``st_attn_tiles_listed{kind}``) for how many query heads
-    (``st_attn_heads{kind}``)."""
+    (``st_attn_heads{kind}``), and the bytes it names for its layer's
+    checkpoint (``st_attn_saved_bytes{kind}``)."""
     n = q.shape[0]
     window = window if window is not None and window < n else None
     q, k, v = (jnp.swapaxes(a, 0, 1) for a in (q, k, v))  # heads first
@@ -415,7 +438,8 @@ def causal_attention(q, k, v, block: int, window: int | None = None,
     bq, bk = (block, block) if block else attention_pallas._fwd_tiles(n)
     pod_tier().count_attention_trace(
         "scan" if block else "pallas", "full" if window is None else "window",
-        len(attention_pallas.tile_list(n, bq, bk, False, window)), heads=q.shape[0])
+        len(attention_pallas.tile_list(n, bq, bk, False, window)), heads=q.shape[0],
+        saved_bytes=_saved_bytes(q, k, v))
     return jnp.swapaxes(_attention_tiles(q, k, v, block, window, scope), 0, 1)
 
 
@@ -757,9 +781,8 @@ def block(p: dict, x: jax.Array, rope, cfg: Config, is_moe: bool):
 
 def _block(params: dict, i: int, x, rope, cfg: Config):
     """Layer ``i``, recomputed in the backward pass but for its attention's
-    output."""
-    fn = jax.checkpoint(partial(block, cfg=cfg, is_moe=cfg.is_moe(i)),
-                        policy=jax.checkpoint_policies.save_only_these_names(ATTN_OUT))
+    residuals."""
+    fn = layer_checkpoint(partial(block, cfg=cfg, is_moe=cfg.is_moe(i)))
     return fn(_sub(params, _layer(i)), x, rope)
 
 

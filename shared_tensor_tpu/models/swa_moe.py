@@ -21,8 +21,8 @@ window and fewer K/V heads than query heads), the dropless expert loop (told
 which experts it holds, routing over all of them), the cross-entropy in
 blocks of tokens. Precision is ``mla_moe``'s too: bfloat16 operands with
 float32 accumulation; router, norms, RoPE, softmax, loss and the residual
-stream float32. Each layer runs under ``jax.checkpoint`` and keeps its
-attention's output.
+stream float32. Each layer runs under ``mla_moe.layer_checkpoint`` and keeps
+its attention's q, k, v and output.
 """
 
 from __future__ import annotations
@@ -36,8 +36,8 @@ import jax.numpy as jnp
 from jax import lax
 
 from .mla_moe import (
-    ATTN_OUT, _layer, _mm, _router_logits, _sub, causal_attention, expert_counters,
-    head_logits, head_loss, held_experts, rms_norm, rope_tables,
+    _layer, _mm, _router_logits, _sub, causal_attention, expert_counters, head_logits,
+    head_loss, held_experts, layer_checkpoint, rms_norm, rope_tables,
 )
 
 _PERIOD = (0, 1, 1, 1)
@@ -225,9 +225,7 @@ def trunk(params: dict, tokens: jax.Array, cfg: Config):
         x = params["model.embed_tokens.weight"][tokens]
     auxes = []
     for i in range(cfg.num_hidden_layers):
-        # recomputed in the backward pass but for its attention's output
-        fn = jax.checkpoint(partial(block, cfg=cfg, window=cfg.window(i)),
-                            policy=jax.checkpoint_policies.save_only_these_names(ATTN_OUT))
+        fn = layer_checkpoint(partial(block, cfg=cfg, window=cfg.window(i)))
         x, aux = fn(_sub(params, _layer(i)), x, rope if cfg.rope_layout[i] else None)
         auxes.append(aux)
     return x, auxes

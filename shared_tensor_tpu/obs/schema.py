@@ -221,6 +221,7 @@ SCHEMA: dict[str, tuple[str, str]] = {
     "st_attn_traces_total": ("counter", "traced calls of causal attention, each counted under two single-label series (per-path label: pallas = the fused kernels of ops/attention_pallas.py | scan = the portable tile loop; per-kind label: full = the whole causal triangle | window = the band of a sliding window)"),
     "st_attn_tiles_listed": ("gauge", "tiles the forward pass of the newest traced causal attention lists (per-kind label: full | window): the band against the triangle"),
     "st_attn_heads": ("gauge", "query heads of the newest traced causal attention (per-kind label: full | window): a model may give its window layers more heads than its full ones"),
+    "st_attn_saved_bytes": ("gauge", "bytes of q, k, v, o and lse (by shape and dtype) the newest traced causal attention names for its layer's checkpoint (per-kind label: full | window): times the layer plan, what the checkpoint policy holds from the forward pass to the backward"),
     # the expert loop's combine (models/mla_moe.py): which path a traced
     # add of a tile's rows took, decided at trace time (backend, the
     # accumulator's shape)

@@ -222,23 +222,20 @@ class PodTier:
         self._trainer = lambda: None
         self.registry.register_collector(self._moe)
         # one count a traced call under each of its two labels, and the
-        # newest traced call's tile count a kind
+        # newest traced call's gauges a kind
         self._attn_traces = {
             ("path", "pallas"): 0, ("path", "scan"): 0, ("kind", "full"): 0, ("kind", "window"): 0,
         }
-        self._attn_tiles = {"full": 0, "window": 0}
-        self._attn_heads = {"full": 0, "window": 0}
+        self._attn_newest = {
+            (name, kind): 0
+            for name in ("st_attn_tiles_listed", "st_attn_heads", "st_attn_saved_bytes")
+            for kind in ("full", "window")
+        }
         attn_keys = {lv: label_key("st_attn_traces_total", *lv) for lv in self._attn_traces}
-        tiles_keys = {
-            kind: label_key("st_attn_tiles_listed", "kind", kind) for kind in self._attn_tiles
-        }
-        heads_keys = {
-            kind: label_key("st_attn_heads", "kind", kind) for kind in self._attn_heads
-        }
+        newest_keys = {nk: label_key(nk[0], "kind", nk[1]) for nk in self._attn_newest}
         self.registry.register_collector(lambda: {
             **{attn_keys[lv]: n for lv, n in self._attn_traces.items()},
-            **{tiles_keys[kind]: n for kind, n in self._attn_tiles.items()},
-            **{heads_keys[kind]: n for kind, n in self._attn_heads.items()},
+            **{newest_keys[nk]: n for nk, n in self._attn_newest.items()},
         })
 
         self._combine_traces = {"pallas": 0, "xla": 0}
@@ -296,19 +293,25 @@ class PodTier:
         with self._mu:
             self._steps[synced] += 1
 
-    def count_attention_trace(self, path: str, kind: str, tiles: int, heads: int) -> None:
+    def count_attention_trace(
+        self, path: str, kind: str, tiles: int, heads: int, saved_bytes: int
+    ) -> None:
         """One traced call of ``models/mla_moe.py``'s causal attention: the
         path it took, ``pallas`` (the fused kernels) or ``scan``; its kind,
         ``full`` (the whole causal triangle) or ``window`` (a band of it);
         the tiles its forward pass lists (the band against the triangle);
-        and its query ``heads`` (a
-        model may give its layer kinds different counts). The choice is made
+        its query ``heads`` (a
+        model may give its layer kinds different counts); and the
+        ``saved_bytes`` of ``q, k, v, o, lse`` it names for its layer's
+        checkpoint (times the layer plan: what the policy holds from the
+        forward pass to the backward). The choice is made
         while a program is traced, so this counts traces, not steps."""
         with self._mu:
             self._attn_traces["path", path] += 1
             self._attn_traces["kind", kind] += 1
-            self._attn_tiles[kind] = tiles
-            self._attn_heads[kind] = heads
+            self._attn_newest["st_attn_tiles_listed", kind] = tiles
+            self._attn_newest["st_attn_heads", kind] = heads
+            self._attn_newest["st_attn_saved_bytes", kind] = saved_bytes
 
     def count_combine_trace(self, path: str) -> None:
         """One traced add of a tile's rows into an expert loop's accumulator

@@ -280,6 +280,23 @@ def test_a_layers_heads_follow_num_attention_heads_per_layer():
     assert 'st_attn_heads{kind="window"} 6' in pod_registry().prometheus_text()
 
 
+def test_the_bytes_a_layers_checkpoint_keeps_of_its_attention_are_a_gauge_by_kind():
+    """``st_attn_saved_bytes{kind}``: q, k, v, o (the operands' dtype) and lse
+    (float32) of the newest traced call, heads first: at 128 tokens and heads
+    of 16, 4 query heads on 2 K/V heads over the prefix, 6 on 2 under the
+    window."""
+    def saved(dtype):
+        cfg = config((4, 4), 128, dtype=dtype)
+        jax.eval_shape(lambda p, b: M.loss_fn(p, b, cfg), *inputs((4, 4), 128))
+        snap = pod_registry().snapshot()
+        return {k: snap[label_key("st_attn_saved_bytes", "kind", k)] for k in ("full", "window")}
+
+    want = lambda heads, size: (heads + 2 + 2 + heads) * T * 16 * size + heads * T * 4
+    assert saved("bfloat16") == {"full": want(4, 2), "window": want(6, 2)}
+    assert f'st_attn_saved_bytes{{kind="window"}} {want(6, 2)}' in pod_registry().prometheus_text()
+    assert saved("float32") == {"full": want(4, 4), "window": want(6, 4)}  # the scan's own programs
+
+
 def test_router_weights_are_the_renormalised_top_of_a_softmax_over_all_experts():
     cfg = config()
     w_r = jax.random.normal(jax.random.key(2), (16, 64))

@@ -2,6 +2,8 @@
 joyai_ref.py) on seeded random weights at a small preset, and through
 PodTrainer: the eight checks ISSUE 29 lists."""
 
+import collections
+import dataclasses
 import functools
 import json
 import os
@@ -12,6 +14,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
+from jax.extend import core as jex_core
 
 ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 sys.path.insert(0, ROOT)
@@ -570,3 +573,112 @@ def test_published_widths_give_the_checkpoints_209_leaves():
         assert shapes[name].shape == shape, name
     assert "model.layers.1.mlp.experts.8.up_proj.weight" not in shapes
     assert "model.layers.0.mlp.gate.weight" not in shapes  # layer 0 is dense
+
+
+# --- what a layer's checkpoint keeps, in all three decoders --------------------
+
+
+def _decoder(name):
+    """A decoder at its cell's rehearsal size: the module, its configuration,
+    seeded weights and one sequence; and one expert layer of it (layer 1) as
+    ``block(p, x, rope)`` with its leaves, input and tables, the name of the
+    q projection's weight and the ``[heads, T, width]`` of its q, k and v."""
+    from chipbench.jobs import train_decoder
+    from shared_tensor_tpu.models import gated_swa_moe, swa_moe
+
+    mod, file, t = {
+        "mla_moe": (M, "joyai-llm-flash", 64),
+        "swa_moe": (swa_moe, "smallthinker-21b-a3b", 128),
+        "gated_swa_moe": (gated_swa_moe, "laguna-s-2.1", 128),
+    }[name]
+    with open(os.path.join(ROOT, "chipbench", "configs", file + ".json")) as f:
+        preset = json.load(f)["rehearsal"]["model"]
+    if mod is M:
+        cfg = train_lm.model_config(preset)
+        block = partial(M.block, cfg=cfg, is_moe=True)
+        rope = M.rope_tables(t, cfg.qk_rope_head_dim, cfg.rope_theta)
+        h, dq = cfg.num_attention_heads, cfg.qk_nope_head_dim + cfg.qk_rope_head_dim
+        wq, qkv = "self_attn.q_b_proj.weight", [(h, t, dq), (h, t, dq), (h, t, cfg.v_head_dim)]
+    else:
+        cfg = train_decoder.model_config(mod, preset)
+        if mod is swa_moe:
+            block = partial(mod.block, cfg=cfg, window=cfg.window(1))
+            rope, h = M.rope_tables(t, cfg.head_dim, cfg.rope_theta), cfg.num_attention_heads
+        else:
+            block = partial(mod.block, cfg=cfg, layer=1)
+            rope, h = mod.layer_rope(cfg, cfg.layer_types[1], t), cfg.heads(1)
+        kv = (cfg.num_key_value_heads, t, cfg.head_dim)
+        wq, qkv = "self_attn.q_proj.weight", [(h, t, cfg.head_dim), kv, kv]
+    params = mod.init_params(jax.random.key(0), cfg)
+    tokens = jax.random.randint(jax.random.key(1), (1, t), 0, cfg.vocab_held)
+    x = jax.random.normal(jax.random.key(2), (t, cfg.hidden_size))
+    layer = (block, M._sub(params, M._layer(1)), x, rope, wq, qkv)
+    return mod, cfg, params, tokens, layer
+
+
+def _keeping_the_output_alone(fn):
+    """The layer checkpoint of before: ``o`` and ``lse``, nothing of q, k, v."""
+    return jax.checkpoint(fn, policy=jax.checkpoint_policies.save_only_these_names(M.ATTN_OUT))
+
+
+@pytest.mark.parametrize("dtype", ["bfloat16", "float32"])
+@pytest.mark.parametrize("name", ["mla_moe", "swa_moe", "gated_swa_moe"])
+def test_keeping_q_k_v_leaves_every_gradient_as_it_was_to_the_bit(name, dtype, monkeypatch):
+    mod, cfg, params, tokens, _ = _decoder(name)
+    cfg = dataclasses.replace(cfg, compute_dtype=dtype)
+    grad = lambda: jax.jit(jax.value_and_grad(
+        lambda p, b: mod.loss_fn(p, b, cfg), has_aux=True))(params, tokens)
+    (loss, _), grads = grad()
+    layers = []  # every layer of the second program goes through the checkpoint of before
+    monkeypatch.setattr(mod, "layer_checkpoint",
+                        lambda fn: layers.append(fn) or _keeping_the_output_alone(fn))
+    (loss_was, _), grads_was = grad()
+    assert len(layers) == getattr(cfg, "n_blocks", cfg.num_hidden_layers)
+    assert float(loss) == float(loss_was) and np.isfinite(float(loss))
+    for leaf in grads:
+        np.testing.assert_array_equal(grads[leaf], grads_was[leaf], err_msg=leaf)
+    assert any(float(jnp.max(jnp.abs(g))) > 0 for g in grads.values())
+
+
+def _product_reads(jaxpr, read) -> int:
+    """The products (``dot_general``) of ``jaxpr``, nested programs included,
+    that take one of the variables ``read`` or what other operations than a
+    product make of them (a cast, a transposition)."""
+    read, n = set(read), 0
+    for eqn in jaxpr.eqns:
+        hit = [i for i, v in enumerate(eqn.invars) if isinstance(v, jex_core.Var) and v in read]
+        if not hit:
+            continue
+        inner = [getattr(j, "jaxpr", j) for j in eqn.params.values()
+                 if hasattr(getattr(j, "jaxpr", j), "eqns")]
+        if eqn.primitive.name == "dot_general":
+            n += 1
+        elif inner:
+            assert all(len(sub.invars) == len(eqn.invars) for sub in inner), eqn.primitive
+            n += sum(_product_reads(sub, [sub.invars[i] for i in hit]) for sub in inner)
+        else:
+            read.update(eqn.outvars)
+    return n
+
+
+@pytest.mark.parametrize("name", ["mla_moe", "swa_moe", "gated_swa_moe"])
+def test_a_layers_checkpoint_keeps_q_k_v_and_their_making_runs_once(name, capsys):
+    *_, (block, p, x, rope, wq, qkv) = _decoder(name)
+
+    def kept(wrap):
+        jax.ad_checkpoint.print_saved_residuals(lambda p, x: wrap(block)(p, x, rope)[0], p, x)
+        return collections.Counter(line.split()[0] for line in capsys.readouterr().out.splitlines())
+
+    # beside o and lse and the layer's arguments: q, k, v, heads first as the
+    # backward rule takes them, and nothing else
+    more = kept(M.layer_checkpoint) - kept(_keeping_the_output_alone)
+    assert more == collections.Counter(f"bf16[{h},{t},{d}]" for h, t, d in qkv)
+    assert not kept(_keeping_the_output_alone) - kept(M.layer_checkpoint)
+
+    def reads_of_wq(wrap):
+        jaxpr = jax.make_jaxpr(jax.grad(lambda p, x: jnp.sum(wrap(block)(p, x, rope)[0])))(p, x).jaxpr
+        return _product_reads(jaxpr, [jaxpr.invars[sorted(p).index(wq)]])
+
+    # the forward product and the cotangent's way back read the weight (a
+    # third product makes its gradient); the recomputed layer read it again
+    assert (reads_of_wq(M.layer_checkpoint), reads_of_wq(_keeping_the_output_alone)) == (2, 3)

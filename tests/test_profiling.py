@@ -295,7 +295,7 @@ def test_pod_counters_are_in_the_schema_and_the_exposition():
     names = (
         "st_pod_steps_total", "st_pod_compiles_total", "st_pod_compile_seconds_total",
         "st_pod_cache_load_seconds_total", "st_pod_last_compile_step",
-        "st_attn_traces_total", "st_codec_kernel_traces_total",
+        "st_attn_traces_total", "st_attn_saved_bytes", "st_codec_kernel_traces_total",
         "st_codec_leaves_per_block_max", "st_codec_words_rows_per_block",
         "st_moe_combine_traces_total",
     )
@@ -307,6 +307,8 @@ def test_pod_counters_are_in_the_schema_and_the_exposition():
     # a trace-time counter: both paths are there from the start, at 0 or more
     assert 'st_attn_traces_total{path="pallas"}' in text
     assert 'st_attn_traces_total{path="scan"}' in text
+    assert 'st_attn_saved_bytes{kind="full"}' in text
+    assert 'st_attn_saved_bytes{kind="window"}' in text
     assert 'st_codec_kernel_traces_total{kernel="quantize_rows"}' in text
     assert 'st_codec_kernel_traces_total{kernel="apply_rows_batch"}' in text
     assert 'st_codec_words_rows_per_block{kernel="quantize_rows"}' in text
