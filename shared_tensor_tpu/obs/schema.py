@@ -198,6 +198,7 @@ SCHEMA: dict[str, tuple[str, str]] = {
     "st_pod_compile_seconds_total": ("counter", "seconds in those compilations"),
     "st_pod_cache_load_seconds_total": ("counter", "seconds retrieving executables from the persistent compilation cache"),
     "st_pod_last_compile_step": ("gauge", "PodTrainer.steps when the latest compilation happened"),
+    "st_table_tiled_share": ("gauge", "share of the newest PodTrainer table's live elements held in 8 x 128 tile order (ops/table.py tiled: leaves the step reads and writes back as bitcasts on the TPU), set when the trainer is built"),
     # r38 the pod tier's host spans (utils/profiling.py PodTier.span): each
     # span is one event in the process's flight recorder (obs.hub()) and one
     # addition to these two series, which count with ST_OBS=0 too. A program

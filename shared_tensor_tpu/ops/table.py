@@ -8,7 +8,13 @@ README.md:41; measured in BASELINE.md: 1000:1 mix leaves the small half at 24%
 error after 48 frames). This module provides that capability natively:
 
 - A pytree is flattened into ONE padded flat buffer, each leaf padded to a
-  whole (8,128)-tile multiple so leaf boundaries are row-aligned.
+  whole (8,128)-tile multiple so leaf boundaries are row-aligned. Leaves
+  lie row-major, the order the host tiers and the wire keep; a spec made
+  with ``tile_order=True`` (the pod tier's device table) holds each leaf
+  whose two minor dimensions are whole 8 x 128 tiles (:func:`tiled`) in the
+  order of its tiles, which is how the TPU lays such an array out: the train
+  step's view of the leaf and its gradient's way back are then bitcasts,
+  not relayouts of the table.
 - Quantization computes an independent power-of-2 RMS scale per leaf
   (dense reductions over the leaves' static row ranges, :func:`leaf_reduce`),
   then runs the same sign/error-feedback rule with a per-row scale — still a
@@ -34,6 +40,7 @@ from ..config import ScalePolicy
 from .codec import SAT, pad_flat, pow2_floor
 from .packing import (
     LANES,
+    SUBLANES,
     TILE,
     dense_words,
     flat_words,
@@ -60,13 +67,19 @@ class TableSpec:
 
     Hashable (all-tuple fields) so it can be a jit static argument. Leaf i
     occupies flat rows [row_offsets[i], row_offsets[i] + padded[i]//128) with
-    ns[i] live elements.
+    ns[i] live elements, in its 8 x 128 tile order where ``tiled[i]`` and
+    row-major otherwise (all row-major unless given).
     """
 
     treedef: Any
     shapes: tuple[tuple[int, ...], ...]
     ns: tuple[int, ...]  # true element count per leaf
     padded: tuple[int, ...]  # padded length per leaf (tile multiple)
+    tiled: tuple[bool, ...] = ()  # leaf held in its 8 x 128 tile order
+
+    def __post_init__(self):
+        if not self.tiled:
+            object.__setattr__(self, "tiled", (False,) * len(self.ns))
 
     @property
     def num_leaves(self) -> int:
@@ -80,15 +93,29 @@ class TableSpec:
     def total_n(self) -> int:
         return sum(self.ns)
 
+    @property
+    def tiled_share(self) -> float:
+        """Share of the live elements held in tile order."""
+        return sum(n for n, t in zip(self.ns, self.tiled) if t) / self.total_n
+
+    def row_major(self) -> "TableSpec":
+        """The same table with every leaf row-major: the layout a host tier
+        or a wire peer of this table holds."""
+        return dataclasses.replace(self, tiled=(False,) * self.num_leaves)
+
     def layout_digest(self) -> bytes:
         """16-byte digest identifying the full table layout (tree structure,
-        leaf shapes, padding). Two specs with equal digests decode each
-        other's frames leaf-for-leaf; (num_leaves, total_n) alone cannot
-        distinguish e.g. {w:(8,128), b:(128,)} from {w:(128,), b:(8,128)}."""
+        leaf shapes, padding, and which leaves are in tile order). Two specs
+        with equal digests decode each other's frames leaf-for-leaf;
+        (num_leaves, total_n) alone cannot distinguish e.g. {w:(8,128),
+        b:(128,)} from {w:(128,), b:(8,128)}. A spec with no tiled leaf has
+        the digest row-major tables always had."""
         import hashlib
 
-        desc = repr((str(self.treedef), self.shapes, self.ns, self.padded))
-        return hashlib.sha256(desc.encode()).digest()[:16]
+        desc = (str(self.treedef), self.shapes, self.ns, self.padded)
+        if any(self.tiled):
+            desc += (self.tiled,)
+        return hashlib.sha256(repr(desc).encode()).digest()[:16]
 
     @property
     def leaf_rows(self) -> LeafRanges:
@@ -297,17 +324,49 @@ class LeafRows:
         return jnp.broadcast_to(scales, (*scales.shape[:-1], len(self.ns)))
 
 
-def make_spec(tree: Any) -> TableSpec:
-    """Build the static layout for a pytree of arrays."""
+def tiled(shape: tuple[int, ...]) -> bool:
+    """Whether a leaf of ``shape`` is held in its 8 x 128 tile order in a
+    tile-ordered table: rank 2 or more, the last dimension whole lanes of
+    128 and the one before it whole sublanes of 8. Such a leaf has a
+    multiple of 1 024 elements, so it has no padding."""
+    return len(shape) >= 2 and shape[-1] % LANES == 0 and shape[-2] % SUBLANES == 0
+
+
+def _tile_axes(x: jnp.ndarray) -> jnp.ndarray:
+    """A tiled leaf ``[..., R, C]`` as ``[..., R / 8, C / 128, 8, 128]``:
+    its elements in tile order (leading dimensions outermost, then blocks of
+    8 rows, then blocks of 128 lanes, then the tile row-major) are this
+    array's in row-major order."""
+    *lead, r, c = x.shape
+    x = x.reshape(*lead, r // SUBLANES, SUBLANES, c // LANES, LANES)
+    return jnp.swapaxes(x, -3, -2)
+
+
+def _from_tiles(tiles: jnp.ndarray, shape: tuple[int, ...]) -> jnp.ndarray:
+    """Inverse of :func:`_tile_axes`: a tiled leaf's elements in tile order,
+    any shape, as the leaf of ``shape``."""
+    *lead, r, c = shape
+    x = tiles.reshape(*lead, r // SUBLANES, c // LANES, SUBLANES, LANES)
+    return jnp.swapaxes(x, -3, -2).reshape(shape)
+
+
+def make_spec(tree: Any, tile_order: bool = False) -> TableSpec:
+    """Build the static layout for a pytree of arrays: every leaf row-major,
+    or with ``tile_order`` every :func:`tiled` leaf in its tile order (the
+    pod tier's device table; a host buffer has no tiling, and ranged pages
+    and the wire read its rows row-major)."""
     leaves, treedef = jax.tree.flatten(tree)
     shapes = tuple(tuple(np.shape(l)) for l in leaves)
     ns = tuple(int(np.prod(s)) if s else 1 for s in shapes)
     padded = tuple(padded_len(n, TILE) for n in ns)
-    return TableSpec(treedef, shapes, ns, padded)
+    order = tuple(map(tiled, shapes)) if tile_order else ()
+    return TableSpec(treedef, shapes, ns, padded, order)
 
 
 def flatten(tree: Any, spec: TableSpec) -> jnp.ndarray:
-    """Pytree -> single padded flat float32 buffer (padding exactly 0)."""
+    """Pytree -> single padded flat float32 buffer (padding exactly 0), a
+    tiled leaf in its tile order: on the TPU that is the leaf's own layout,
+    so its part of the buffer is written from it as it lies."""
     leaves, treedef = jax.tree.flatten(tree)
     if treedef != spec.treedef:
         # the reference raises THError("Not the right size!") on mismatch
@@ -319,25 +378,42 @@ def flatten(tree: Any, spec: TableSpec) -> jnp.ndarray:
         )
     with jax.named_scope("st.flatten"):
         parts = []
-        for i, (leaf, n, p) in enumerate(zip(leaves, spec.ns, spec.padded)):
-            flat = jnp.ravel(jnp.asarray(leaf)).astype(jnp.float32)
-            if flat.shape[0] != n:
-                raise ValueError(
-                    f"leaf {i} has {flat.shape[0]} elements, spec expects {n}"
-                )
-            parts.append(pad_flat(flat, p))
+        for i, (leaf, n, p, t) in enumerate(
+            zip(leaves, spec.ns, spec.padded, spec.tiled)
+        ):
+            leaf = jnp.asarray(leaf)
+            if leaf.size != n:
+                raise ValueError(f"leaf {i} has {leaf.size} elements, spec expects {n}")
+            if t:
+                leaf = _tile_axes(leaf.reshape(spec.shapes[i]))
+            parts.append(pad_flat(jnp.ravel(leaf).astype(jnp.float32), p))
         return jnp.concatenate(parts) if len(parts) > 1 else parts[0]
 
 
 def unflatten(flat: jnp.ndarray, spec: TableSpec) -> Any:
-    """Inverse of :func:`flatten`."""
+    """Inverse of :func:`flatten`. A tiled leaf is a slice of whole tiles of
+    the table seen as ``[total / 1024, 8, 128]`` (on the TPU a bitcast of
+    ``flat``): XLA fuses that slice with the cast a product reads, where a
+    slice of the flat vector would be copied whole in float32 first."""
     with jax.named_scope("st.unflatten"):
+        tiles = flat.reshape(-1, SUBLANES, LANES) if any(spec.tiled) else None
         leaves = []
         off = 0
-        for shape, n, p in zip(spec.shapes, spec.ns, spec.padded):
-            leaves.append(flat[off : off + n].reshape(shape))
+        for shape, n, p, t in zip(spec.shapes, spec.ns, spec.padded, spec.tiled):
+            if t:
+                leaves.append(_from_tiles(tiles[off // TILE : (off + n) // TILE], shape))
+            else:
+                leaves.append(flat[off : off + n].reshape(shape))
             off += p
         return jax.tree.unflatten(spec.treedef, leaves)
+
+
+@partial(jax.jit, static_argnames=("src", "dst"))
+def reorder(flat: jnp.ndarray, src: TableSpec, dst: TableSpec) -> jnp.ndarray:
+    """A flat table laid out by ``src`` in the leaf orders of ``dst``, a
+    spec of the same tree, shapes and padding (one program: the pod tier's
+    device table <-> the row-major order of a host tier)."""
+    return flatten(unflatten(flat, src), dst)
 
 
 def resolve_impl(impl: str) -> str:

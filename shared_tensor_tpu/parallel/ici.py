@@ -86,6 +86,13 @@ def state_sharding(mesh: Mesh, config: MeshConfig | None = None) -> NamedShardin
     return NamedSharding(mesh, P(cfg.peer_axis, cfg.shard_axis))
 
 
+#: :func:`flatten` / :func:`unflatten` as one program each, for the pod's
+#: table outside a trace: op by op, every tiled leaf's reshape and transpose
+#: would build a program of its own
+_flatten = jax.jit(flatten, static_argnums=1)
+_unflatten = jax.jit(unflatten, static_argnums=1)
+
+
 def init_state(
     mesh: Mesh,
     spec: TableSpec,
@@ -99,7 +106,7 @@ def init_state(
     (comm/peer.py).
 
     Host spans: ``st:init_state`` around ``st:init_state.seed`` (the
-    template flattened, leaf by leaf, and put under the shard axis),
+    template flattened in one program and put under the shard axis),
     ``st:init_state.broadcast`` (one program: the seed to every peer) and
     ``st:init_state.residual`` (one program: zeros). Each child ends by
     waiting for its array, which the next one or the caller's first step
@@ -123,7 +130,7 @@ def init_state(
         else:
             with pod.span("init_state.seed"):
                 seed = jax.block_until_ready(jax.device_put(
-                    flatten(template, spec), NamedSharding(mesh, P(shard_ax))
+                    _flatten(template, spec), NamedSharding(mesh, P(shard_ax))
                 ))
             with pod.span("init_state.broadcast"):
                 values = jax.block_until_ready(jax.jit(
@@ -142,7 +149,7 @@ def read_peer(state: PeerSyncState, spec: TableSpec, peer: int):
     """Peer ``peer``'s current replica as the caller's pytree (reference
     copyToTensor)."""
     with pod_tier().span("read_peer"):
-        return unflatten(state.values[peer], spec)
+        return _unflatten(state.values[peer], spec)
 
 
 def add_updates_raw(state: PeerSyncState, updates: jax.Array) -> PeerSyncState:

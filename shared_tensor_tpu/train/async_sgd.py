@@ -204,7 +204,9 @@ class PodTrainer:
         pod = profiling.pod_tier()
         with pod.span("trainer_init"):
             with pod.span("make_spec"):
-                self.spec: TableSpec = make_spec(self.template)
+                # the device table: each leaf of whole 8 x 128 tiles in its
+                # tile order, so the step's view of it is a bitcast
+                self.spec: TableSpec = make_spec(self.template, tile_order=True)
             self.state: PeerSyncState = init_state(
                 self.mesh, self.spec, self.template, self.mesh_config
             )

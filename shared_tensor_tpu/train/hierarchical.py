@@ -25,6 +25,11 @@ Bridge semantics (all additive, order-free):
   deltas; measured against what we already pushed) is applied to every
   device replica's values, residuals untouched — split horizon at the pod
   boundary.
+
+The peer tier's table is row-major and the pod's device table holds its
+leaves of whole 8 x 128 tiles in tile order (``ops/table.py``): the bridge's
+bookkeeping is kept in the peer's order and converted where it meets the
+pod (its mean on the way out, the pulled delta on the way in).
 """
 
 from __future__ import annotations
@@ -35,7 +40,7 @@ import jax
 import jax.numpy as jnp
 
 from ..comm.peer import SharedTensorPeer
-from ..ops.table import unflatten
+from ..ops.table import reorder, unflatten
 from ..parallel.ici import apply_external
 from .async_sgd import PodTrainer
 
@@ -104,29 +109,27 @@ class HierarchicalTrainer:
         sync_every: int = 1,
         _peer_seen: jnp.ndarray | None = None,
     ):
-        if peer.st.spec.layout_digest() != pod.spec.layout_digest():
+        # the same tree, shapes and padding; the leaves' orders may differ
+        if peer.st.spec.row_major().layout_digest() != pod.spec.row_major().layout_digest():
             raise ValueError("pod table layout != peer table layout")
         self.pod = pod
         self.peer = peer
         self.sync_every = max(1, int(sync_every))
         # What the pod has already incorporated of the peer-tier replica,
-        # and what the peer tier already has of the pod's progress.
+        # and what the peer tier already has of the pod's progress, both in
+        # the peer's order.
         # ``_peer_seen`` must be the exact snapshot the pod was seeded from
         # (create() passes it); deriving it from the pod itself keeps the
         # invariant for manual wiring too — a fresh peer.st.snapshot_flat()
         # here would silently absorb frames applied since the pod seed.
-        self._peer_seen = (
-            _peer_seen if _peer_seen is not None else self._pod_mean_of(pod)
-        )
         self._pod_pushed = self._pod_mean()
+        self._peer_seen = _peer_seen if _peer_seen is not None else self._pod_pushed
         self.exchanges = 0
 
-    @staticmethod
-    def _pod_mean_of(pod: PodTrainer) -> jnp.ndarray:
-        return jnp.mean(pod.state.values, axis=0)
-
     def _pod_mean(self) -> jnp.ndarray:
-        return self._pod_mean_of(self.pod)
+        """The pod replicas' mean, in the peer's order."""
+        mean = jnp.mean(self.pod.state.values, axis=0)
+        return reorder(mean, self.pod.spec, self.peer.st.spec)
 
     def step(self, batch: Any, lr: float = 1e-2):
         losses, scales = self.pod.step(batch, lr)
@@ -147,12 +150,14 @@ class HierarchicalTrainer:
         # st.add leaves frames waiting for the next keepalive tick.
         mean = self._pod_mean()
         outgoing = mean - self._pod_pushed
-        self.peer.add(unflatten(outgoing, self.pod.spec))
+        self.peer.add(unflatten(outgoing, self.peer.st.spec))
         # The peer replica now includes our push; remember both.
         self._peer_seen = snap + outgoing
         self._pod_pushed = mean + incoming  # after applying incoming below
         apply = jax.device_get(incoming)  # host hop: peer tier is host-side
-        self.pod.state = apply_external(self.pod.state, jnp.asarray(apply))
+        self.pod.state = apply_external(
+            self.pod.state, reorder(jnp.asarray(apply), self.peer.st.spec, self.pod.spec)
+        )
         self.exchanges += 1
 
     def read(self, peer: int = 0) -> Any:

@@ -184,6 +184,7 @@ class PodTier:
         self._last_compile_step = instrument(
             self.registry.gauge, "st_pod_last_compile_step"
         )
+        self._tiled_share = instrument(self.registry.gauge, "st_table_tiled_share")
         self._trace_s = instrument(self.registry.counter, "st_pod_trace_seconds_total")
         self._lower_s = instrument(self.registry.counter, "st_pod_lower_seconds_total")
         # spans: the recorder they go to, this thread's open ones, the series
@@ -274,8 +275,10 @@ class PodTier:
 
     def watch(self, trainer) -> None:
         """The trainer whose ``aux`` the expert layers' gauges read (the
-        newest made; held weakly)."""
+        newest made; held weakly), and whose table's share of live elements
+        in tile order ``st_table_tiled_share`` reads."""
         self._trainer = weakref.ref(trainer)
+        self._tiled_share.set(trainer.spec.tiled_share)
 
     def _moe(self) -> dict:
         """The newest step's expert-layer counters, fetched from the device

@@ -161,6 +161,28 @@ def test_pod_roundtrip_resumes_training(tmp_path):
     assert float(jnp.mean(l2)) < float(jnp.mean(l0)) * 0.8
 
 
+def test_pod_checkpoint_in_row_major_order_is_refused(tmp_path):
+    """A trainer holds its ``[16, 256]`` leaf in tile order; a checkpoint of
+    the same table written row-major (as every pod checkpoint was before
+    tile order) carries that layout's digest, so the trainer refuses it
+    rather than read it in the wrong order, and takes its own back."""
+    mesh = make_mesh(2, 1)
+    tpl = {"b": jnp.ones((4,), jnp.float32),
+           "w": jnp.arange(16 * 256, dtype=jnp.float32).reshape(16, 256)}
+    tr = PodTrainer(mesh, tpl, lambda p, b: 0.0)
+    assert tr.spec.tiled == (False, True)
+    path = str(tmp_path / "pod.npz")
+    row_major = tr.spec.row_major()
+    ckpt.save_pod(init_state(mesh, row_major, tpl), row_major, path)
+    with pytest.raises(ValueError, match="layout"):
+        ckpt.load_pod(path, mesh, tr.spec)
+    with pytest.raises(ValueError, match="layout"):
+        ckpt.load_trainer(tr, path)
+    ckpt.save_trainer(tr, path)
+    ckpt.load_trainer(tr, path)
+    np.testing.assert_array_equal(np.asarray(tr.read(1)["w"]), np.asarray(tpl["w"]))
+
+
 def test_pod_peer_count_mismatch_rejected(tmp_path):
     mesh = make_mesh(4, 1)
     st = init_state(mesh, PodTrainer(mesh, _template(), lambda p, b: 0.0).spec)

@@ -116,6 +116,46 @@ def test_two_pod_training_converges_to_mixture():
         a.close()
 
 
+def test_tiled_pod_converges_with_a_host_peer():
+    """A pod whose device table holds a leaf in 8 x 128 tile order (its
+    ``[16, 256]``) bridged to a host peer, whose table is row-major: what
+    either side adds reaches the other leaf for leaf and element for
+    element, so the bridge's order conversions hold both ways."""
+    from shared_tensor_tpu.comm.peer import create_or_fetch
+
+    mesh_a, _ = _meshes()
+    port = _free_port()
+    tpl = {"m": jnp.zeros((16, 256), jnp.float32), "w": jnp.zeros((8,), jnp.float32)}
+    host = create_or_fetch("127.0.0.1", port, tpl)
+    try:
+        pod = HierarchicalTrainer.create(mesh_a, "127.0.0.1", port, tpl, _quad_loss)
+        try:
+            assert pod.pod.spec.tiled == (True, False)
+            assert not any(host.st.spec.tiled)
+            ramp = {"m": jnp.arange(16 * 256, dtype=jnp.float32).reshape(16, 256) / 4096,
+                    "w": jnp.linspace(-1.0, 1.0, 8, dtype=jnp.float32)}
+            # the pod's two device peers each add the ramp, the host its
+            # negative halved: every replica settles on 1.5 x the ramp
+            from shared_tensor_tpu.ops.table import flatten
+
+            pod.pod.add(jnp.broadcast_to(flatten(ramp, pod.pod.spec), (2, pod.pod.spec.total)))
+            host.add(jax.tree.map(lambda x: -0.5 * x, ramp))
+
+            def pump():
+                pod.step(jnp.zeros((2, 8), jnp.float32), lr=0.0)
+
+            def settled(read):
+                return all(np.allclose(np.asarray(read[k]), 1.5 * np.asarray(ramp[k]), atol=0.02)
+                           for k in ramp)
+
+            assert _settle(pump, lambda: settled(pod.read(0)) and settled(host.read())), (
+                pod.read(0), host.read())
+        finally:
+            pod.close()
+    finally:
+        host.close()
+
+
 def test_layout_mismatch_rejected():
     from shared_tensor_tpu.comm.peer import create_or_fetch
     from shared_tensor_tpu.train import PodTrainer

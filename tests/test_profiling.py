@@ -288,6 +288,24 @@ def test_pod_compile_counter_names_the_step_that_recompiled():
     assert snap["st_pod_compile_seconds_total"] > 0.0
 
 
+def test_pod_gauge_reads_the_newest_trainer_tables_tiled_share():
+    """``st_table_tiled_share`` is set when a PodTrainer is built: the share
+    of its table's live elements held in tile order (a ``[16, 128]`` leaf
+    is; a ``[5, 9]`` matrix and a vector are not)."""
+    from shared_tensor_tpu.parallel import make_mesh
+    from shared_tensor_tpu.train import PodTrainer
+    from shared_tensor_tpu.utils.profiling import pod_registry
+
+    tr, _ = _pod_trainer()
+    assert tr.spec.tiled == (True,)
+    assert pod_registry().snapshot()["st_table_tiled_share"] == 1.0
+    tpl = {"b": jnp.ones((77,), jnp.float32), "w": jnp.ones((16, 256), jnp.float32)}
+    PodTrainer(make_mesh(2, 1), tpl, lambda p, b: jnp.sum(p["w"]) * b, sync=False)
+    assert pod_registry().snapshot()["st_table_tiled_share"] == 4096 / (4096 + 77)
+    PodTrainer(make_mesh(2, 1), {"m": jnp.ones((5, 9))}, lambda p, b: jnp.sum(p["m"]) * b)
+    assert pod_registry().snapshot()["st_table_tiled_share"] == 0.0
+
+
 def test_pod_counters_are_in_the_schema_and_the_exposition():
     from shared_tensor_tpu.obs.schema import SCHEMA
     from shared_tensor_tpu.utils.profiling import pod_registry
@@ -297,7 +315,7 @@ def test_pod_counters_are_in_the_schema_and_the_exposition():
         "st_pod_cache_load_seconds_total", "st_pod_last_compile_step",
         "st_attn_traces_total", "st_attn_saved_bytes", "st_codec_kernel_traces_total",
         "st_codec_leaves_per_block_max", "st_codec_words_rows_per_block",
-        "st_moe_combine_traces_total",
+        "st_moe_combine_traces_total", "st_table_tiled_share",
     )
     text = pod_registry().prometheus_text()
     for name in names:

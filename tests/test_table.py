@@ -48,6 +48,68 @@ def test_flatten_roundtrip():
     assert spec.total_n == sum(v.size for v in t.values())
 
 
+#: leaf shapes the order rule sees: row-major (a vector, lanes not whole, rows
+#: not whole sublanes) and tiled (one tile row wide, where both orders agree;
+#: 24 lane blocks; a leading dimension)
+ORDER_SHAPES = [(300,), (4, 2560), (8, 128), (16, 64), (24, 3072), (2, 16, 256)]
+#: ``make_spec(_order_tree(ORDER_SHAPES))``'s digest from before tile order
+#: existed: a row-major spec keeps the digest host tiers and wire peers compare
+ROW_MAJOR_DIGEST = "6c2f7011b2661faae7757c2b0c030bc7"
+
+
+def _order_tree(shapes):
+    rng = np.random.default_rng(len(shapes))
+    return {f"l{i}": rng.standard_normal(s).astype(np.float32) for i, s in enumerate(shapes)}
+
+
+@pytest.mark.parametrize(
+    "case,shape",
+    [("round_trip", s) for s in ORDER_SHAPES]
+    + [("row_major_digest", None), ("orders_differ", None)],
+    ids=str,
+)
+def test_leaf_order(case, shape):
+    """A tile-ordered spec (the pod's device table) holds a leaf in its
+    8 x 128 tile order exactly where its two minor dimensions are whole
+    tiles, and gives every leaf back bit for bit; ``reorder`` turns the table
+    into the row-major one and back. ``make_spec``'s default is row-major
+    with the digest it always had; the two orders' digests differ."""
+    from shared_tensor_tpu.ops.table import reorder, tiled
+
+    if case == "round_trip":
+        t = {"b": np.arange(77, dtype=np.float32), "x": _order_tree([shape])["l0"]}
+        spec, rows = make_spec(t, tile_order=True), make_spec(t)
+        is_tiled = len(shape) >= 2 and shape[-1] % 128 == 0 and shape[-2] % 8 == 0
+        assert spec.tiled == (False, is_tiled) and tiled(shape) == is_tiled
+        assert rows.tiled == (False, False) and spec.row_major() == rows
+        flat = flatten(t, spec)
+        for k, v in unflatten(flat, spec).items():
+            np.testing.assert_array_equal(np.asarray(v), t[k])
+        x = np.asarray(flat)[1024 : 1024 + t["x"].size]
+        if is_tiled:
+            *lead, r, c = shape
+            order = t["x"].reshape(*lead, r // 8, 8, c // 128, 128).swapaxes(-3, -2)
+            np.testing.assert_array_equal(x, order.reshape(-1))
+        else:
+            np.testing.assert_array_equal(x, t["x"].reshape(-1))
+        np.testing.assert_array_equal(
+            np.asarray(reorder(flat, spec, rows)), np.asarray(flatten(t, rows)))
+        np.testing.assert_array_equal(
+            np.asarray(reorder(flatten(t, rows), rows, spec)), np.asarray(flat))
+        assert spec.tiled_share == (t["x"].size / (77 + t["x"].size) if is_tiled else 0.0)
+    elif case == "row_major_digest":
+        spec = make_spec(_order_tree(ORDER_SHAPES))
+        assert not any(spec.tiled)
+        assert spec.layout_digest().hex() == ROW_MAJOR_DIGEST
+        assert spec.row_major().layout_digest() == spec.layout_digest()
+    else:
+        tree = _order_tree(ORDER_SHAPES)
+        spec = make_spec(tree, tile_order=True)
+        assert spec.tiled == (False, False, True, False, True, True)
+        assert spec.layout_digest() != make_spec(tree).layout_digest()
+        assert spec.row_major().layout_digest().hex() == ROW_MAJOR_DIGEST
+
+
 def test_single_leaf_matches_scalar_codec():
     """A one-leaf table must reproduce codec.quantize bit-for-bit."""
     rng = np.random.default_rng(3)

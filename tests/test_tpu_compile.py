@@ -381,3 +381,51 @@ def test_expert_layer_grad_compiles_for_v5e_with_token_major_accumulators(
                  and result(line).startswith(tiles) and operation(line) in ("fusion", "copy", "transpose")
                  and "st.moe.combine" in line]
     assert len(relayouts) == 2, relayouts
+
+
+def _leaf_relayouts(text: str, sizes: set[int]) -> list[str]:
+    """Non-bitcast ``reshape`` / ``copy`` / ``transpose`` instructions of a
+    compiled text whose result has one of ``sizes`` elements, whatever scope
+    XLA names them by: a relayout of the table's view of a leaf is named
+    after the cast that follows it, or by nothing."""
+    found = []
+    for m in re.finditer(r"= \w+\[([\d,]*)\]\S* (?:reshape|copy|transpose)\(.*", text):
+        if int(np.prod([int(d) for d in m.group(1).split(",") if d])) in sizes:
+            found.append(m.group(0)[:160])
+    return found
+
+
+def test_tiled_leaves_reach_the_step_as_bitcasts_on_v5e(v5e_devices):
+    """A per-peer train step over leaves at a decoder's widths: in the pod's
+    tile-ordered table (``make_spec(tile_order=True)``) the step's view of
+    each ``[256, 3072]`` / ``[2, 256, 3072]`` leaf (``st.unflatten``) and its
+    gradient's way back (``st.flatten``) compile with no ``reshape``,
+    ``copy`` or ``transpose`` of the leaf; in the row-major table the same
+    step relayouts each (four of them)."""
+    tpl = {
+        "w1": jax.ShapeDtypeStruct((256, 3072), jnp.float32),
+        "w2": jax.ShapeDtypeStruct((2, 256, 3072), jnp.float32),
+        "norm": jax.ShapeDtypeStruct((3072,), jnp.float32),
+    }
+
+    def loss(p, x):
+        h = mla_moe._mm(x, p["w1"], jnp.bfloat16) * mla_moe._mm(
+            x, p["w2"].reshape(512, 3072), jnp.bfloat16)[:, :256]
+        return jnp.mean(jnp.square(h)) + jnp.sum(jnp.square(p["norm"]))
+
+    mesh = make_mesh(1, 1, devices=v5e_devices)
+    arg = lambda shape, pspec: jax.ShapeDtypeStruct(
+        shape, jnp.float32, sharding=NamedSharding(mesh, pspec))
+    sh = state_sharding(mesh).spec
+    found = {}
+    for tile_order in (True, False):
+        spec = table.make_spec(tpl, tile_order=tile_order)
+        block = arg((1, spec.total), sh)
+        text = build_train_step(mesh, spec, loss).lower(
+            PeerSyncState(block, block), None, arg((1, 128, 3072), P(sh[0])), arg((), P())
+        ).compile().as_text()
+        assert text.count("tpu_custom_call") >= 2
+        found[tile_order] = _leaf_relayouts(text, {256 * 3072, 2 * 256 * 3072})
+    assert spec.tiled == (False, False, False) and len(found[False]) == 4, found[False]
+    assert table.make_spec(tpl, tile_order=True).tiled == (False, True, True)  # norm, w1, w2
+    assert not found[True], found[True]

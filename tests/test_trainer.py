@@ -29,6 +29,22 @@ def _batches(key, n_peer, batch=4, seq=16):
     )
 
 
+def test_read_gives_the_template_back_bit_for_bit():
+    """The device table holds the leaves of whole 8 x 128 tiles in tile
+    order (the ``[8, 384]`` and ``[2, 16, 128]`` ones, not the vector or the
+    ``[3, 5]``): every peer reads the template back as it was given."""
+    rng = np.random.default_rng(7)
+    tpl = {"v": rng.standard_normal(300), "s": rng.standard_normal((3, 5)),
+           "m": rng.standard_normal((8, 384)), "t": rng.standard_normal((2, 16, 128))}
+    tpl = {k: v.astype(np.float32) for k, v in tpl.items()}
+    tr = PodTrainer(make_mesh(2, 1), tpl, lambda p, b: 0.0)
+    assert tr.spec.tiled == (True, False, True, False)  # m, s, t, v
+    for peer in range(2):
+        got = tr.read(peer)
+        for k in tpl:
+            np.testing.assert_array_equal(np.asarray(got[k]), tpl[k])
+
+
 def test_train_step_runs_and_loss_decreases():
     tr = _trainer(n_peer=4)
     first = last = None
