@@ -56,20 +56,24 @@ from ..utils.profiling import pod_tier
 #: attention's ``o`` and ``lse`` and, heads first as the backward rule takes
 #: them, its ``q``, ``k`` and ``v`` (:func:`_attention_tiles_fwd` names them);
 #: a state-space mixer's scan output (``nemotron_h``'s ``_ssd_fwd`` names it);
-#: a latent expert layer's routed sum before its way back (:func:`moe`)
+#: a latent expert layer's routed sum before its way back (:func:`moe`); a
+#: delta-rule mixer's scan output and the states entering its groups of
+#: chunks (``kimi_linear``'s ``_kda_fwd`` names them)
 ATTN_OUT, ATTN_QKV, SSM_OUT, MOE_LATENT = "attn_out", "attn_qkv", "ssm_out", "moe_latent"
+KDA_OUT = "kda_out"
 
 
 def layer_checkpoint(fn):
     """``fn`` (one decoder layer) under ``jax.checkpoint``: recomputed in the
     backward pass but for its attention's residuals, so neither the attention
     nor what makes q, k and v (their products, RoPE, the head-first layout)
-    runs a second time; but for a scan's output, so the scan runs once
-    forward and once in its backward rule; and but for a latent expert
-    layer's routed sum, which the gradient of its way back reads, so the
-    experts' forward loop runs once."""
+    runs a second time; but for a scan's output (and a delta-rule scan's
+    states entering its groups of chunks), so the scan runs once forward and
+    once in its backward rule; and but for a latent expert layer's routed
+    sum, which the gradient of its way back reads, so the experts' forward
+    loop runs once."""
     return jax.checkpoint(fn, policy=jax.checkpoint_policies.save_only_these_names(
-        ATTN_OUT, ATTN_QKV, SSM_OUT, MOE_LATENT))
+        ATTN_OUT, ATTN_QKV, SSM_OUT, MOE_LATENT, KDA_OUT))
 
 
 @dataclasses.dataclass(frozen=True)
@@ -450,22 +454,30 @@ def causal_attention(q, k, v, block: int, window: int | None = None,
 
 def mla(p: dict, x: jax.Array, rope, cfg: Config) -> jax.Array:
     """Multi-head latent attention of ``x [T, hidden]`` (already normed);
-    ``p`` holds the ``self_attn.*`` leaves."""
+    ``p`` holds the ``self_attn.*`` leaves. The queries come through the
+    low-rank pair ``q_a_proj`` / ``q_b_proj``, or from one ``q_proj`` where
+    ``p`` holds it (a model without ``q_lora_rank``). ``rope`` is the (cos,
+    sin) tables that turn the rotary part of q and k; with ``rope`` None that
+    part is kept as projected (no position encoding)."""
     n, h, dt = x.shape[0], cfg.num_attention_heads, cfg.dtype
     nope, rd, vd = cfg.qk_nope_head_dim, cfg.qk_rope_head_dim, cfg.v_head_dim
-    cos, sin = rope
+
+    def turned(a):
+        return a if rope is None else rope_interleaved(a, *rope)
+
     with jax.named_scope("st.mla.proj"):
-        c_q = rms_norm(_mm(x, p["q_a_proj.weight"], dt), p["q_a_layernorm.weight"],
-                       cfg.rms_norm_eps)
-        q = _mm(c_q, p["q_b_proj.weight"], dt).reshape(n, h, nope + rd)
+        if "q_proj.weight" in p:
+            q = _mm(x, p["q_proj.weight"], dt).reshape(n, h, nope + rd)
+        else:
+            c_q = rms_norm(_mm(x, p["q_a_proj.weight"], dt), p["q_a_layernorm.weight"],
+                           cfg.rms_norm_eps)
+            q = _mm(c_q, p["q_b_proj.weight"], dt).reshape(n, h, nope + rd)
         kv_a = _mm(x, p["kv_a_proj_with_mqa.weight"], dt)
         c_kv = rms_norm(kv_a[:, : cfg.kv_lora_rank], p["kv_a_layernorm.weight"],
                         cfg.rms_norm_eps)
-        k_r = rope_interleaved(kv_a[:, None, cfg.kv_lora_rank:], cos, sin)
+        k_r = turned(kv_a[:, None, cfg.kv_lora_rank:])
         kv = _mm(c_kv, p["kv_b_proj.weight"], dt).reshape(n, h, nope + vd)
-        q = jnp.concatenate(
-            [q[..., :nope], rope_interleaved(q[..., nope:], cos, sin)], axis=-1
-        ).astype(dt)
+        q = jnp.concatenate([q[..., :nope], turned(q[..., nope:])], axis=-1).astype(dt)
         k = jnp.concatenate(
             [kv[..., :nope], jnp.broadcast_to(k_r, (n, h, rd))], axis=-1
         ).astype(dt)

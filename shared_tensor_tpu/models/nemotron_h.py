@@ -254,13 +254,15 @@ def init_params(key: jax.Array, cfg: Config) -> dict[str, jax.Array]:
 # --- the mixer ---------------------------------------------------------------------
 
 
-def causal_conv(x: jax.Array, w: jax.Array, b: jax.Array) -> jax.Array:
+def causal_conv(x: jax.Array, w: jax.Array, b: jax.Array | None = None) -> jax.Array:
     """The depthwise causal convolution of ``x [T, C]`` by the taps ``w [K,
     C]`` (a ``Conv1d`` of ``groups = C``, padded by K - 1 and cut to T):
-    ``out_t = b + sum_k w[k] x_{t - K + 1 + k}``, float32."""
+    ``out_t = b + sum_k w[k] x_{t - K + 1 + k}``, float32; with ``b`` None a
+    convolution without bias."""
     n, k = x.shape[0], w.shape[0]
     xp = jnp.pad(x.astype(jnp.float32), ((k - 1, 0), (0, 0)))
-    return b + sum(w[i] * xp[i:i + n] for i in range(k))
+    taps = sum(w[i] * xp[i:i + n] for i in range(k))
+    return taps if b is None else b + taps
 
 
 def gated_group_norm(y: jax.Array, z: jax.Array, w: jax.Array, groups: int,

@@ -229,6 +229,12 @@ SCHEMA: dict[str, tuple[str, str]] = {
     "st_ssm_heads": ("gauge", "heads the newest traced scan holds (a share of the mixer's heads: those of whole groups)"),
     "st_ssm_chunks": ("gauge", "chunks of chunk_size positions the newest traced scan runs (the within-chunk quadratic form a chunk, one pass over their states)"),
     "st_ssm_saved_bytes": ("gauge", "bytes of the scan's output (by shape and dtype) the newest traced scan names for its layer's checkpoint: times the layer plan, what the checkpoint policy holds of the mixers from the forward pass to the backward"),
+    # the delta-rule mixers' chunked scan (models/kimi_linear.py), counted
+    # when a program that calls it is traced
+    "st_kda_traces_total": ("counter", "traced calls of the chunked delta-rule scan of a Kimi Delta Attention mixer"),
+    "st_kda_heads": ("gauge", "heads the newest traced delta-rule scan holds"),
+    "st_kda_chunks": ("gauge", "chunks of 64 positions the newest traced delta-rule scan runs (a triangular solve a chunk and head, one pass over their states)"),
+    "st_kda_saved_bytes": ("gauge", "bytes of the delta-rule scan's output and of the states entering its groups of chunks (by shape and dtype) the newest traced scan names for its layer's checkpoint"),
     # the expert loop's combine (models/mla_moe.py): which path a traced
     # add of a tile's rows took, decided at trace time (backend, the
     # accumulator's shape)

@@ -244,6 +244,10 @@ class PodTier:
         self._ssm = {"st_ssm_traces_total": 0, "st_ssm_heads": 0, "st_ssm_chunks": 0,
                      "st_ssm_saved_bytes": 0}
         self.registry.register_collector(lambda: dict(self._ssm))
+        # the delta-rule mixers' chunked scan, the same way
+        self._kda = {"st_kda_traces_total": 0, "st_kda_heads": 0, "st_kda_chunks": 0,
+                     "st_kda_saved_bytes": 0}
+        self.registry.register_collector(lambda: dict(self._kda))
 
         self._combine_traces = {"pallas": 0, "xla": 0}
         combine_keys = {
@@ -332,6 +336,17 @@ class PodTier:
             self._ssm["st_ssm_traces_total"] += 1
             self._ssm.update(st_ssm_heads=heads, st_ssm_chunks=chunks,
                              st_ssm_saved_bytes=saved_bytes)
+
+    def count_kda_trace(self, heads: int, chunks: int, saved_bytes: int) -> None:
+        """One traced call of ``models/kimi_linear.py``'s chunked delta-rule
+        scan: the ``heads`` it holds, the ``chunks`` it runs, and the
+        ``saved_bytes`` (by shape and dtype) of its output and the states
+        entering its groups of chunks, which its layer's checkpoint keeps.
+        Traces, not steps."""
+        with self._mu:
+            self._kda["st_kda_traces_total"] += 1
+            self._kda.update(st_kda_heads=heads, st_kda_chunks=chunks,
+                             st_kda_saved_bytes=saved_bytes)
 
     def count_combine_trace(self, path: str) -> None:
         """One traced add of a tile's rows into an expert loop's accumulator
